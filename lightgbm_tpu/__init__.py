@@ -6,7 +6,11 @@ compute (one-hot MXU histograms, single-program leaf-wise tree growth,
 LightGBM Python API surface (``Dataset``/``Booster``/``train``/``cv``/sklearn
 wrappers).
 """
-from .basic import Booster, Dataset
+from .utils import compile_cache as _compile_cache
+
+_compile_cache.configure()
+
+from .basic import Booster, Dataset  # noqa: E402
 from .callback import early_stopping, print_evaluation, log_evaluation, \
     record_evaluation, reset_parameter
 from .config import Config

@@ -23,7 +23,7 @@ Two layers:
 - **ledger** — :class:`CostLedger` wraps named jit/lowered programs,
   records ``Compiled.cost_analysis()`` (flops, bytes accessed,
   transcendentals) and ``memory_analysis()`` (argument/output/temp
-  bytes; peak is derived — jax 0.4 exposes no peak field), joins them
+  bytes; peak is derived), joins them
   with measured wall times, and emits one ``program_cost`` schema event
   per program through the existing :class:`~.events.EventLog` for
   ``obs-report --roofline`` to render.
@@ -33,7 +33,7 @@ from __future__ import annotations
 import threading
 from typing import Any, Callable, Dict, List, Optional
 
-__all__ = ["PEAK_RATES", "DEFAULT_CHIP", "normalize_chip", "peak_flops",
+__all__ = ["PEAK_RATES", "normalize_chip", "peak_flops",
            "peak_bandwidth", "mfu", "arithmetic_intensity",
            "ridge_intensity", "classify_bound", "roofline", "CostLedger",
            "get_ledger", "reset_ledger", "current_chip", "analyze_jitted",
@@ -44,10 +44,10 @@ COST_EVENT = "program_cost"
 
 # --------------------------------------------------------------------------
 # THE peak table.  Published per-chip dense-bf16 matmul peak and HBM
-# bandwidth; keys are lowercased ``device.device_kind`` values with the
-# platform name as fallback.  The CPU row is a deliberately round
-# container-class estimate (AVX-512 Xeon-ish) so CPU-fallback runs still
-# produce a finite, labelled MFU instead of a lie or a crash.
+# bandwidth; keys are lowercased ``device.device_kind`` values ("tpu v5
+# lite" is what a v5e chip reports).  A device that is not in the table is
+# an error, not a default: a rate priced against the wrong peak is a wrong
+# number under a device metric's name, so there is no CPU row either.
 # --------------------------------------------------------------------------
 PEAK_RATES: Dict[str, Dict[str, float]] = {
     "tpu v4":      {"flops": 275e12, "bytes_per_sec": 1228e9},
@@ -56,21 +56,19 @@ PEAK_RATES: Dict[str, Dict[str, float]] = {
     "tpu v5p":     {"flops": 459e12, "bytes_per_sec": 2765e9},
     "tpu v6e":     {"flops": 918e12, "bytes_per_sec": 1640e9},
     "tpu v6 lite": {"flops": 918e12, "bytes_per_sec": 1640e9},
-    "cpu":         {"flops": 3.3e12,  "bytes_per_sec": 150e9},
 }
-
-#: unrecognized TPU kinds price against v5e (the fleet's common chip)
-DEFAULT_CHIP = "tpu v5e"
 
 
 def normalize_chip(kind: Optional[str]) -> str:
-    """Map a ``device_kind``/platform string onto a peak-table key."""
+    """Map a ``device_kind`` string onto its peak-table key; raises
+    ``KeyError`` for a kind the table does not hold."""
     k = (kind or "").strip().lower()
-    if k in PEAK_RATES:
-        return k
-    if "cpu" in k or k in ("", "interpreter"):
-        return "cpu"
-    return DEFAULT_CHIP
+    if k not in PEAK_RATES:
+        raise KeyError(
+            f"device kind {kind!r} has no row in obs.costs.PEAK_RATES "
+            f"(known: {', '.join(PEAK_RATES)}); add its published peaks "
+            "before pricing a run on it")
+    return k
 
 
 def peak_flops(kind: Optional[str]) -> float:
@@ -126,18 +124,18 @@ def roofline(flops: float, bytes_accessed: float, seconds: float,
 
 
 # --------------------------------------------------------------------------
-# device access (lazy jax; every entry point tolerates a jax-free process)
+# device access (jax imported lazily: the module itself stays stdlib-only)
 # --------------------------------------------------------------------------
 
+def _device_kind() -> str:
+    import jax
+    return jax.devices()[0].device_kind.strip().lower()
+
+
 def current_chip() -> str:
-    """Peak-table key for the ambient default device ('cpu' when jax is
-    absent or the backend is unreachable)."""
-    try:
-        import jax
-        d = jax.devices()[0]
-        return normalize_chip(getattr(d, "device_kind", "") or d.platform)
-    except Exception:
-        return "cpu"
+    """Peak-table key of the default device; raises ``KeyError`` when the
+    table does not hold it (the CPU backend included)."""
+    return normalize_chip(_device_kind())
 
 
 #: test seam for :func:`record_watermarks` — ``device.memory_stats()`` is
@@ -190,18 +188,10 @@ def record_watermarks(prefix: str, registry: Any = None) -> Dict[str, int]:
 # --------------------------------------------------------------------------
 
 def _cost_dict(compiled: Any) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` normalized: jax 0.4 returns a LIST of
-    per-executable dicts (element 0 on single-program jits), newer jax a
-    plain dict; some backends return None.  Keys of interest: ``flops``,
+    """``Compiled.cost_analysis()`` (a plain dict; None on backends without
+    an analysis) reduced to the keys of interest: ``flops``,
     ``bytes accessed``, ``transcendentals``."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return {}
+    ca = compiled.cost_analysis() or {}
     out = {}
     for key, name in (("flops", "flops"),
                       ("bytes accessed", "bytes_accessed"),
@@ -213,14 +203,11 @@ def _cost_dict(compiled: Any) -> Dict[str, float]:
 
 
 def _memory_dict(compiled: Any) -> Dict[str, int]:
-    """``Compiled.memory_analysis()`` normalized.  jax 0.4's
-    ``CompiledMemoryStats`` has argument/output/temp/alias sizes but NO
-    peak field — ``peak_bytes`` is derived as arg+out+temp-alias (what
-    the executable pins at once, the planning number OOM math needs)."""
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:
-        return {}
+    """``Compiled.memory_analysis()`` normalized.  ``CompiledMemoryStats``
+    has argument/output/temp/alias sizes; ``peak_bytes`` is derived as
+    arg+out+temp-alias (what the executable pins at once, the planning
+    number OOM math needs)."""
+    ma = compiled.memory_analysis()
     if ma is None:
         return {}
     out = {}
@@ -271,8 +258,10 @@ class CostLedger:
         ``cost_analysis``/``memory_analysis`` (jax ``Compiled``); pass
         ``model_flops`` for an analytic work model to report alongside
         XLA's count, ``predicted_mfu`` for a work-model MFU bound."""
+        # the device kind is stored as found; pricing it against the peak
+        # table (rooflines) is where an unknown kind raises
         ent: Dict[str, Any] = {"program": name,
-                               "chip": chip or self._chip or current_chip()}
+                               "chip": chip or self._chip or _device_kind()}
         if compiled is not None:
             ent["cost"] = _cost_dict(compiled)
             ent["memory"] = _memory_dict(compiled)
@@ -305,7 +294,9 @@ class CostLedger:
     # ------------------------------------------------------------------
     def rooflines(self) -> List[Dict[str, Any]]:
         """One achieved-vs-peak record per OBSERVED program (programs with
-        analysis but no timings are skipped: no wall time, no rate)."""
+        analysis but no timings are skipped: no wall time, no rate).
+        Raises ``KeyError`` when a program ran on a device kind the peak
+        table does not hold."""
         out = []
         with self._lock:
             entries = [dict(e) for e in self._programs.values()]

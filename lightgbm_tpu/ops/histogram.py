@@ -107,8 +107,7 @@ def _hist_scatter(bins, grad, hess, mask, max_bin):
 def _hist_onehot(bins, grad, hess, mask, max_bin, chunk_rows):
     # gh on the LEFT of the dot: [3, chunk] @ [chunk, F*B].  The tiny "3" dim
     # lands on M (MXU sublane granularity 8) instead of N (lane granularity
-    # 128), which benched 2.5x faster on v5e than the [F*B, chunk] @
-    # [chunk, 3] orientation (scripts/bench_hist.py).
+    # 128), not the [F*B, chunk] @ [chunk, 3] orientation.
     #
     # precision=HIGHEST: on TPU the DEFAULT matmul precision rounds f32
     # inputs to bf16 (one MXU pass), which silently degrades this "f32
@@ -329,9 +328,12 @@ _PALLAS_BLOCK_ROWS = 1024
 # cannot bound the one-hot tile — _hist_pallas also shrinks BR to keep
 # FC*Bp*BR bf16 within _PALLAS_ONEHOT_BYTES of VMEM.
 _PALLAS_BLOCK_LANES = 2048
-# v5e VMEM is ~128MB; 8MB keeps the tile comfortably resident alongside the
-# in/out blocks while letting BR (grid-step row count) stay large enough to
-# amortize per-step overheads
+# No pallas_call here passes vmem_limit_bytes, so every kernel lives under
+# Mosaic's default scoped VMEM limit (about 16 MB a core), not the chip's
+# physical VMEM.  8MB for the tile lets BR (grid-step row count) stay large
+# enough to amortize per-step overheads, and at the bench shape (28 x 256)
+# the kernels compile and pass parity on v5e under that limit
+# (chip_smoke.py, PR 22).  Wider shapes have not met the chip.
 _PALLAS_ONEHOT_BYTES = 8 * 1024 * 1024
 
 
@@ -342,8 +344,10 @@ _PALLAS_ONEHOT_BYTES = 8 * 1024 * 1024
 _PALLAS_ROWMAJOR_MAX_LANES = 32768
 
 # the batched-leaf kernel keeps its whole [num_slots, 6, f*Bp] f32
-# accumulator VMEM-resident for the full grid; cap it so accumulator +
-# one-hot tile + I/O blocks stay well inside v5e's ~128MB VMEM
+# accumulator VMEM-resident for the full grid.  This cap was written against
+# physical VMEM and is far above the default scoped limit the kernel
+# actually compiles under (see above): the accumulator is 2.8MB at the bench
+# shape (16 slots x 28 x 256), and nothing near the cap has ever compiled.
 _PALLAS_LEAFACC_BYTES = 48 * 1024 * 1024
 
 

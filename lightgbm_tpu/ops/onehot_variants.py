@@ -363,9 +363,12 @@ VARIANTS = {
 VARIANT_NAMES = tuple(VARIANTS)
 
 # candidates the first-fit auto-tuner times (pick_variant): one entrant per
-# family that can plausibly win on hardware — the pure-compare-dtype
-# variants share base's work model, so only the cheapest (u8cmp) runs
-AUTO_CANDIDATES = ("base", "u8cmp", "staged", "packed", "int8")
+# family that can plausibly win on hardware.  The pure-compare-dtype variants
+# (bf16cmp, i16cmp, u8cmp, sub1abs) share base's work model, and Mosaic on
+# v5e refuses each of them (8/16-bit iota, u8->bf16 cast), so none is an
+# entrant: a candidate that cannot compile costs every first fit a failed
+# compile.  tests/test_chip_smoke.py compiles every name below for v5e.
+AUTO_CANDIDATES = ("base", "staged", "packed", "int8")
 
 
 def resolve(name: str, max_bin: int):
@@ -456,6 +459,9 @@ def make_bench_kernel(variant: str, f: int, max_bin: int, BR: int, *,
 # --------------------------------------------------------------------------
 
 _AUTO_CACHE: dict = {}
+#: wall seconds each election in _AUTO_CACHE took (same keys) — compile of
+#: every candidate included; read by chip_smoke.py
+_AUTO_SECONDS: dict = {}
 
 
 def _auto_bench_data(max_bin: int, f: int, rows: int = 262144):
@@ -508,6 +514,8 @@ def pick_variant(max_bin: int, num_features: int, *,
     module scope so later fits (and every tree of this fit) reuse the
     winner without re-timing or retracing.  Off-TPU the Pallas kernels are
     not the production path, so 'base' is returned without timing."""
+    import time
+
     import jax
     backend = backend or jax.default_backend()
     if backend != "tpu":
@@ -515,8 +523,10 @@ def pick_variant(max_bin: int, num_features: int, *,
     key = (jax.devices()[0].device_kind, int(max_bin))
     if key in _AUTO_CACHE:
         return _AUTO_CACHE[key]
+    t0 = time.perf_counter()
     choice = _run_auto_bench(max_bin, num_features)
     _AUTO_CACHE[key] = choice
+    _AUTO_SECONDS[key] = time.perf_counter() - t0
     return choice
 
 
@@ -526,8 +536,9 @@ def _run_auto_bench(max_bin: int, num_features: int) -> str:
     (precision-pinned — the same reference the hardware dual gate uses)
     before its timing counts; the fastest parity-clean candidate wins.  A
     candidate that fails to lower or fails parity is skipped with a
-    warning, never fatal — 'base' (itself covered by bench_dual's hardware
-    gate) is the floor."""
+    warning.  There is no floor: an election in which NO candidate passes
+    raises, because the only thing left to return would be a kernel that
+    just failed on this device."""
     from ..utils.log import Log
     from .histogram import HIST_PARITY_TOL, _hist_onehot
     import jax
@@ -536,7 +547,8 @@ def _run_auto_bench(max_bin: int, num_features: int) -> str:
     ref = jax.jit(lambda b_, g_: _hist_onehot(b_, g_, h, m, max_bin,
                                               65536))(bins, g)
     ref = ref.block_until_ready()
-    best, best_t = "base", float("inf")
+    best, best_t = None, float("inf")
+    failures = []
     for name in AUTO_CANDIDATES:
         if not VARIANTS[name].supports(max_bin):
             continue
@@ -545,16 +557,22 @@ def _run_auto_bench(max_bin: int, num_features: int) -> str:
         except Exception as e:             # noqa: BLE001 — lowering failures
             Log.warning("hist_variant auto-tune: %s failed (%s)", name,
                         str(e)[:120])
+            failures.append(f"{name}: {str(e)[:200]}")
             continue
-        if err > HIST_PARITY_TOL:
+        if not err <= HIST_PARITY_TOL:     # NaN must disqualify too
             Log.warning("hist_variant auto-tune: %s FAILED on-device parity "
                         "(relerr %.2e > %.0e) — disqualified", name, err,
                         HIST_PARITY_TOL)
+            failures.append(f"{name}: relerr {err:.2e}")
             continue
         Log.info("hist_variant auto-tune: %s %.3f ms (relerr %.2e)", name,
                  t * 1e3, err)
         if t < best_t:
             best, best_t = name, t
+    if best is None:
+        raise RuntimeError(
+            "hist_variant=auto: no candidate compiled and passed on-device "
+            f"parity at max_bin={max_bin} ({'; '.join(failures)})")
     Log.info("hist_variant auto-tune: picked %s for max_bin=%d", best,
              max_bin)
     return best

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.grower import GrowerConfig, grow_tree
-from .mesh import DATA_AXIS, shard_map
+from .mesh import DATA_AXIS
 
 
 def make_dp_train_step(grower_cfg: GrowerConfig,
@@ -118,7 +118,7 @@ def make_dp_train_step(grower_cfg: GrowerConfig,
             return grow_all(grads, hesses, bins, score, row_weight, fmask,
                             key)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             step_ex, mesh=mesh,
             in_specs=(P(axis_name), score_spec, score_spec, score_spec,
                       P(axis_name), P(), P()),
@@ -135,7 +135,7 @@ def make_dp_train_step(grower_cfg: GrowerConfig,
         grads, hesses = grad_fn(score, label, weight)
         return grow_all(grads, hesses, bins, score, row_weight, fmask, key)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), score_spec, P(axis_name),
                   P(axis_name), P(), P()),

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.grower import GrowerConfig, grow_tree
-from .mesh import FEATURE_AXIS, shard_map
+from .mesh import FEATURE_AXIS
 
 
 def make_fp_train_step(grower_cfg: GrowerConfig,
@@ -59,7 +59,7 @@ def make_fp_train_step(grower_cfg: GrowerConfig,
         new_score = score + jnp.where(has_split, delta[node_assign], 0.0)
         return new_score, tree
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(None, axis_name), P(), P(), P(), P(), P()),
         out_specs=(P(), P()),

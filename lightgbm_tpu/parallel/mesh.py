@@ -17,27 +17,6 @@ DATA_AXIS = "data"
 FEATURE_AXIS = "feature"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``.
-
-    Newer jax exports ``jax.shard_map`` with a ``check_vma`` flag; older
-    releases (<= 0.4.x) only have ``jax.experimental.shard_map.shard_map``
-    whose equivalent flag is ``check_rep``.  Every sharded program in this
-    package goes through this ONE resolver so a jax upgrade/downgrade is a
-    single-site change instead of a per-call-site hunt."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        try:
-            return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        except TypeError:       # transitional releases: jax.shard_map + check_rep
-            return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def default_mesh(num_devices: Optional[int] = None,
                  axis_name: str = DATA_AXIS,
                  devices: Optional[Sequence] = None) -> jax.sharding.Mesh:

@@ -15,10 +15,6 @@ def emit(**kv):
 
 
 def main():
-    import bench
-    if not bench.probe_backend(300):
-        emit(stage="abort", reason="tpu_unreachable")
-        return 1
     import jax
     import jax.numpy as jnp
     import numpy as np

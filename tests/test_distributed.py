@@ -62,9 +62,8 @@ def grow(bins, g, h, rw, fm, key):
     return grow_tree(bins, g, h, rw, fm, **meta, key=key, cfg=cfg)
 
 
-from lightgbm_tpu.parallel.mesh import shard_map
 
-sharded = shard_map(
+sharded = jax.shard_map(
     grow, mesh=mesh,
     in_specs=(P("dp"), P("dp"), P("dp"), P("dp"), P(), P()),
     out_specs=(P(), P("dp")), check_vma=False)

@@ -193,10 +193,9 @@ print("INTERPRET_OK")
 
 def test_batched_hist_kernel_interpret_parity():
     # the Pallas batched-leaf kernel vs the scatter fallback, in interpret
-    # mode.  Runs in a CLEAN subprocess: the conftest strips non-cpu
-    # backend factories, after which interpret-mode pallas can no longer
-    # register its TPU lowering rules in-process.  (The real TPU lowering
-    # is covered by scripts/bench_dual.py / tpu_perf_suite.py on hardware.)
+    # mode, in a clean subprocess.  (The real TPU lowering is covered by
+    # tests/test_chip_smoke.py's AOT compile and, on the chip, by
+    # chip_smoke.py phase 1.)
     import os
     import subprocess
     import sys

@@ -34,12 +34,17 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # roofline math: peaks, MFU, bound classification
 def test_normalize_chip_and_peak_table():
     assert costs.normalize_chip("TPU v4") == "tpu v4"
-    assert costs.normalize_chip("TPU v5 lite") == "tpu v5 lite"
-    assert costs.normalize_chip("cpu") == "cpu"
-    assert costs.normalize_chip(None) == "cpu"
-    assert costs.normalize_chip("") == "cpu"
-    # unknown accelerator kinds price against the fleet default, not CPU
-    assert costs.normalize_chip("TPU v99x") == costs.DEFAULT_CHIP
+    assert costs.normalize_chip("TPU v5 lite") == "tpu v5 lite"   # a v5e chip
+    # a device the table does not hold is an error, never a default: no CPU
+    # "peak", no pricing of an unknown TPU kind as some other chip
+    assert "cpu" not in costs.PEAK_RATES
+    for kind in ("cpu", None, "", "TPU v99x"):
+        with pytest.raises(KeyError, match="PEAK_RATES"):
+            costs.normalize_chip(kind)
+        with pytest.raises(KeyError):
+            costs.peak_flops(kind)
+    with pytest.raises(KeyError):
+        costs.current_chip()                  # this process runs on the CPU
     for kind, peaks in costs.PEAK_RATES.items():
         assert peaks["flops"] > 0 and peaks["bytes_per_sec"] > 0, kind
         assert costs.ridge_intensity(kind) == pytest.approx(
@@ -82,8 +87,19 @@ def test_ledger_records_jitted_hist_cost_and_memory(tmp_path):
 
     fn = jax.jit(lambda bb, gg: jnp.sum(
         _hist_onehot(bb, gg, gg, ones, b, 65536)))
-    led = costs.CostLedger()
     model_flops = 2.0 * 6 * n * f * b
+    # recorded where it ran (the CPU): the compiler's analysis is kept, but
+    # there is no peak to price a timing against
+    cpu_led = costs.CostLedger()
+    costs.analyze_jitted("test.hist_onehot", fn, bins, g, ledger=cpu_led)
+    assert cpu_led.entry("test.hist_onehot")["chip"] == "cpu"
+    cpu_led.observe("test.hist_onehot", 0.02)
+    with pytest.raises(KeyError, match="PEAK_RATES"):
+        cpu_led.rooflines()
+
+    # the join math, on a ledger told which chip its (synthetic) seconds
+    # are for
+    led = costs.CostLedger(chip="tpu v5e")
     ent = costs.analyze_jitted("test.hist_onehot", fn, bins, g, ledger=led,
                                model_flops=model_flops, rows=n, features=f,
                                max_bin=b)
@@ -123,7 +139,24 @@ def test_ledger_records_jitted_hist_cost_and_memory(tmp_path):
     assert rec["memory"]["peak_bytes"] == mem["peak_bytes"]
 
 
-def test_roofline_report_renders_hist_program(tmp_path):
+# ---------------------------------------------------------------------------
+# watermark gauges during a boosting run (injected stats: CPU has none)
+@pytest.fixture
+def clean_obs_state(tmp_path):
+    obs_metrics.reset()
+    get_tracer().reset()
+    global_timer.reset()
+    saved = costs.get_ledger()
+    costs.reset_ledger()
+    yield str(tmp_path / "train_events.jsonl")
+    costs.set_stats_provider(None)
+    costs._LEDGER = saved
+    global_timer.detach_tracer()
+    get_tracer().reset()
+    obs_metrics.reset()
+
+
+def test_roofline_report_renders_hist_program(tmp_path, clean_obs_state):
     """Acceptance: ``obs-report --roofline`` renders an MFU/roofline row
     for the production hist kernel from journal ``program_cost`` events."""
     import jax
@@ -138,13 +171,12 @@ def test_roofline_report_renders_hist_program(tmp_path):
     fn = jax.jit(lambda bb, gg: jnp.sum(
         _hist_onehot(bb, gg, gg, ones, b, 65536)))
 
-    led = costs.CostLedger()
+    # a rendering test: the chip is named and the seconds are synthetic (a
+    # CPU timing has no peak to be priced against)
+    led = costs.CostLedger(chip="tpu v5e")
     costs.analyze_jitted("bench.hist_onehot", fn, bins, g, ledger=led,
                          model_flops=2.0 * 6 * n * f * b)
-    import time
-    t0 = time.perf_counter()
-    jax.block_until_ready(fn(bins, g))
-    led.observe("bench.hist_onehot", time.perf_counter() - t0)
+    led.observe("bench.hist_onehot", 0.01)
 
     journal = str(tmp_path / "perf.jsonl")
     led.emit(EventLog(journal))
@@ -161,23 +193,6 @@ def test_roofline_report_renders_hist_program(tmp_path):
                             "--format", "json", "--out", outj]) == 0
     rows = json.load(open(outj))["roofline"]
     assert any(r["program"] == "bench.hist_onehot" for r in rows)
-
-
-# ---------------------------------------------------------------------------
-# watermark gauges during a boosting run (injected stats: CPU has none)
-@pytest.fixture
-def clean_obs_state(tmp_path):
-    obs_metrics.reset()
-    get_tracer().reset()
-    global_timer.reset()
-    saved = costs.get_ledger()
-    costs.reset_ledger()
-    yield str(tmp_path / "train_events.jsonl")
-    costs.set_stats_provider(None)
-    costs._LEDGER = saved
-    global_timer.detach_tracer()
-    get_tracer().reset()
-    obs_metrics.reset()
 
 
 def test_watermark_gauges_populate_during_boosting(clean_obs_state):
@@ -206,7 +221,7 @@ def test_watermark_gauges_populate_during_boosting(clean_obs_state):
     ent = led.entry("train.grow_tree")
     assert ent["calls"] >= 1
     assert ent["cost"].get("flops", 0) > 0
-    assert any(r["program"] == "train.grow_tree" for r in led.rooflines())
+    assert ent["chip"] == "cpu"       # named as found; not priceable
 
 
 def test_record_watermarks_empty_when_backend_has_no_stats():
