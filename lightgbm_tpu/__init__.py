@@ -10,6 +10,10 @@ from .utils import compile_cache as _compile_cache
 
 _compile_cache.configure()
 
+from .obs import install_compile_listener as _install_compile_listener  # noqa: E402
+
+_install_compile_listener()     # compilations become lgbm/compile spans
+
 from .basic import Booster, Dataset  # noqa: E402
 from .callback import early_stopping, print_evaluation, log_evaluation, \
     record_evaluation, reset_parameter

@@ -16,6 +16,7 @@ from .config import Config
 from .io.dataset import Dataset as _InnerDataset
 from .models.gbdt import GBDT
 from .models import model_io
+from .obs import get_tracer
 from .utils.log import Log, check, LightGBMError
 
 __all__ = ["Dataset", "Booster", "LightGBMError"]
@@ -326,7 +327,8 @@ class Booster:
             train_set.params = dict(self.params)
             train_set.construct()
             self.pandas_categorical = train_set.pandas_categorical
-            self._gbdt = self._create_engine(cfg, train_set._inner)
+            with get_tracer().span("lgbm/booster/init"):
+                self._gbdt = self._create_engine(cfg, train_set._inner)
             self.name_valid_sets: List[str] = []
         elif model_file is not None:
             with open(model_file) as f:
@@ -443,6 +445,7 @@ class Booster:
                 # iteration) must not retrace the grower
                 gbdt._grower_cfg = new
                 gbdt.__dict__.pop("_grow_jit", None)
+                gbdt._recorded_programs.discard("train.grow_tree")
         return self
 
     def attr(self, key: str):
@@ -610,11 +613,7 @@ class Booster:
             out = []
             # boosters loaded from model text have no training data/metrics
             if getattr(gb, "train_metrics", None) and gb._train_score is not None:
-                score = np.asarray(gb._train_score, np.float64)
-                s = score[0] if gb.num_tree_per_iteration == 1 else score
-                for m in gb.train_metrics:
-                    for mname, val, hib in m.eval(s, gb.objective):
-                        out.append((name, mname, val, hib))
+                out = gb.eval_scores(name, gb._train_score, gb.train_metrics)
         else:
             all_results = self._gbdt.eval_current()
             out = [(n, m, v, h) for (n, m, v, h) in all_results if n == name]
@@ -730,7 +729,11 @@ class Booster:
                    start_iteration: int = 0) -> dict:
         g = self._gbdt
         K = g.num_tree_per_iteration
-        models = g.models
+        with get_tracer().span("lgbm/dump"):
+            models = g.models       # drains: builds the host trees
+            return self._dump_dict(g, K, models)
+
+    def _dump_dict(self, g, K, models) -> dict:
         return {
             "name": "tree",
             "version": "v3",

@@ -367,9 +367,6 @@ class Config:
     # event-sink override; "" = the shared journal (WATCHER_PERF_LOG env
     # var, else the repo-root perf_results.jsonl)
     obs_events_path: str = ""
-    # also wrap spans in jax.profiler Step/TraceAnnotation so host phases
-    # align with XLA ops when a device trace capture is active
-    obs_trace_device: bool = False
     # uniform-reservoir size of the rolling-percentile (p50/p99) histograms
     obs_reservoir_size: int = 512
     # live health plane (obs/health.py): serve /metrics (Prometheus text)

@@ -21,6 +21,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from ..config import Config
+from ..obs.tracer import get_tracer
 from ..utils.log import Log, check, LightGBMError
 from ..utils.random_gen import Random
 from .bin import BinMapper, BinType, MissingType
@@ -142,6 +143,16 @@ class Dataset:
         reference's per-feature sparse bin containers
         (``src/io/sparse_bin.hpp:73``): the DEVICE matrix is the bundled
         dense one, whose width EFB has already collapsed."""
+        with get_tracer().span("lgbm/dataset/construct",
+                               reference=reference is not None):
+            return cls._from_data(data, config, label, weight, group,
+                                  init_score, categorical_feature,
+                                  feature_names, reference)
+
+    @classmethod
+    def _from_data(cls, data, config, label, weight, group, init_score,
+                   categorical_feature, feature_names, reference):
+        span = get_tracer().span    # O(1) spans per construct, none per block
         config = config or Config()
         self = cls(config)
         sparse = _is_sparse(data)
@@ -150,7 +161,8 @@ class Dataset:
             check(not config.linear_tree,
                   "linear_tree with sparse input is not supported")
         else:
-            data = _to_2d_float(data)
+            with span("lgbm/dataset/construct/to_2d_float"):
+                data = _to_2d_float(data)
         self.num_data, self.num_total_features = data.shape
         self.feature_names = _sanitize_feature_names(
             list(feature_names)) if feature_names else [
@@ -169,14 +181,19 @@ class Dataset:
             cats = set(_resolve_categorical(categorical_feature, self.feature_names, config))
             self._construct_bin_mappers(data, cats)
 
-        if sparse:
-            self._bin_data_sparse(data, reference)
-        else:
-            self._bin_data(data)
-            if reference is not None:
-                self._adopt_bundling(reference)
+        # binning: a validation set against its reference's bin mappers, a
+        # training set against its own
+        with span("lgbm/dataset/construct/reference_bin"
+                  if reference is not None
+                  else "lgbm/dataset/construct/bin_values"):
+            if sparse:
+                self._bin_data_sparse(data, reference)
             else:
-                self._apply_bundling()
+                self._bin_data(data)
+                if reference is not None:
+                    self._adopt_bundling(reference)
+                else:
+                    self._apply_bundling()
         if config.linear_tree or (reference is not None
                                   and reference.raw_data is not None):
             self.raw_data = np.asarray(data, np.float32)
@@ -198,24 +215,28 @@ class Dataset:
         n = self.num_data
         # row sampling for bin construction (reference bin_construct_sample_cnt,
         # dataset_loader.cpp SampleTextDataFromFile:902)
+        span = get_tracer().span
         sample_cnt = min(n, cfg.bin_construct_sample_cnt)
-        rng = Random(cfg.data_random_seed)
-        sample_idx = rng.sample(n, sample_cnt)
-        if _is_sparse(data):
-            # column-at-a-time densification: O(sample_cnt) per feature,
-            # never the full [sample, F] dense sample (which for
-            # Allstate-shaped data would itself exceed the binned matrix)
-            sample_csc = data[sample_idx].tocsc()
-            col = lambda f: np.asarray(  # noqa: E731
-                sample_csc[:, [f]].toarray(), np.float64).ravel()
-        else:
-            sample = data[sample_idx]
-            col = lambda f: sample[:, f]  # noqa: E731
+        with span("lgbm/dataset/construct/sample", rows=sample_cnt):
+            rng = Random(cfg.data_random_seed)
+            sample_idx = rng.sample(n, sample_cnt)
+            if _is_sparse(data):
+                # column-at-a-time densification: O(sample_cnt) per feature,
+                # never the full [sample, F] dense sample (which for
+                # Allstate-shaped data would itself exceed the binned matrix)
+                sample_csc = data[sample_idx].tocsc()
+                col = lambda f: np.asarray(  # noqa: E731
+                    sample_csc[:, [f]].toarray(), np.float64).ravel()
+            else:
+                sample = data[sample_idx]
+                col = lambda f: sample[:, f]  # noqa: E731
 
-        self.bin_mappers = [
-            self._find_bin_one(f, col(f), sample_cnt, cats)
-            for f in range(self.num_total_features)]
-        self._finalize_used_features()
+        with span("lgbm/dataset/construct/find_bins",
+                  features=self.num_total_features):
+            self.bin_mappers = [
+                self._find_bin_one(f, col(f), sample_cnt, cats)
+                for f in range(self.num_total_features)]
+            self._finalize_used_features()
 
     def _find_bin_one(self, f: int, values: np.ndarray, sample_cnt: int,
                       cats: set) -> BinMapper:

@@ -14,6 +14,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -22,8 +23,10 @@ import numpy as np
 
 from ..config import Config
 from ..io.dataset import Dataset, DeviceData
-from ..obs import TrainTelemetry
+from ..obs import TrainTelemetry, get_tracer
+from ..obs import costs as obs_costs
 from ..obs import health as obs_health
+from ..obs import metrics as obs_metrics
 from ..metric import create_metrics
 from ..objective import ObjectiveFunction, create_objective
 from ..ops.grower import GrowerConfig, TreeArrays, grow_tree
@@ -31,7 +34,6 @@ from ..ops.predict import predict_leaf_binned
 from ..ops.split import SplitParams
 from ..utils.log import Log, check, LightGBMError
 from ..utils.random_gen import key_for_iteration
-from ..utils.timer import global_timer
 from .tree import Tree
 
 # rows per densified block when predicting on scipy.sparse input: bounds
@@ -80,11 +82,13 @@ class GBDT:
             from ..obs import flight as obs_flight
             obs_flight.install()
         self._health_jit = None
-        self._grow_cost_recorded = False
+        # programs whose cost and device scopes are in the obs tables
+        self._recorded_programs: set = set()
+        self._drained_at = None         # (iteration, time_ns) of the last drain
         self._models: List[Tree] = []
         # deferred host trees: (tree_arrays, shrinkage, bias, iter,
-        # health_stats-or-None) tuples whose device->host copies are in
-        # flight (see `models` property)
+        # health_stats-or-None, frontier_stats-or-None) tuples whose
+        # device->host copies are in flight (see `models` property)
         self._pending: List[tuple] = []
         self._stop_flag = False
         self._empty_by_iter: Dict[int, int] = {}
@@ -130,8 +134,18 @@ class GBDT:
         """Materialize pending device trees (oldest first), leaving at most
         ``keep`` in flight."""
         while len(self._pending) > keep:
-            arrs, shrink, bias, _it, health_dev = self._pending.pop(0)
+            arrs, shrink, bias, _it, health_dev, stats_dev = \
+                self._pending.pop(0)
+            # the host waiting for a tree issued earlier (under update():
+            # the tree before the one just issued); the span carries that
+            # tree's frontier counters, which rode the same async copy
+            tracer = get_tracer()
+            tracer.begin("lgbm/update/drain", tree_iteration=_it)
             host = jax.device_get(arrs)
+            tracer.end("lgbm/update/drain",
+                       **(self._count_frontier(*stats_dev)
+                          if stats_dev is not None else {}))
+            self._observe_drain(_it)
             if health_dev is not None:
                 # sentinel scalars rode the same async materialization —
                 # by now they are computed+copied, so this is a cheap host
@@ -159,6 +173,48 @@ class GBDT:
                 if cnt >= self.num_tree_per_iteration:
                     self._stop_flag = True
 
+    def _observe_drain(self, it: int) -> None:
+        """Drain to drain is what the device took for one iteration's trees
+        (the host runs ahead and waits here): the cost ledger's measured
+        time for the grow program, which the enqueue's host time is not."""
+        now = time.time_ns()
+        last, self._drained_at = self._drained_at, (it, now)
+        if self._obs is not None and last and last[0] == it - 1:
+            obs_costs.get_ledger().observe(
+                "train.grow_tree",
+                (now - last[1]) / 1e9 / self.num_tree_per_iteration)
+
+    @staticmethod
+    def _count_frontier(stats_dev, rows: int) -> Dict[str, int]:
+        """The frontier grower's counters of one tree (``rows`` is what each
+        round passed) into ``obs.metrics``, totals as counters and per tree
+        as histograms; returned for the drain span to carry."""
+        rounds, hi, lo = (int(v) for v in np.asarray(stats_dev))
+        if not rounds:
+            return {}       # the serial grower counts nothing
+        counts = {"rounds": rounds, "rows_passed": rounds * rows,
+                  "rows_selected": (hi << 20) + lo}
+        for name, v in (("train.frontier_rounds", counts["rounds"]),
+                        ("train.rows_passed", counts["rows_passed"]),
+                        ("train.rows_selected", counts["rows_selected"])):
+            obs_metrics.counter(name).inc(v)
+            obs_metrics.histogram(name + "_per_tree").observe(v)
+        return counts
+
+    def _record_program(self, name: str, fn, *args, **meta) -> None:
+        """Once per program and booster, right after its first call: the
+        compiled program's cost into the obs ledger and its operations'
+        scopes into ``obs.device_scopes()``.  jax hands back the trace and
+        the executable it holds (no second compilation); never fatal."""
+        if name in self._recorded_programs:
+            return
+        self._recorded_programs.add(name)
+        try:
+            with get_tracer().span("lgbm/scope_table", program=name):
+                obs_costs.analyze_jitted(name, fn, *args, **meta)
+        except Exception as e:
+            Log.debug("program %s not recorded: %s", name, e)
+
     # ------------------------------------------------------------------
     def init_train(self, train_data: Dataset) -> None:
         cfg = self.config
@@ -174,11 +230,15 @@ class GBDT:
         self.train_metrics = create_metrics(cfg)
         for m in self.train_metrics:
             m.init(train_data.metadata, train_data.num_data)
-        self._dd = train_data.device_data()
-        self._label_dev = (jnp.asarray(train_data.metadata.label)
-                          if train_data.metadata.label is not None else None)
-        self._weight_dev = (jnp.asarray(train_data.metadata.weight)
-                           if train_data.metadata.weight is not None else None)
+        tracer = get_tracer()
+        with tracer.span("lgbm/booster/init/to_device", what="bins,labels"):
+            self._dd = train_data.device_data()
+            self._label_dev = (
+                jnp.asarray(train_data.metadata.label)
+                if train_data.metadata.label is not None else None)
+            self._weight_dev = (
+                jnp.asarray(train_data.metadata.weight)
+                if train_data.metadata.weight is not None else None)
         K = self.num_tree_per_iteration
         n = train_data.num_data
 
@@ -193,7 +253,8 @@ class GBDT:
                 s = self.objective.boost_from_score(k)
                 self.init_scores[k] = s
                 init[k] += s
-        self._train_score = jnp.asarray(init)
+        with tracer.span("lgbm/booster/init/to_device", what="scores"):
+            self._train_score = jnp.asarray(init)
         self._grower_cfg = self._make_grower_cfg()
         self._setup_parallel()
         gc = self._grower_cfg
@@ -321,8 +382,11 @@ class GBDT:
             from ..ops import onehot_variants as _ov
             kernel_bins = self._dd.bundle_bins or max_bin
             if cfg.hist_variant == "auto":
+                tracer = get_tracer()
+                tracer.begin("lgbm/booster/init/election")
                 hist_variant = _ov.pick_variant(
                     kernel_bins, self.train_data.num_features)
+                tracer.end("lgbm/booster/init/election", variant=hist_variant)
             else:
                 hist_variant = _ov.resolve(cfg.hist_variant, kernel_bins)
         else:
@@ -541,7 +605,10 @@ class GBDT:
         else:
             for k in range(K):
                 init[k] += self.init_scores[k]
-        self._valid_scores.append(jnp.asarray(init))
+        with get_tracer().span("lgbm/booster/init/to_device",
+                               what="valid bins,scores"):
+            valid_data.device_data()
+            self._valid_scores.append(jnp.asarray(init))
 
     # ------------------------------------------------------------------
     # bagging (gbdt.cpp:182-262); subclasses (GOSS) override
@@ -555,10 +622,23 @@ class GBDT:
             return None, grad, hess
         if iteration % cfg.bagging_freq == 0:
             key = key_for_iteration(cfg.bagging_seed, iteration // cfg.bagging_freq)
-            self._bag_mask = bag_mask_from_uniform(
-                cfg, jax.random.uniform(key, (n,)), self._label_dev)
+            self._bag_mask = self._sample_jit(key, self._label_dev)
+            self._record_program("train.sample", self._sample_jit, key,
+                                 self._label_dev)
         mask = self._bag_mask
         return mask, grad * mask, hess * mask
+
+    @functools.cached_property
+    def _sample_jit(self):
+        cfg = self.config
+        n = self.train_data.num_data
+
+        @jax.jit
+        @jax.named_scope("lgbm/sample")
+        def bag_sample(key, label):
+            return bag_mask_from_uniform(cfg, jax.random.uniform(key, (n,)),
+                                         label)
+        return bag_sample
 
     # -- bagging subset (reference CopySubrow, gbdt.cpp:256): when bagging
     # drops a material fraction of rows, compact the survivors into a
@@ -601,6 +681,7 @@ class GBDT:
         n = self.train_data.num_data
 
         @functools.partial(jax.jit, static_argnums=2)
+        @jax.named_scope("lgbm/sample")
         def fn(mask, bins, cap):
             cs = jnp.cumsum((mask > 0).astype(jnp.int32))
             targets = jnp.arange(1, cap + 1, dtype=jnp.int32)
@@ -638,21 +719,37 @@ class GBDT:
                         "that meet the split requirements")
             return True
 
+        # host spans (always recorded, a dozen a tree): what the host spent
+        # ISSUING each step of an asynchronous program and, under
+        # lgbm/update/drain, waiting for the device; the device's own time by
+        # phase is in a profiler trace, read through obs.device_scopes()
         obs = self._obs
+        tracer = get_tracer()
+        tracer.begin("lgbm/update", iteration=it)
+        try:
+            should_stop = self._train_one_iter(grad, hess, it, tracer)
+        finally:
+            tracer.end("lgbm/update")
         if obs is not None:
-            obs.phase_mark()
-            # the global_timer scopes below nest under this span (the
-            # timer->tracer bridge), giving Perfetto the train-loop tree
-            obs.tracer.begin("train/iteration", step=it)
+            obs.iteration_event(it, trees=K)
+        elif self._health_enabled:
+            obs_health.set_status(stage="train", iteration=it)
+        return should_stop
 
-        with global_timer.scope("GBDT::gradients"):
+    def _train_one_iter(self, grad, hess, it: int, tracer) -> bool:
+        cfg = self.config
+        K = self.num_tree_per_iteration
+        n = self.train_data.num_data
+        obs = self._obs
+        with tracer.span("lgbm/update/gradients"):
             if grad is None or hess is None:
                 g, h = self._compute_gradients(self._train_score)
             else:
                 g = jnp.asarray(np.asarray(grad, np.float32).reshape(K, n))
                 h = jnp.asarray(np.asarray(hess, np.float32).reshape(K, n))
 
-        bag_mask, g, h = self._bagging_weights(it, g, h)
+        with tracer.span("lgbm/update/sample"):
+            bag_mask, g, h = self._bagging_weights(it, g, h)
         row_weight = bag_mask if bag_mask is not None else jnp.ones(n, jnp.float32)
         fmask = self._feature_mask(it)
         self._prev_scores = (self._train_score, list(self._valid_scores))
@@ -669,20 +766,20 @@ class GBDT:
 
         should_stop = True
         for k in range(K):
-            with global_timer.scope("GBDT::grow_tree"):
+            with tracer.span("lgbm/update/grow_dispatch"):
                 cegb_coupled, cegb_used = self._cegb_state()
-                tree_arrays, node_assign = self._grow_jit(
-                    self._dd.bins, g[k], h[k], row_weight, fmask,
-                    key_for_iteration(cfg.seed, it, salt=k + 1),
-                    cegb_coupled, cegb_used)
-            if obs is not None and not self._grow_cost_recorded:
-                self._ledger_grow_cost(
-                    self._dd.bins, g[k], h[k], row_weight, fmask,
-                    key_for_iteration(cfg.seed, it, salt=k + 1),
-                    cegb_coupled, cegb_used)
+                grow_args = (self._dd.bins, g[k], h[k], row_weight, fmask,
+                             key_for_iteration(cfg.seed, it, salt=k + 1),
+                             cegb_coupled, cegb_used)
+                tree_arrays, node_assign, stats_dev = self._grow_jit(
+                    *grow_args)
+            self._record_grow_program(grow_args)
             # ONE host fetch for the whole tree, not one blocking
             # np.asarray per field
+            tracer.begin("lgbm/update/drain", tree_iteration=it)
             tree_host = jax.device_get(tree_arrays)
+            tracer.end("lgbm/update/drain",
+                       **self._count_frontier(stats_dev, n))
             if self._health_due(it, k):
                 # the slow path already syncs per tree; check in line
                 self._run_numeric_check(it, self._health_stats_fn()(
@@ -730,7 +827,7 @@ class GBDT:
                     if tree.is_linear:
                         tree.leaf_const = np.asarray(tree.leaf_value, np.float64).copy()
 
-            with global_timer.scope("GBDT::update_score"):
+            with tracer.span("lgbm/update/score_dispatch"):
                 delta = tree_arrays.leaf_value * self.shrinkage_rate
                 if linear_dev is not None:
                     from ..ops.linear import linear_leaf_delta
@@ -758,11 +855,6 @@ class GBDT:
             self._tree_weights.append(self.shrinkage_rate)
 
         self.iter_ += 1
-        if obs is not None:
-            obs.tracer.end("train/iteration")
-            obs.iteration_event(it, trees=K)
-        elif self._health_enabled:
-            obs_health.set_status(stage="train", iteration=it)
         if should_stop:
             Log.warning("Stopped training because there are no more leaves "
                         "that meet the split requirements")
@@ -782,29 +874,35 @@ class GBDT:
                     or getattr(self, "_bag_sub", None) is None):
                 self._bag_sub = self._bag_compact_jit(bag_mask, self._dd.bins,
                                                       cap)
+                self._record_program("train.sample_compact",
+                                     self._bag_compact_jit, bag_mask,
+                                     self._dd.bins, cap)
             bag_rows, bag_rw, bag_bins = self._bag_sub
+        tracer = get_tracer()
+        shrink = self.shrinkage_rate
         for k in range(K):
-            with global_timer.scope("GBDT::grow_tree"):
+            with tracer.span("lgbm/update/grow_dispatch"):
+                key = key_for_iteration(cfg.seed, it, salt=k + 1)
                 if cap is not None:
                     # grow over the compacted bag; leaf assignment for the
                     # FULL training set comes from one binned traversal
-                    tree_arrays, _ = self._grow_jit(
-                        bag_bins, jnp.take(g[k], bag_rows),
-                        jnp.take(h[k], bag_rows), bag_rw, fmask,
-                        key_for_iteration(cfg.seed, it, salt=k + 1),
-                        None, None)
+                    grow_args = (bag_bins, jnp.take(g[k], bag_rows),
+                                 jnp.take(h[k], bag_rows), bag_rw, fmask,
+                                 key, None, None)
+                    tree_arrays, _, stats_dev = self._grow_jit(*grow_args)
                     node_assign = self._predict_leaf_jit(tree_arrays,
                                                          self._dd.bins)
+                    self._record_program("train.bag_traverse",
+                                         self._predict_leaf_jit, tree_arrays,
+                                         self._dd.bins)
                 else:
-                    tree_arrays, node_assign = self._grow_jit(
-                        self._dd.bins, g[k], h[k], row_weight, fmask,
-                        key_for_iteration(cfg.seed, it, salt=k + 1), None, None)
-            if (self._obs is not None and not self._grow_cost_recorded
-                    and cap is None):
-                self._ledger_grow_cost(
-                    self._dd.bins, g[k], h[k], row_weight, fmask,
-                    key_for_iteration(cfg.seed, it, salt=k + 1), None, None)
-            jax.tree.map(lambda a: a.copy_to_host_async(), tree_arrays)
+                    grow_args = (self._dd.bins, g[k], h[k], row_weight,
+                                 fmask, key, None, None)
+                    tree_arrays, node_assign, stats_dev = self._grow_jit(
+                        *grow_args)
+            self._record_grow_program(grow_args)
+            jax.tree.map(lambda a: a.copy_to_host_async(),
+                         (tree_arrays, stats_dev))
             health_dev = None
             if self._health_due(it, k):
                 # sentinel reductions ride the same async materialization:
@@ -814,29 +912,29 @@ class GBDT:
                 jax.tree.map(lambda a: a.copy_to_host_async(), health_dev)
             bias = (self.init_scores[k]
                     if it == 0 and self.init_scores[k] != 0.0 else 0.0)
-            self._pending.append((tree_arrays, self.shrinkage_rate, bias, it,
-                                  health_dev))
-            with global_timer.scope("GBDT::update_score"):
-                gate = tree_arrays.num_leaves > 1
-                delta = tree_arrays.leaf_value * self.shrinkage_rate
-                self._train_score = self._train_score.at[k].add(
-                    jnp.where(gate, delta[node_assign], 0.0))
+            self._pending.append((tree_arrays, shrink, bias, it, health_dev,
+                                  (stats_dev, int(grow_args[0].shape[0]))))
+            with tracer.span("lgbm/update/score_dispatch"):
+                # the product apart from the sum, as the serial path has it:
+                # in one program the compiler may round them once (an FMA)
+                delta = tree_arrays.leaf_value * shrink
+                score_args = (self._train_score, delta,
+                              tree_arrays.num_leaves, node_assign, k)
+                self._train_score = self._score_update_jit(*score_args)
+                self._record_program("train.score_update",
+                                     self._score_update_jit, *score_args)
                 for vi, vset in enumerate(self.valid_sets):
-                    vleaf = self._predict_leaf_jit(tree_arrays,
-                                                   vset.device_data().bins)
-                    self._valid_scores[vi] = self._valid_scores[vi].at[k].add(
-                        jnp.where(gate, delta[vleaf], 0.0))
+                    valid_args = (self._valid_scores[vi], tree_arrays, delta,
+                                  vset.device_data().bins, k)
+                    self._valid_scores[vi] = self._valid_update_jit(
+                        *valid_args)
+                    self._record_program(f"train.valid_update.{vi}",
+                                         self._valid_update_jit, *valid_args)
             self._device_trees.append(tree_arrays)
-            self._tree_weights.append(self.shrinkage_rate)
+            self._tree_weights.append(shrink)
         self.iter_ += 1
-        if self._obs is not None:
-            # iteration event here, per-tree split-gain events from
-            # _drain_pending when the async host copies land — telemetry
-            # must not add a device sync to the fast path
-            self._obs.tracer.end("train/iteration")
-            self._obs.iteration_event(it, trees=K)
-        elif self._health_enabled:
-            obs_health.set_status(stage="train", iteration=it)
+        # per-tree split-gain events come from _drain_pending when the async
+        # host copies land: telemetry must not add a device sync here.
         # keep one iteration in flight: draining then blocks only on the
         # PREVIOUS iteration's device work (host stays a full iteration
         # ahead) and its async device->host copy has typically landed, so
@@ -889,24 +987,59 @@ class GBDT:
             raise LightGBMError("objective is None; provide custom grad/hess")
         if self.num_tree_per_iteration > 1:
             return obj.get_gradients_multi(score, self._label_dev, self._weight_dev)
+        if obj.pure_gradients:
+            # one compiled program, so its operations carry lgbm/gradients
+            args = (score, self._label_dev, self._weight_dev)
+            out = self._gradients_jit(*args)
+            self._record_program("train.gradients", self._gradients_jit,
+                                 *args)
+            return out
         g, h = obj.get_gradients(score[0], self._label_dev, self._weight_dev)
         return g[None, :], h[None, :]
 
-    def _ledger_grow_cost(self, *args) -> None:
-        """One-time XLA cost/memory capture of the compiled grow program
-        into the obs cost ledger (``train.grow_tree``): re-lowering costs
-        one retrace, ``compile()`` hits the executable cache, and the
-        telemetry loop joins per-iteration grow seconds against it.
-        Never fatal — attribution must not break training."""
-        self._grow_cost_recorded = True
-        try:
-            from ..obs import costs as obs_costs
-            bins = args[0]
-            obs_costs.analyze_jitted(
-                "train.grow_tree", self._grow_jit, *args,
-                rows=int(bins.shape[0]), features=int(bins.shape[1]))
-        except Exception:
-            pass
+    @functools.cached_property
+    def _gradients_jit(self):
+        obj = self.objective
+
+        @jax.jit
+        @jax.named_scope("lgbm/gradients")
+        def gradients(score, label, weight):
+            g, h = obj.get_gradients(score[0], label, weight)
+            return g[None, :], h[None, :]
+        return gradients
+
+    def _record_grow_program(self, args) -> None:
+        bins = args[0]
+        self._record_program("train.grow_tree", self._grow_jit, *args,
+                             rows=int(bins.shape[0]),
+                             features=int(bins.shape[1]))
+
+    @functools.cached_property
+    def _score_update_jit(self):
+        """``score[k] += delta[node_assign]`` (``delta``: the shrunk leaf
+        values), nothing for a tree that did not split (its one leaf holds
+        no value)."""
+        @functools.partial(jax.jit, static_argnums=4)
+        @jax.named_scope("lgbm/score_update")
+        def score_update(score, delta, num_leaves, node_assign, k):
+            return score.at[k].add(
+                jnp.where(num_leaves > 1, delta[node_assign], 0.0))
+        return score_update
+
+    @functools.cached_property
+    def _valid_update_jit(self):
+        """One validation set's rows down the new tree (binned traversal) and
+        that set's score update."""
+        dd = self._dd
+
+        @functools.partial(jax.jit, static_argnums=4)
+        @jax.named_scope("lgbm/valid_traverse")
+        def valid_update(score, tree_arrays, delta, bins, k):
+            leaf = predict_leaf_binned(tree_arrays, bins, dd.nan_bins,
+                                       efb=dd.efb)
+            return score.at[k].add(
+                jnp.where(tree_arrays.num_leaves > 1, delta[leaf], 0.0))
+        return valid_update
 
     @functools.cached_property
     def _grow_jit(self):
@@ -920,7 +1053,8 @@ class GBDT:
 
         if mesh is None:
             @jax.jit
-            def fn(bins, g, h, rw, fmask, key, cegb_coupled, cegb_used):
+            def grow_tree_step(bins, g, h, rw, fmask, key, cegb_coupled,
+                               cegb_used):
                 return grow_tree(bins, g, h, rw, fmask, dd.num_bins,
                                  dd.default_bins, dd.nan_bins,
                                  dd.is_categorical, dd.monotone, key, cfg,
@@ -928,8 +1062,8 @@ class GBDT:
                                  cegb_coupled=cegb_coupled,
                                  cegb_lazy=lazy, cegb_used_data=cegb_used,
                                  forced=forced, efb=dd.efb,
-                                 feature_contri=contri)
-            return fn
+                                 feature_contri=contri, with_stats=True)
+            return grow_tree_step
 
         # parallel learners: the same grow_tree program under shard_map, with
         # rows (data/voting) or features (feature) sharded over the mesh and
@@ -962,12 +1096,13 @@ class GBDT:
                                  nan_bins, is_cat, mono, key, cfg,
                                  interaction_sets=inter_p, cegb_coupled=cc,
                                  cegb_lazy=lazy_p, cegb_used_data=cu,
-                                 forced=forced, feature_contri=contri_p)
+                                 forced=forced, feature_contri=contri_p,
+                                 with_stats=True)
 
             sharded = jax.shard_map(
                 grow, mesh=mesh,
                 in_specs=(P(None, axis), P(), P(), P(), P(), P(), P(), P()),
-                out_specs=(P(), P()), check_vma=False)
+                out_specs=(P(), P(), P()), check_vma=False)
 
             @jax.jit
             def fn(bins, g, h, rw, fmask, key, cegb_coupled, cegb_used):
@@ -991,13 +1126,13 @@ class GBDT:
                              dd.monotone, key, cfg, interaction_sets=inter,
                              cegb_coupled=cc, cegb_lazy=lazy,
                              cegb_used_data=cu, forced=forced, efb=dd.efb,
-                             feature_contri=contri)
+                             feature_contri=contri, with_stats=True)
 
         sharded = jax.shard_map(
             grow, mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(), P(),
                       P(axis)),
-            out_specs=(P(), P(axis)), check_vma=False)
+            out_specs=(P(), P(axis), P()), check_vma=False)
 
         @jax.jit
         def fn(bins, g, h, rw, fmask, key, cegb_coupled, cegb_used):
@@ -1010,9 +1145,9 @@ class GBDT:
                 rw = jnp.pad(rw, (0, n_pad))
                 if cegb_used is not None:
                     cegb_used = jnp.pad(cegb_used, ((0, n_pad), (0, 0)))
-            tree, na = sharded(bins, g, h, rw, fmask, key,
-                               cegb_coupled, cegb_used)
-            return tree, (na[:n] if n_pad else na)
+            tree, na, stats = sharded(bins, g, h, rw, fmask, key,
+                                      cegb_coupled, cegb_used)
+            return tree, (na[:n] if n_pad else na), stats
         return fn
 
     def _cegb_state(self):
@@ -1067,11 +1202,14 @@ class GBDT:
     def _predict_leaf_jit(self):
         dd = self._dd
 
+        # rows the grower did not pass, down the new tree: a validation set
+        # on the serial path, the whole training set where a bag was grown
         @jax.jit
-        def fn(tree_arrays, bins):
+        @jax.named_scope("lgbm/valid_traverse")
+        def predict_leaf(tree_arrays, bins):
             return predict_leaf_binned(tree_arrays, bins, dd.nan_bins,
                                        efb=dd.efb)
-        return fn
+        return predict_leaf
 
     # ------------------------------------------------------------------
     def eval_current(self) -> List[Tuple[str, str, float, bool]]:
@@ -1079,17 +1217,34 @@ class GBDT:
         Returns (dataset_name, metric_name, value, higher_better)."""
         out = []
         if self.config.is_provide_training_metric and self.train_metrics:
-            score = np.asarray(self._train_score, np.float64)
+            out += self.eval_scores(self.train_data_name, self._train_score,
+                                    self.train_metrics)
+        for vi in range(len(self.valid_sets)):
+            out += self.eval_scores(self.valid_names[vi],
+                                    self._valid_scores[vi],
+                                    self.valid_metrics[vi])
+        return out
+
+    def eval_scores(self, data_name: str, score_dev, metrics) -> List[tuple]:
+        """One data set's metrics over its device scores, as ``lgbm/eval``
+        with three kinds of children: ``wait`` (the device finishing the
+        scores' last update, a whole tree where the host ran ahead), ``fetch``
+        (the device-to-host copy and the float64 cast) and one ``metric``
+        each."""
+        out = []
+        tracer = get_tracer()
+        with tracer.span("lgbm/eval", iteration=self.iter_ - 1,
+                         data=data_name):
+            with tracer.span("lgbm/eval/wait"):
+                jax.block_until_ready(score_dev)
+            with tracer.span("lgbm/eval/fetch"):
+                score = np.asarray(score_dev, np.float64)
             s = score[0] if self.num_tree_per_iteration == 1 else score
-            for m in self.train_metrics:
-                for name, val, hib in m.eval(s, self.objective):
-                    out.append((self.train_data_name, name, val, hib))
-        for vi, vset in enumerate(self.valid_sets):
-            score = np.asarray(self._valid_scores[vi], np.float64)
-            s = score[0] if self.num_tree_per_iteration == 1 else score
-            for m in self.valid_metrics[vi]:
-                for name, val, hib in m.eval(s, self.objective):
-                    out.append((self.valid_names[vi], name, val, hib))
+            for m in metrics:
+                with tracer.span("lgbm/eval/metric",
+                                 metric=type(m).__name__):
+                    for name, val, hib in m.eval(s, self.objective):
+                        out.append((data_name, name, val, hib))
         return out
 
     # ------------------------------------------------------------------

@@ -7,6 +7,8 @@ device-side ``top_k`` + masked scaling instead of a partial sort.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,17 +39,29 @@ def goss_mask_from_importance(cfg, imp, u, k_top: int):
 class GOSS(GBDT):
     def _bagging_weights(self, iteration, grad, hess):
         cfg = self.config
-        n = self.train_data.num_data
         if cfg.top_rate + cfg.other_rate >= 1.0:
             return None, grad, hess
-        # importance = sum over classes of |g*h| (goss.hpp:115)
-        imp = jnp.sum(jnp.abs(grad * hess), axis=0)
         key = key_for_iteration(cfg.bagging_seed, iteration)
-        mask, amplify = goss_mask_from_importance(
-            cfg, imp, jax.random.uniform(key, (n,)),
-            max(1, int(cfg.top_rate * n)))
-        amplify = amplify[None, :]
-        return mask, grad * amplify, hess * amplify
+        out = self._goss_jit(grad, hess, key)
+        self._record_program("train.sample", self._goss_jit, grad, hess, key)
+        return out
+
+    @functools.cached_property
+    def _goss_jit(self):
+        cfg = self.config
+        n = self.train_data.num_data
+
+        @jax.jit
+        @jax.named_scope("lgbm/sample")
+        def goss_sample(grad, hess, key):
+            # importance = sum over classes of |g*h| (goss.hpp:115)
+            imp = jnp.sum(jnp.abs(grad * hess), axis=0)
+            mask, amplify = goss_mask_from_importance(
+                cfg, imp, jax.random.uniform(key, (n,)),
+                max(1, int(cfg.top_rate * n)))
+            amplify = amplify[None, :]
+            return mask, grad * amplify, hess * amplify
+        return goss_sample
 
     # -- bagging-subset compaction (models/gbdt.py): GOSS keeps
     # top_rate + ~other_rate of the rows and re-bags EVERY iteration, so the

@@ -20,6 +20,11 @@ from ..config import Config
 
 class ObjectiveFunction:
     name: str = "base"
+    #: ``get_gradients`` is a pure function of its arguments and of values
+    #: fixed at ``init`` (no state that moves between calls, no host work):
+    #: the booster may compile it once into one program.  False unless a
+    #: class says otherwise; a subclass that adds state must reset it.
+    pure_gradients: bool = False
 
     def __init__(self, config: Config):
         self.config = config

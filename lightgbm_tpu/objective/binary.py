@@ -12,6 +12,7 @@ from ..utils.log import Log
 
 class BinaryLogloss(ObjectiveFunction):
     name = "binary"
+    pure_gradients = True
 
     def __init__(self, config, is_unbalance=None):
         super().__init__(config)

@@ -8,21 +8,23 @@ them without importing jax:
   jsonl :class:`~.events.EventLog` behind ``perf_results.jsonl``;
 - :mod:`.metrics` — process-wide counters/gauges/reservoir-percentile
   histograms, snapshottable on demand;
-- :mod:`.tracer` — nested, thread-safe spans exporting Chrome trace JSON
-  and (optionally) riding ``jax.profiler`` annotations;
+- :mod:`.tracer` — nested, thread-safe host spans, always recorded in a
+  bounded ring on the profiler's clock, each also a ``jax.profiler``
+  annotation; compilations become ``lgbm/compile`` spans;
+- :mod:`.scopes` — the ``jax.named_scope`` names of the device phases and
+  :func:`device_scopes`, the table that reads a device trace by them;
 - :mod:`.report` — the ``python -m lightgbm_tpu obs-report`` renderer.
 
 :class:`TrainTelemetry` is the glue the boosting loops hold: one object
-wiring config knobs (``obs_telemetry``, ``obs_events_path``,
-``obs_trace_device``) to an event log, the metrics registry, the global
-tracer, and the ``global_timer`` -> tracer span bridge.
+wiring config knobs (``obs_telemetry``, ``obs_events_path``) to an event
+log, the metrics registry and the global tracer.
 """
 from __future__ import annotations
 
 import os
 from typing import Any, Dict, List, Optional
 
-from . import costs, flight, health, regress
+from . import costs, flight, health, regress, scopes
 from .costs import CostLedger, get_ledger
 from .events import (EventLog, SCHEMA_VERSION, classify_record, make_event,
                      new_run_id, perf_log_path, validate_event)
@@ -30,13 +32,15 @@ from .flight import FlightRecorder
 from .health import DivergenceError, SLOMonitor
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       get_registry)
-from .tracer import Span, Tracer, get_tracer
+from .scopes import device_scopes
+from .tracer import Span, Tracer, get_tracer, install_compile_listener
 
 __all__ = ["EventLog", "SCHEMA_VERSION", "classify_record", "make_event",
            "new_run_id", "perf_log_path", "validate_event",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "get_registry", "Span", "Tracer", "get_tracer",
-           "costs", "regress", "CostLedger", "get_ledger",
+           "install_compile_listener", "device_scopes",
+           "costs", "regress", "scopes", "CostLedger", "get_ledger",
            "flight", "health", "FlightRecorder", "DivergenceError",
            "SLOMonitor", "TrainTelemetry"]
 
@@ -47,19 +51,10 @@ class TrainTelemetry:
     one attribute check per iteration).
 
     Wires the config to the subsystem: events go to ``obs_events_path``
-    (default: the shared perf journal), per-iteration seconds feed named
-    histograms in the process registry, and ``global_timer`` scopes are
-    bridged into the global tracer so the existing ``GBDT::*`` /
-    ``StreamGBDT::*`` scopes become nested spans under each iteration's
-    ``train/iteration`` span (with ``jax.profiler`` step annotation when
-    ``obs_trace_device`` is set and a capture is active).
+    (default: the shared perf journal) and per-iteration span seconds feed
+    named histograms in the process registry.  The spans themselves need no
+    telemetry: the global tracer records them always.
     """
-
-    #: the global_timer scope names whose per-iteration deltas are
-    #: reported as phase seconds (in-HBM and streaming loops)
-    PHASE_SCOPES = ("GBDT::gradients", "GBDT::grow_tree",
-                    "GBDT::update_score", "StreamGBDT::gradients",
-                    "StreamGBDT::grow_tree", "StreamGBDT::update_score")
 
     def __init__(self, config: Any, kind: str = "train"):
         self.kind = kind
@@ -69,12 +64,6 @@ class TrainTelemetry:
         self.metrics = get_registry()
         self.reservoir = int(getattr(config, "obs_reservoir_size", 512))
         self.tracer = get_tracer()
-        self.tracer.annotate_device = bool(
-            getattr(config, "obs_trace_device", False))
-        from ..utils.timer import global_timer
-        self._timer = global_timer
-        global_timer.attach_tracer(self.tracer)
-        self._phase_base: Dict[str, float] = {}
         # health plane: arm the flight recorder (dump lands beside the
         # journal unless LGBM_FLIGHT_DIR redirects it), publish the run
         # on the status board, start the exposition server when enabled
@@ -84,47 +73,37 @@ class TrainTelemetry:
         health.maybe_start(getattr(config, "obs_health_port", 0))
 
     # ------------------------------------------------------------------
-    def step(self, it: int):
-        """Context for one boosting iteration: a ``train/iteration`` span
-        (StepTraceAnnotation-backed when device tracing is on)."""
-        return self.tracer.step("train/iteration", step=it)
-
-    def phase_mark(self) -> None:
-        """Remember the timer's accumulators at iteration start; the
-        iteration event reports the deltas (the jitted growers are one
-        compiled program, so phase seconds come from the host scopes)."""
-        self._phase_base = {n: self._timer.seconds(n)
-                            for n in self.PHASE_SCOPES}
-
-    def phase_seconds(self) -> Dict[str, float]:
-        out = {}
-        for n in self.PHASE_SCOPES:
-            dt = self._timer.seconds(n) - self._phase_base.get(n, 0.0)
-            if dt > 0.0:
-                short = n.split("::", 1)[-1]
-                out[short] = round(dt, 6)
+    def span_seconds(self, it: int) -> Dict[str, float]:
+        """Seconds of the ``lgbm/update`` span of iteration ``it`` and of its
+        children, by span name.  Host seconds: what the host spent issuing
+        (``grow_dispatch``, ``score_dispatch``) and waiting
+        (``drain``), not what the device spent on a phase."""
+        out: Dict[str, float] = {}
+        for s in reversed(self.tracer.spans()):
+            if s.iteration is not None and s.iteration < it:
+                break
+            if s.iteration == it and s.name.startswith("lgbm/update"):
+                out[s.name] = round(out.get(s.name, 0.0) + s.duration, 6)
         return out
 
-    # ------------------------------------------------------------------
     def iteration_event(self, it: int, *, trees: int,
                         extra: Optional[Dict[str, Any]] = None) -> None:
         """Emit the per-iteration training event + update metrics."""
-        phases = self.phase_seconds()
+        spans = self.span_seconds(it)
         self.metrics.counter(f"{self.kind}.iterations").inc()
-        for name, secs in phases.items():
-            self.metrics.histogram(f"{self.kind}.{name}_seconds",
-                                   self.reservoir).observe(secs)
+        for name, secs in spans.items():
+            # lgbm/update/grow_dispatch -> train.grow_dispatch_seconds
+            self.metrics.histogram(
+                f"{self.kind}.{name.rsplit('/', 1)[-1]}_seconds",
+                self.reservoir).observe(secs)
         rec: Dict[str, Any] = {"iteration": it, "trees": trees,
-                               "phase_seconds": phases}
+                               "span_seconds": spans}
         # device-memory watermarks (local stats read, no device sync; CPU
         # publishes none and the helper degrades to {}) + the cost-ledger
         # wall-time join for the recorded grow program
         wm = costs.record_watermarks(self.kind, self.metrics)
         if wm:
             rec["device_memory"] = wm
-        if "grow_tree" in phases:
-            get_ledger().observe(f"{self.kind}.grow_tree",
-                                 phases["grow_tree"])
         if extra:
             rec.update(extra)
         self.log.emit(f"{self.kind}_iter", **rec)
@@ -155,6 +134,3 @@ class TrainTelemetry:
             self.metrics.histogram(f"{self.kind}.split_gain",
                                    self.reservoir).observe(max(gains))
         self.log.emit(f"{self.kind}_tree", **rec)
-
-    def close(self) -> None:
-        self._timer.detach_tracer()

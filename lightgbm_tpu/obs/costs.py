@@ -361,10 +361,14 @@ def reset_ledger() -> CostLedger:
 def analyze_jitted(name: str, fn: Callable, *args: Any,
                    ledger: Optional[CostLedger] = None,
                    **record_kw: Any) -> Dict[str, Any]:
-    """Lower+compile ``fn`` AOT on ``args`` and record its analysis under
-    ``name``.  For an already-jitted ``fn`` the compile is an executable
-    cache hit, so the cost is one retrace.  Returns the ledger entry."""
+    """Lower+compile ``fn`` AOT on ``args``, record its analysis under
+    ``name`` and read its operations' scopes into the device-scope table
+    (``obs.scopes``).  For an ``fn`` that was just called on the same
+    arguments jax hands back the trace and the executable it holds: no
+    second compilation.  Returns the ledger entry."""
     import jax
+    from . import scopes
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
     compiled = jitted.lower(*args).compile()
+    scopes.record_compiled(compiled)
     return (ledger or get_ledger()).record(name, compiled, **record_kw)

@@ -61,14 +61,23 @@ POS_INF = -NEG_INF
 
 def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                        num_bins, default_bins, nan_bins, is_categorical,
-                       monotone, key, cfg, efb=None, feature_contri=None
-                       ) -> Tuple["TreeArrays", jax.Array]:
+                       monotone, key, cfg, efb=None, feature_contri=None,
+                       with_stats=False):
     """Grow one tree with round-batched best-first expansion.
 
     Same contract as ``grower.grow_tree`` (returns ``(TreeArrays,
     node_assignment)``) for the eligible feature subset; trees are
     identical to the sequential grower's up to float-summation order in
     histograms and tie-breaks between exactly-equal gains.
+
+    ``with_stats`` adds a third result, ``int32[3]``: the frontier rounds the
+    tree took and the rows of the leaves those rounds split, as
+    ``hi * 2**20 + lo`` (no 64-bit integers without x64; exact up to 2047
+    rounds of 2**31 rows).  Each round passes every one of the ``n`` rows
+    whatever it splits, so the two say what share of that work was useful.
+
+    The device phases carry ``jax.named_scope`` names (``obs/scopes.py``
+    lists them): compile-time metadata, nothing at run time.
     """
     from .grower import TreeArrays, _BestSplits
 
@@ -272,302 +281,325 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             internal_value=jnp.zeros(L - 1, jnp.float32),
             internal_count=jnp.zeros(L - 1, jnp.float32),
             num_leaves=jnp.int32(1))
-        return empty, jnp.zeros(n, jnp.int32)
+        na = jnp.zeros(n, jnp.int32)
+        return (empty, na, jnp.zeros(3, jnp.int32)) if with_stats \
+            else (empty, na)
 
     # ---- root -------------------------------------------------------------
-    root_hist = reduce_hist(
-        build_histogram(bins, grad, hess, row_weight, Bb,
-                        method=cfg.hist_method,
-                        chunk_rows=cfg.hist_chunk_rows,
-                        variant=cfg.hist_variant))
-    tot = jnp.stack([jnp.sum(grad * row_weight), jnp.sum(hess * row_weight),
-                     jnp.sum(row_weight)])
-    if mode in ("data", "voting"):
-        # feature mode replicates rows, so local sums are already global
-        tot = jax.lax.psum(tot, axis)
-    root_split = find(expand_hist(root_hist), tot[0], tot[1], tot[2],
-                      fmask=node_mask_for(0), rand=rand_thr_for(0),
-                      mult=mult_for(0))
+    with jax.named_scope("lgbm/root"):
+        root_hist = reduce_hist(
+            build_histogram(bins, grad, hess, row_weight, Bb,
+                            method=cfg.hist_method,
+                            chunk_rows=cfg.hist_chunk_rows,
+                            variant=cfg.hist_variant))
+        tot = jnp.stack([jnp.sum(grad * row_weight),
+                         jnp.sum(hess * row_weight), jnp.sum(row_weight)])
+        if mode in ("data", "voting"):
+            # feature mode replicates rows, so local sums are already global
+            tot = jax.lax.psum(tot, axis)
+        root_split = find(expand_hist(root_hist), tot[0], tot[1], tot[2],
+                          fmask=node_mask_for(0), rand=rand_thr_for(0),
+                          mult=mult_for(0))
 
-    # histogram blocks ladder: rungs over the per-round leaf-grouped gather
-    # capacity (block-aligned); every rung a BR multiple
-    cap_max = -(-(n // 2 + k * BR) // BR) * BR
-    caps2: "list[int]" = []
-    c = max(8 * BR, min(16384, cap_max))
-    c = -(-c // BR) * BR
-    while c < cap_max:
-        caps2.append(c)
-        c = -(-(c * 4) // BR) * BR
-    caps2.append(cap_max)
+        # histogram blocks ladder: rungs over the per-round leaf-grouped
+        # gather capacity (block-aligned); every rung a BR multiple
+        cap_max = -(-(n // 2 + k * BR) // BR) * BR
+        caps2: "list[int]" = []
+        c = max(8 * BR, min(16384, cap_max))
+        c = -(-c // BR) * BR
+        while c < cap_max:
+            caps2.append(c)
+            c = -(-(c * 4) // BR) * BR
+        caps2.append(cap_max)
 
-    pend0 = _BestSplits.empty(LS, cw)
-    pend0 = _batch_set(pend0, jnp.array([0]), _as_batch(root_split, 1),
-                       jnp.array([True]))
+        pend0 = _BestSplits.empty(LS, cw)
+        pend0 = _batch_set(pend0, jnp.array([0]), _as_batch(root_split, 1),
+                           jnp.array([True]))
 
-    state = dict(
-        perm=jnp.arange(n, dtype=jnp.int32),
-        pos_leaf=jnp.zeros(n, jnp.int32),
-        leaf_begin=jnp.zeros(LS, jnp.int32),
-        leaf_nrows=jnp.zeros(LS, jnp.int32).at[0].set(n),
-        leaf_depth=jnp.zeros(LS, jnp.int32),
-        leaf_sum_g=jnp.zeros(LS, jnp.float32).at[0].set(tot[0]),
-        leaf_weight=jnp.zeros(LS, jnp.float32).at[0].set(tot[1]),
-        leaf_count=jnp.zeros(LS, jnp.float32).at[0].set(tot[2]),
-        leaf_cghat=jnp.full(LS, POS_INF, jnp.float32),   # creator split g_hat
-        leaf_cs=jnp.full(LS, -1, jnp.int32),             # creator split idx
-        leaf_il=jnp.zeros(LS, bool),                     # was left child
-        pend=pend0,
-        pend_ghat=jnp.full(LS, NEG_INF, jnp.float32).at[0].set(
-            jnp.minimum(root_split.gain, POS_INF)),
-        hist=jnp.zeros((LS, n_cols, Bb, 3), jnp.float32).at[0].set(root_hist),
-        # split records
-        sp_ghat=jnp.full(S, NEG_INF, jnp.float32),
-        sp_parent=jnp.full(S, -1, jnp.int32),
-        sp_is_left=jnp.zeros(S, bool),
-        sp_feature=jnp.zeros(S, jnp.int32),
-        sp_threshold=jnp.zeros(S, jnp.int32),
-        sp_dleft=jnp.zeros(S, bool),
-        sp_iscat=jnp.zeros(S, bool),
-        sp_catbits=jnp.zeros((S, cw), jnp.int32),
-        sp_gain=jnp.zeros(S, jnp.float32),
-        sp_lout=jnp.zeros(S, jnp.float32), sp_rout=jnp.zeros(S, jnp.float32),
-        sp_lsumg=jnp.zeros(S, jnp.float32), sp_rsumg=jnp.zeros(S, jnp.float32),
-        sp_lweight=jnp.zeros(S, jnp.float32),
-        sp_rweight=jnp.zeros(S, jnp.float32),
-        sp_lcount=jnp.zeros(S, jnp.float32),
-        sp_rcount=jnp.zeros(S, jnp.float32),
-        sp_value=jnp.zeros(S, jnp.float32),   # split-leaf output (internal)
-        sp_count=jnp.zeros(S, jnp.float32),   # split-leaf weighted count
-        sp_begin=jnp.zeros(S, jnp.int32),     # split-leaf row range (local)
-        sp_nrows=jnp.zeros(S, jnp.int32),
-        sp_nleft=jnp.zeros(S, jnp.int32),     # raw left row count (local)
-        n_applied=jnp.int32(0),
-    )
-    if use_mono:
-        # per-leaf monotone output bounds (basic mode: root-path state only)
-        state["leaf_lo"] = jnp.full(LS, NEG_INF, jnp.float32)
-        state["leaf_hi"] = jnp.full(LS, POS_INF, jnp.float32)
+        state = dict(
+            perm=jnp.arange(n, dtype=jnp.int32),
+            pos_leaf=jnp.zeros(n, jnp.int32),
+            leaf_begin=jnp.zeros(LS, jnp.int32),
+            leaf_nrows=jnp.zeros(LS, jnp.int32).at[0].set(n),
+            leaf_depth=jnp.zeros(LS, jnp.int32),
+            leaf_sum_g=jnp.zeros(LS, jnp.float32).at[0].set(tot[0]),
+            leaf_weight=jnp.zeros(LS, jnp.float32).at[0].set(tot[1]),
+            leaf_count=jnp.zeros(LS, jnp.float32).at[0].set(tot[2]),
+            leaf_cghat=jnp.full(LS, POS_INF, jnp.float32),  # creator's g_hat
+            leaf_cs=jnp.full(LS, -1, jnp.int32),            # creator split idx
+            leaf_il=jnp.zeros(LS, bool),                    # was left child
+            pend=pend0,
+            pend_ghat=jnp.full(LS, NEG_INF, jnp.float32).at[0].set(
+                jnp.minimum(root_split.gain, POS_INF)),
+            hist=jnp.zeros((LS, n_cols, Bb, 3), jnp.float32).at[0].set(
+                root_hist),
+            # split records
+            sp_ghat=jnp.full(S, NEG_INF, jnp.float32),
+            sp_parent=jnp.full(S, -1, jnp.int32),
+            sp_is_left=jnp.zeros(S, bool),
+            sp_feature=jnp.zeros(S, jnp.int32),
+            sp_threshold=jnp.zeros(S, jnp.int32),
+            sp_dleft=jnp.zeros(S, bool),
+            sp_iscat=jnp.zeros(S, bool),
+            sp_catbits=jnp.zeros((S, cw), jnp.int32),
+            sp_gain=jnp.zeros(S, jnp.float32),
+            sp_lout=jnp.zeros(S, jnp.float32),
+            sp_rout=jnp.zeros(S, jnp.float32),
+            sp_lsumg=jnp.zeros(S, jnp.float32),
+            sp_rsumg=jnp.zeros(S, jnp.float32),
+            sp_lweight=jnp.zeros(S, jnp.float32),
+            sp_rweight=jnp.zeros(S, jnp.float32),
+            sp_lcount=jnp.zeros(S, jnp.float32),
+            sp_rcount=jnp.zeros(S, jnp.float32),
+            sp_value=jnp.zeros(S, jnp.float32),   # split-leaf output
+            sp_count=jnp.zeros(S, jnp.float32),   # split-leaf weighted count
+            sp_begin=jnp.zeros(S, jnp.int32),     # split-leaf row range (local)
+            sp_nrows=jnp.zeros(S, jnp.int32),
+            sp_nleft=jnp.zeros(S, jnp.int32),     # raw left row count (local)
+            n_applied=jnp.int32(0),
+            # counters (with_stats): rounds taken, rows of the leaves split
+            n_rounds=jnp.int32(0),
+            rows_sel_hi=jnp.int32(0), rows_sel_lo=jnp.int32(0),
+        )
+        if use_mono:
+            # per-leaf monotone output bounds (basic mode: root-path state)
+            state["leaf_lo"] = jnp.full(LS, NEG_INF, jnp.float32)
+            state["leaf_hi"] = jnp.full(LS, POS_INF, jnp.float32)
 
     from .split import leaf_output
 
-    # one named scope per frontier round so device traces show the
-    # per-round cost of the batched partition+hist+search program
-    @jax.named_scope("lgbm/frontier_round")
+    # One round.  The loop runs under ``lgbm/frontier_round`` and each phase
+    # of the body under its own scope below it, so a device trace can be read
+    # by phase (obs.device_scopes()).
     def round_body(st):
         applied = st["n_applied"]
-        # expansion priority: g_hat primary, RAW gain secondary.  Structural
-        # g_hat ties (child gain > parent gain caps the child at the parent's
-        # g_hat) are popped by the true process in raw-gain cascade order, so
-        # expanding tie classes in raw order keeps the applied set a superset
-        # of the true prefix without blowing the overshoot slack.
-        sel = jnp.lexsort((-st["pend"].gain, -st["pend_ghat"]))[:k]
-        ghat_sel = st["pend_ghat"][sel]
-        i_ar = jnp.arange(k, dtype=jnp.int32)
-        t_full = jax.lax.top_k(st["sp_ghat"], L - 1)[0][-1]
-        # >= on the threshold: when a child's raw gain exceeds its parent's,
-        # g_hat(child) == g_hat(parent) EXACTLY (structural tie), and such a
-        # child can pop before an applied record with the same g_hat — it
-        # must be expanded so the replay can consider it
-        valid = ((ghat_sel > 0.0)
-                 & (applied + i_ar < S)
-                 & ((applied + i_ar < L - 1) | (ghat_sel >= t_full)))
-        v = jnp.sum(valid.astype(jnp.int32))
+        with jax.named_scope("select"):
+            # expansion priority: g_hat primary, RAW gain secondary.  Structural
+            # g_hat ties (child gain > parent gain caps the child at the parent's
+            # g_hat) are popped by the true process in raw-gain cascade order, so
+            # expanding tie classes in raw order keeps the applied set a superset
+            # of the true prefix without blowing the overshoot slack.
+            sel = jnp.lexsort((-st["pend"].gain, -st["pend_ghat"]))[:k]
+            ghat_sel = st["pend_ghat"][sel]
+            i_ar = jnp.arange(k, dtype=jnp.int32)
+            t_full = jax.lax.top_k(st["sp_ghat"], L - 1)[0][-1]
+            # >= on the threshold: when a child's raw gain exceeds its parent's,
+            # g_hat(child) == g_hat(parent) EXACTLY (structural tie), and such a
+            # child can pop before an applied record with the same g_hat — it
+            # must be expanded so the replay can consider it
+            valid = ((ghat_sel > 0.0)
+                     & (applied + i_ar < S)
+                     & ((applied + i_ar < L - 1) | (ghat_sel >= t_full)))
+            v = jnp.sum(valid.astype(jnp.int32))
 
-        b = st["pend"]
-        sel_feat = b.feature[sel]
-        sel_thr = b.threshold[sel]
-        sel_dleft = b.default_left[sel]
-        sel_cbits = b.cat_bits[sel]                       # [k, CW]
-        sel_iscat = is_categorical[sel_feat]
-        sel_gain = b.gain[sel]
-        sp_ghat_i = jnp.minimum(sel_gain, st["leaf_cghat"][sel])
-        right_slot = applied + 1 + i_ar                   # leaf slot of right child
-        s_idx = applied + i_ar                            # split record index
-        # the weighted-count comparison is GLOBAL (identical on every shard),
-        # so all shards histogram the same side (grower.py apply_split)
-        left_smaller = b.lc[sel] <= b.rc[sel]
+            b = st["pend"]
+            sel_feat = b.feature[sel]
+            sel_thr = b.threshold[sel]
+            sel_dleft = b.default_left[sel]
+            sel_cbits = b.cat_bits[sel]                       # [k, CW]
+            sel_iscat = is_categorical[sel_feat]
+            sel_gain = b.gain[sel]
+            sp_ghat_i = jnp.minimum(sel_gain, st["leaf_cghat"][sel])
+            right_slot = applied + 1 + i_ar                   # leaf slot of right child
+            s_idx = applied + i_ar                            # split record index
+            # the weighted-count comparison is GLOBAL (identical on every shard),
+            # so all shards histogram the same side (grower.py apply_split)
+            left_smaller = b.lc[sel] <= b.rc[sel]
 
         # ---- [N]-pass: decide + segmented stable partition ----------------
-        slot_of_leaf = jnp.full(LS, -1, jnp.int32).at[
-            jnp.where(valid, sel, LS)].set(i_ar, mode="drop")
-        lf = st["pos_leaf"]
-        si = slot_of_leaf[lf]
-        act = si >= 0
-        sic = jnp.maximum(si, 0)
-        feat_p = sel_feat[sic]
-        rowid = st["perm"]
-        if mode == "feature":
-            # columns are sharded: the owner shard selects its local column
-            # and ONE [N] psum broadcasts it (rows are replicated, so every
-            # shard's perm/selection state is identical; grower.py
-            # partition_and_hist does the same per split — here it is once
-            # per ROUND)
-            local_ix = jnp.clip(feat_p - f_start, 0, f - 1)
-            owns = (feat_p >= f_start) & (feat_p < f_start + f)
-            colv_loc = jnp.take(comb_flat,
-                                rowid * ncc + local_ix).astype(jnp.int32)
-            colv = jax.lax.psum(jnp.where(owns & act, colv_loc, 0), axis)
-        else:
-            col_id_p = col_of_feat[feat_p] if efb is not None else feat_p
-            colv = jnp.take(comb_flat,
-                            rowid * ncc + col_id_p).astype(jnp.int32)
-            colv = decode_col(colv, feat_p)
-        nb_p = nan_bins[feat_p]
-        is_miss = (colv == nb_p) & (nb_p >= 0)
-        wsel = jnp.take(sel_cbits.reshape(-1),
-                        sic * cw + jnp.clip(colv >> 5, 0, cw - 1))
-        gl_cat = ((wsel >> (colv & 31)) & 1) > 0
-        gl = jnp.where(sel_iscat[sic], gl_cat,
-                       jnp.where(is_miss, sel_dleft[sic],
-                                 colv <= sel_thr[sic]))
-        gl_a = gl & act
-        cumL = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                jnp.cumsum(gl_a.astype(jnp.int32))])
-        cumA = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                jnp.cumsum(act.astype(jnp.int32))])
-        beg_p = st["leaf_begin"][lf]
-        baseL = jnp.take(cumL, beg_p)
-        baseA = jnp.take(cumA, beg_p)
-        rankL = cumL[1:] - gl_a.astype(jnp.int32) - baseL     # exclusive
-        rankA = cumA[1:] - act.astype(jnp.int32) - baseA
-        rankR = rankA - rankL
-        sel_beg = st["leaf_begin"][sel]
-        sel_rows = st["leaf_nrows"][sel]
-        nl_i = (jnp.take(cumL, sel_beg + sel_rows)
-                - jnp.take(cumL, sel_beg))                    # [k] raw left
-        nl_p = nl_i[sic]
-        pos_idx = jnp.arange(n, dtype=jnp.int32)
-        new_pos = jnp.where(act,
-                            beg_p + jnp.where(gl, rankL, nl_p + rankR),
-                            pos_idx)
-        perm_new = jnp.zeros(n, jnp.int32).at[new_pos].set(rowid)
-        pos_leaf_new = jnp.zeros(n, jnp.int32).at[new_pos].set(
-            jnp.where(gl | ~act, lf, right_slot[sic]))
+        with jax.named_scope("partition"):
+            with jax.named_scope("decide"):
+                slot_of_leaf = jnp.full(LS, -1, jnp.int32).at[
+                    jnp.where(valid, sel, LS)].set(i_ar, mode="drop")
+                lf = st["pos_leaf"]
+                si = slot_of_leaf[lf]
+                act = si >= 0
+                sic = jnp.maximum(si, 0)
+                feat_p = sel_feat[sic]
+                rowid = st["perm"]
+                if mode == "feature":
+                    # columns are sharded: the owner shard selects its local column
+                    # and ONE [N] psum broadcasts it (rows are replicated, so every
+                    # shard's perm/selection state is identical; grower.py
+                    # partition_and_hist does the same per split — here it is once
+                    # per ROUND)
+                    local_ix = jnp.clip(feat_p - f_start, 0, f - 1)
+                    owns = (feat_p >= f_start) & (feat_p < f_start + f)
+                    colv_loc = jnp.take(comb_flat,
+                                        rowid * ncc + local_ix).astype(jnp.int32)
+                    colv = jax.lax.psum(jnp.where(owns & act, colv_loc, 0), axis)
+                else:
+                    col_id_p = col_of_feat[feat_p] if efb is not None else feat_p
+                    colv = jnp.take(comb_flat,
+                                    rowid * ncc + col_id_p).astype(jnp.int32)
+                    colv = decode_col(colv, feat_p)
+                nb_p = nan_bins[feat_p]
+                is_miss = (colv == nb_p) & (nb_p >= 0)
+                wsel = jnp.take(sel_cbits.reshape(-1),
+                                sic * cw + jnp.clip(colv >> 5, 0, cw - 1))
+                gl_cat = ((wsel >> (colv & 31)) & 1) > 0
+                gl = jnp.where(sel_iscat[sic], gl_cat,
+                               jnp.where(is_miss, sel_dleft[sic],
+                                         colv <= sel_thr[sic]))
+                gl_a = gl & act
+            with jax.named_scope("rank"):
+                cumL = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                        jnp.cumsum(gl_a.astype(jnp.int32))])
+                cumA = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                        jnp.cumsum(act.astype(jnp.int32))])
+                beg_p = st["leaf_begin"][lf]
+                baseL = jnp.take(cumL, beg_p)
+                baseA = jnp.take(cumA, beg_p)
+                rankL = cumL[1:] - gl_a.astype(jnp.int32) - baseL     # exclusive
+                rankA = cumA[1:] - act.astype(jnp.int32) - baseA
+                rankR = rankA - rankL
+                sel_beg = st["leaf_begin"][sel]
+                sel_rows = st["leaf_nrows"][sel]
+                nl_i = (jnp.take(cumL, sel_beg + sel_rows)
+                        - jnp.take(cumL, sel_beg))                    # [k] raw left
+                nl_p = nl_i[sic]
+            with jax.named_scope("scatter"):
+                pos_idx = jnp.arange(n, dtype=jnp.int32)
+                new_pos = jnp.where(act,
+                                    beg_p + jnp.where(gl, rankL, nl_p + rankR),
+                                    pos_idx)
+                perm_new = jnp.zeros(n, jnp.int32).at[new_pos].set(rowid)
+                pos_leaf_new = jnp.zeros(n, jnp.int32).at[new_pos].set(
+                    jnp.where(gl | ~act, lf, right_slot[sic]))
 
         # ---- leaf bookkeeping --------------------------------------------
-        def upd(arr, idx, val, pred):
-            return arr.at[jnp.where(pred, idx, LS)].set(val, mode="drop")
-        nr_i = sel_rows - nl_i
-        depth_c = st["leaf_depth"][sel] + 1
-        leaf_begin = upd(st["leaf_begin"], right_slot, sel_beg + nl_i, valid)
-        leaf_nrows = upd(upd(st["leaf_nrows"], sel, nl_i, valid),
-                         right_slot, nr_i, valid)
-        leaf_depth = upd(upd(st["leaf_depth"], sel, depth_c, valid),
-                         right_slot, depth_c, valid)
-        leaf_sum_g = upd(upd(st["leaf_sum_g"], sel, b.lg[sel], valid),
-                         right_slot, b.rg[sel], valid)
-        leaf_weight = upd(upd(st["leaf_weight"], sel, b.lh[sel], valid),
-                          right_slot, b.rh[sel], valid)
-        leaf_count = upd(upd(st["leaf_count"], sel, b.lc[sel], valid),
-                         right_slot, b.rc[sel], valid)
-        leaf_cghat = upd(upd(st["leaf_cghat"], sel, sp_ghat_i, valid),
-                         right_slot, sp_ghat_i, valid)
-        leaf_cs = upd(upd(st["leaf_cs"], sel, s_idx, valid),
-                      right_slot, s_idx, valid)
-        leaf_il = upd(upd(st["leaf_il"], sel, jnp.ones(k, bool), valid),
-                      right_slot, jnp.zeros(k, bool), valid)
+        with jax.named_scope("bookkeeping"):
+            def upd(arr, idx, val, pred):
+                return arr.at[jnp.where(pred, idx, LS)].set(val, mode="drop")
+            nr_i = sel_rows - nl_i
+            rows_sel = jnp.sum(jnp.where(valid, sel_rows, 0))   # <= n
+            depth_c = st["leaf_depth"][sel] + 1
+            leaf_begin = upd(st["leaf_begin"], right_slot, sel_beg + nl_i, valid)
+            leaf_nrows = upd(upd(st["leaf_nrows"], sel, nl_i, valid),
+                             right_slot, nr_i, valid)
+            leaf_depth = upd(upd(st["leaf_depth"], sel, depth_c, valid),
+                             right_slot, depth_c, valid)
+            leaf_sum_g = upd(upd(st["leaf_sum_g"], sel, b.lg[sel], valid),
+                             right_slot, b.rg[sel], valid)
+            leaf_weight = upd(upd(st["leaf_weight"], sel, b.lh[sel], valid),
+                              right_slot, b.rh[sel], valid)
+            leaf_count = upd(upd(st["leaf_count"], sel, b.lc[sel], valid),
+                             right_slot, b.rc[sel], valid)
+            leaf_cghat = upd(upd(st["leaf_cghat"], sel, sp_ghat_i, valid),
+                             right_slot, sp_ghat_i, valid)
+            leaf_cs = upd(upd(st["leaf_cs"], sel, s_idx, valid),
+                          right_slot, s_idx, valid)
+            leaf_il = upd(upd(st["leaf_il"], sel, jnp.ones(k, bool), valid),
+                          right_slot, jnp.zeros(k, bool), valid)
 
-        extra_mono = {}
-        if use_mono:
-            # basic mode: pinch both children at the midpoint of the child
-            # outputs (grower.py apply_split, reference BasicConstraint) —
-            # depends only on the expansion's own path, so batching k
-            # expansions cannot reorder it
-            mono_sel = monotone[sel_feat]
-            lo_p, hi_p = st["leaf_lo"][sel], st["leaf_hi"][sel]
-            mid = (b.lout[sel] + b.rout[sel]) * 0.5
-            l_lo = jnp.where(mono_sel < 0, jnp.maximum(lo_p, mid), lo_p)
-            l_hi = jnp.where(mono_sel > 0, jnp.minimum(hi_p, mid), hi_p)
-            r_lo = jnp.where(mono_sel > 0, jnp.maximum(lo_p, mid), lo_p)
-            r_hi = jnp.where(mono_sel < 0, jnp.minimum(hi_p, mid), hi_p)
-            extra_mono = dict(
-                leaf_lo=upd(upd(st["leaf_lo"], sel, l_lo, valid),
-                            right_slot, r_lo, valid),
-                leaf_hi=upd(upd(st["leaf_hi"], sel, l_hi, valid),
-                            right_slot, r_hi, valid))
+            extra_mono = {}
+            if use_mono:
+                # basic mode: pinch both children at the midpoint of the child
+                # outputs (grower.py apply_split, reference BasicConstraint) —
+                # depends only on the expansion's own path, so batching k
+                # expansions cannot reorder it
+                mono_sel = monotone[sel_feat]
+                lo_p, hi_p = st["leaf_lo"][sel], st["leaf_hi"][sel]
+                mid = (b.lout[sel] + b.rout[sel]) * 0.5
+                l_lo = jnp.where(mono_sel < 0, jnp.maximum(lo_p, mid), lo_p)
+                l_hi = jnp.where(mono_sel > 0, jnp.minimum(hi_p, mid), hi_p)
+                r_lo = jnp.where(mono_sel > 0, jnp.maximum(lo_p, mid), lo_p)
+                r_hi = jnp.where(mono_sel < 0, jnp.minimum(hi_p, mid), hi_p)
+                extra_mono = dict(
+                    leaf_lo=upd(upd(st["leaf_lo"], sel, l_lo, valid),
+                                right_slot, r_lo, valid),
+                    leaf_hi=upd(upd(st["leaf_hi"], sel, l_hi, valid),
+                                right_slot, r_hi, valid))
 
-        # ---- split records ------------------------------------------------
-        def rec(arr, val):
-            return arr.at[jnp.where(valid, s_idx, S)].set(val, mode="drop")
-        sp_value_i = leaf_output(st["leaf_sum_g"][sel], st["leaf_weight"][sel],
-                                 p, 0.0, st["leaf_count"][sel])
-        recs = dict(
-            sp_ghat=rec(st["sp_ghat"], sp_ghat_i),
-            sp_parent=rec(st["sp_parent"], st["leaf_cs"][sel]),
-            sp_is_left=rec(st["sp_is_left"], st["leaf_il"][sel]),
-            sp_feature=rec(st["sp_feature"], sel_feat),
-            sp_threshold=rec(st["sp_threshold"], sel_thr),
-            sp_dleft=rec(st["sp_dleft"], sel_dleft),
-            sp_iscat=rec(st["sp_iscat"], sel_iscat),
-            sp_catbits=rec(st["sp_catbits"], sel_cbits),
-            sp_gain=rec(st["sp_gain"], sel_gain),
-            sp_lout=rec(st["sp_lout"], b.lout[sel]),
-            sp_rout=rec(st["sp_rout"], b.rout[sel]),
-            sp_lsumg=rec(st["sp_lsumg"], b.lg[sel]),
-            sp_rsumg=rec(st["sp_rsumg"], b.rg[sel]),
-            sp_lweight=rec(st["sp_lweight"], b.lh[sel]),
-            sp_rweight=rec(st["sp_rweight"], b.rh[sel]),
-            sp_lcount=rec(st["sp_lcount"], b.lc[sel]),
-            sp_rcount=rec(st["sp_rcount"], b.rc[sel]),
-            sp_value=rec(st["sp_value"], sp_value_i),
-            sp_count=rec(st["sp_count"], st["leaf_count"][sel]),
-            sp_begin=rec(st["sp_begin"], sel_beg),
-            sp_nrows=rec(st["sp_nrows"], sel_rows),
-            sp_nleft=rec(st["sp_nleft"], nl_i),
-        )
+            # ---- split records ------------------------------------------------
+            def rec(arr, val):
+                return arr.at[jnp.where(valid, s_idx, S)].set(val, mode="drop")
+            sp_value_i = leaf_output(st["leaf_sum_g"][sel], st["leaf_weight"][sel],
+                                     p, 0.0, st["leaf_count"][sel])
+            recs = dict(
+                sp_ghat=rec(st["sp_ghat"], sp_ghat_i),
+                sp_parent=rec(st["sp_parent"], st["leaf_cs"][sel]),
+                sp_is_left=rec(st["sp_is_left"], st["leaf_il"][sel]),
+                sp_feature=rec(st["sp_feature"], sel_feat),
+                sp_threshold=rec(st["sp_threshold"], sel_thr),
+                sp_dleft=rec(st["sp_dleft"], sel_dleft),
+                sp_iscat=rec(st["sp_iscat"], sel_iscat),
+                sp_catbits=rec(st["sp_catbits"], sel_cbits),
+                sp_gain=rec(st["sp_gain"], sel_gain),
+                sp_lout=rec(st["sp_lout"], b.lout[sel]),
+                sp_rout=rec(st["sp_rout"], b.rout[sel]),
+                sp_lsumg=rec(st["sp_lsumg"], b.lg[sel]),
+                sp_rsumg=rec(st["sp_rsumg"], b.rg[sel]),
+                sp_lweight=rec(st["sp_lweight"], b.lh[sel]),
+                sp_rweight=rec(st["sp_rweight"], b.rh[sel]),
+                sp_lcount=rec(st["sp_lcount"], b.lc[sel]),
+                sp_rcount=rec(st["sp_rcount"], b.rc[sel]),
+                sp_value=rec(st["sp_value"], sp_value_i),
+                sp_count=rec(st["sp_count"], st["leaf_count"][sel]),
+                sp_begin=rec(st["sp_begin"], sel_beg),
+                sp_nrows=rec(st["sp_nrows"], sel_rows),
+                sp_nleft=rec(st["sp_nleft"], nl_i),
+            )
 
         # ---- batched smaller-child histograms -----------------------------
-        small_n = jnp.where(valid, jnp.where(left_smaller, nl_i, nr_i), 0)
-        small_beg = jnp.where(left_smaller, sel_beg, sel_beg + nl_i)
-        nblocks = jnp.maximum(-(-small_n // BR), 1)   # >=1: every slot inits
-        blk_start = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                     jnp.cumsum(nblocks)])[:-1]
-        nb_tot = blk_start[-1] + nblocks[-1]
+        with jax.named_scope("hist_gather"):
+            small_n = jnp.where(valid, jnp.where(left_smaller, nl_i, nr_i), 0)
+            small_beg = jnp.where(left_smaller, sel_beg, sel_beg + nl_i)
+            nblocks = jnp.maximum(-(-small_n // BR), 1)   # >=1: every slot inits
+            blk_start = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                         jnp.cumsum(nblocks)])[:-1]
+            nb_tot = blk_start[-1] + nblocks[-1]
 
         def mk_branch(C2):
             NB = C2 // BR
 
             def br(perm_arg):
-                blk = jnp.arange(NB, dtype=jnp.int32)
-                i_of_blk = jnp.clip(
-                    unrolled_rank(blk_start, blk, strict=False) - 1, 0, k - 1)
-                q = jnp.arange(C2, dtype=jnp.int32)
-                qb = q // BR
-                i_of_q = i_of_blk[qb]
-                local = (qb - blk_start[i_of_q]) * BR + (q % BR)
-                okrow = (local < small_n[i_of_q]) & (qb < nb_tot)
-                row_pos = jnp.clip(small_beg[i_of_q] + local, 0, n - 1)
-                rid = jnp.take(perm_arg, row_pos)
-                combb = jnp.take(comb, jnp.where(okrow, rid, 0), axis=0)
-                ghb = _unpack_gh(combb)
-                m = jnp.where(okrow, ghb[:, 2], 0.0)
-                return build_histogram_leaves(
-                    combb, ghb[:, 0], ghb[:, 1], m, i_of_blk, k, Bb,
-                    method=cfg.hist_method, block_rows=BR,
-                    f_limit=n_cols,
-                    variant=cfg.hist_variant)[:, :n_cols]
+                with jax.named_scope("hist_gather"):
+                    blk = jnp.arange(NB, dtype=jnp.int32)
+                    i_of_blk = jnp.clip(
+                        unrolled_rank(blk_start, blk, strict=False) - 1, 0, k - 1)
+                    q = jnp.arange(C2, dtype=jnp.int32)
+                    qb = q // BR
+                    i_of_q = i_of_blk[qb]
+                    local = (qb - blk_start[i_of_q]) * BR + (q % BR)
+                    okrow = (local < small_n[i_of_q]) & (qb < nb_tot)
+                    row_pos = jnp.clip(small_beg[i_of_q] + local, 0, n - 1)
+                    rid = jnp.take(perm_arg, row_pos)
+                    combb = jnp.take(comb, jnp.where(okrow, rid, 0), axis=0)
+                    ghb = _unpack_gh(combb)
+                    m = jnp.where(okrow, ghb[:, 2], 0.0)
+                with jax.named_scope("hist"):
+                    return build_histogram_leaves(
+                        combb, ghb[:, 0], ghb[:, 1], m, i_of_blk, k, Bb,
+                        method=cfg.hist_method, block_rows=BR,
+                        f_limit=n_cols,
+                        variant=cfg.hist_variant)[:, :n_cols]
             return br
 
-        idx = jnp.searchsorted(jnp.asarray(caps2, jnp.int32), nb_tot * BR)
+        with jax.named_scope("hist_gather"):
+            idx = jnp.searchsorted(jnp.asarray(caps2, jnp.int32),
+                                   nb_tot * BR)
         hist_small = jax.lax.switch(idx, [mk_branch(c) for c in caps2],
                                     perm_new)
-        hist_small = reduce_hist(hist_small)              # [k, NC, Bb, 3]
+        with jax.named_scope("hist"):
+            hist_small = reduce_hist(hist_small)              # [k, NC, Bb, 3]
 
-        parent_hist = st["hist"][sel]
-        large_hist = parent_hist - hist_small
-        ls4 = left_smaller[:, None, None, None]
-        lhist = jnp.where(ls4, hist_small, large_hist)
-        rhist = parent_hist - lhist
-        v4 = valid[:, None, None, None]
-        hist = st["hist"].at[sel].set(jnp.where(v4, lhist, parent_hist))
-        hist = hist.at[jnp.where(valid, right_slot, LS)].set(
-            rhist, mode="drop")
+            parent_hist = st["hist"][sel]
+            large_hist = parent_hist - hist_small
+            ls4 = left_smaller[:, None, None, None]
+            lhist = jnp.where(ls4, hist_small, large_hist)
+            rhist = parent_hist - lhist
+            v4 = valid[:, None, None, None]
+            hist = st["hist"].at[sel].set(jnp.where(v4, lhist, parent_hist))
+            hist = hist.at[jnp.where(valid, right_slot, LS)].set(
+                rhist, mode="drop")
 
         # ---- 2k child split searches (one vmapped program) ----------------
-        hist2 = jnp.concatenate([lhist, rhist])           # [2k, NC, Bb, 3]
-        g2 = jnp.concatenate([b.lg[sel], b.rg[sel]])
-        h2 = jnp.concatenate([b.lh[sel], b.rh[sel]])
-        c2 = jnp.concatenate([b.lc[sel], b.rc[sel]])
+        with jax.named_scope("lgbm/split_search"):
+            hist2 = jnp.concatenate([lhist, rhist])       # [2k, NC, Bb, 3]
+            g2 = jnp.concatenate([b.lg[sel], b.rg[sel]])
+            h2 = jnp.concatenate([b.lh[sel], b.rh[sel]])
+            c2 = jnp.concatenate([b.lc[sel], b.rc[sel]])
         if use_mono:
             # bounds per child, penalty factor per child depth; the step
             # keying rides along (node_mask_for/rand_thr_for ignore the
@@ -594,16 +626,17 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             s2 = jax.vmap(lambda hc, g_, h_, c_: find(expand_hist(hc),
                                                       g_, h_, c_))(
                 hist2, g2, h2, c2)
-        depth_ok = (cfg.max_depth <= 0) | (depth_c < cfg.max_depth)
-        dok2 = jnp.concatenate([depth_ok, depth_ok])
-        s2 = s2._replace(gain=jnp.where(dok2, s2.gain, NEG_INF))
-        sl = jax.tree.map(lambda a: a[:k], s2)
-        sr = jax.tree.map(lambda a: a[k:], s2)
-        pend = _batch_set(st["pend"], sel, sl, valid)
-        pend = _batch_set(pend, jnp.where(valid, right_slot, LS), sr, valid)
-        pend_ghat = upd(upd(st["pend_ghat"], sel,
-                            jnp.minimum(sl.gain, sp_ghat_i), valid),
-                        right_slot, jnp.minimum(sr.gain, sp_ghat_i), valid)
+        with jax.named_scope("bookkeeping"):
+            depth_ok = (cfg.max_depth <= 0) | (depth_c < cfg.max_depth)
+            dok2 = jnp.concatenate([depth_ok, depth_ok])
+            s2 = s2._replace(gain=jnp.where(dok2, s2.gain, NEG_INF))
+            sl = jax.tree.map(lambda a: a[:k], s2)
+            sr = jax.tree.map(lambda a: a[k:], s2)
+            pend = _batch_set(st["pend"], sel, sl, valid)
+            pend = _batch_set(pend, jnp.where(valid, right_slot, LS), sr, valid)
+            pend_ghat = upd(upd(st["pend_ghat"], sel,
+                                jnp.minimum(sl.gain, sp_ghat_i), valid),
+                            right_slot, jnp.minimum(sr.gain, sp_ghat_i), valid)
 
         return dict(
             perm=perm_new, pos_leaf=pos_leaf_new,
@@ -615,8 +648,12 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             **extra_mono,
             **recs,
             n_applied=applied + v,
+            n_rounds=st["n_rounds"] + 1,
+            rows_sel_hi=st["rows_sel_hi"] + (rows_sel >> 20),
+            rows_sel_lo=st["rows_sel_lo"] + (rows_sel & 0xFFFFF),
         )
 
+    @jax.named_scope("select")
     def round_cond(st):
         applied = st["n_applied"]
         t_full = jax.lax.top_k(st["sp_ghat"], L - 1)[0][-1]
@@ -625,7 +662,8 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                 & ((applied < L - 1) | (mx >= t_full)))
 
     if L > 1:
-        state = jax.lax.while_loop(round_cond, round_body, state)
+        with jax.named_scope("lgbm/frontier_round"):
+            state = jax.lax.while_loop(round_cond, round_body, state)
 
     # ---- exact best-first selection + numbering: tiny PQ replay -----------
     # The applied records are a superset of the true best-first prefix.  A
@@ -636,128 +674,136 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     # j-th split is leaf j+1).  [L]-sized ops per step: ~L x 8 tiny ops
     # total, vs the full histogram+search pipeline the sequential loop
     # pays per step.
-    appl = jnp.arange(S, dtype=jnp.int32) < state["n_applied"]
-    rec_ids = jnp.arange(S, dtype=jnp.int32)
-    child_left = jnp.full(S, -1, jnp.int32).at[
-        jnp.where(appl & (state["sp_parent"] >= 0) & state["sp_is_left"],
-                  jnp.clip(state["sp_parent"], 0), S)].set(
-        rec_ids, mode="drop")
-    child_right = jnp.full(S, -1, jnp.int32).at[
-        jnp.where(appl & (state["sp_parent"] >= 0) & ~state["sp_is_left"],
-                  jnp.clip(state["sp_parent"], 0), S)].set(
-        rec_ids, mode="drop")
+    with jax.named_scope("lgbm/finalize"):
+        appl = jnp.arange(S, dtype=jnp.int32) < state["n_applied"]
+        rec_ids = jnp.arange(S, dtype=jnp.int32)
+        child_left = jnp.full(S, -1, jnp.int32).at[
+            jnp.where(appl & (state["sp_parent"] >= 0) & state["sp_is_left"],
+                      jnp.clip(state["sp_parent"], 0), S)].set(
+            rec_ids, mode="drop")
+        child_right = jnp.full(S, -1, jnp.int32).at[
+            jnp.where(appl & (state["sp_parent"] >= 0) & ~state["sp_is_left"],
+                      jnp.clip(state["sp_parent"], 0), S)].set(
+            rec_ids, mode="drop")
 
-    def gain_of(r):
-        return jnp.where(r >= 0, state["sp_gain"][jnp.clip(r, 0)], NEG_INF)
+        def gain_of(r):
+            return jnp.where(r >= 0, state["sp_gain"][jnp.clip(r, 0)], NEG_INF)
 
-    have_root = state["n_applied"] > 0      # record 0 is always the root split
-    cur_rec0 = jnp.full(L, -1, jnp.int32).at[0].set(
-        jnp.where(have_root, 0, -1))
-    gains0 = jnp.full(L, NEG_INF, jnp.float32).at[0].set(
-        gain_of(cur_rec0[0]))
+        have_root = state["n_applied"] > 0      # record 0 is always the root split
+        cur_rec0 = jnp.full(L, -1, jnp.int32).at[0].set(
+            jnp.where(have_root, 0, -1))
+        gains0 = jnp.full(L, NEG_INF, jnp.float32).at[0].set(
+            gain_of(cur_rec0[0]))
 
-    def replay_step(j, carry):
-        cur_rec, gains, order, leaf_of_node, cnt = carry
-        pop = jnp.argmax(gains).astype(jnp.int32)
-        ok = gains[pop] > 0.0
-        rec = cur_rec[pop]
-        order = order.at[j].set(jnp.where(ok, rec, -1))
-        leaf_of_node = leaf_of_node.at[j].set(jnp.where(ok, pop, -1))
-        lc = child_left[jnp.clip(rec, 0)]
-        rc = child_right[jnp.clip(rec, 0)]
-        new_id = jnp.minimum(j + 1, L - 1)
-        cur_rec = cur_rec.at[pop].set(jnp.where(ok, lc, cur_rec[pop]))
-        cur_rec = cur_rec.at[new_id].set(
-            jnp.where(ok, rc, cur_rec[new_id]))
-        gains = gains.at[pop].set(jnp.where(ok, gain_of(lc), NEG_INF))
-        gains = gains.at[new_id].set(
-            jnp.where(ok, gain_of(rc), gains[new_id]))
-        return cur_rec, gains, order, leaf_of_node, cnt + ok.astype(jnp.int32)
+        def replay_step(j, carry):
+            cur_rec, gains, order, leaf_of_node, cnt = carry
+            pop = jnp.argmax(gains).astype(jnp.int32)
+            ok = gains[pop] > 0.0
+            rec = cur_rec[pop]
+            order = order.at[j].set(jnp.where(ok, rec, -1))
+            leaf_of_node = leaf_of_node.at[j].set(jnp.where(ok, pop, -1))
+            lc = child_left[jnp.clip(rec, 0)]
+            rc = child_right[jnp.clip(rec, 0)]
+            new_id = jnp.minimum(j + 1, L - 1)
+            cur_rec = cur_rec.at[pop].set(jnp.where(ok, lc, cur_rec[pop]))
+            cur_rec = cur_rec.at[new_id].set(
+                jnp.where(ok, rc, cur_rec[new_id]))
+            gains = gains.at[pop].set(jnp.where(ok, gain_of(lc), NEG_INF))
+            gains = gains.at[new_id].set(
+                jnp.where(ok, gain_of(rc), gains[new_id]))
+            return cur_rec, gains, order, leaf_of_node, cnt + ok.astype(jnp.int32)
 
-    _, _, order, leaf_of_node, nsel = jax.lax.fori_loop(
-        0, L - 1, replay_step,
-        (cur_rec0, gains0,
-         jnp.full(L - 1, -1, jnp.int32), jnp.full(L - 1, -1, jnp.int32),
-         jnp.int32(0)))
+        _, _, order, leaf_of_node, nsel = jax.lax.fori_loop(
+            0, L - 1, replay_step,
+            (cur_rec0, gains0,
+             jnp.full(L - 1, -1, jnp.int32), jnp.full(L - 1, -1, jnp.int32),
+             jnp.int32(0)))
 
-    node_on = order >= 0
-    src = jnp.clip(order, 0)                                  # node j <- record
-    leaf_id_of_node = jnp.maximum(leaf_of_node, 0)
-    node_ids = jnp.arange(L - 1, dtype=jnp.int32)
+        node_on = order >= 0
+        src = jnp.clip(order, 0)                                  # node j <- record
+        leaf_id_of_node = jnp.maximum(leaf_of_node, 0)
+        node_ids = jnp.arange(L - 1, dtype=jnp.int32)
 
-    # children pointers: a selected child record overwrites the leaf default
-    pos_of_rec = jnp.full(S, -1, jnp.int32).at[
-        jnp.where(node_on, src, S)].set(node_ids, mode="drop")
+        # children pointers: a selected child record overwrites the leaf default
+        pos_of_rec = jnp.full(S, -1, jnp.int32).at[
+            jnp.where(node_on, src, S)].set(node_ids, mode="drop")
 
-    def child_ptr(crec, default_leaf):
-        c = crec[src]                                          # child record
-        cpos = pos_of_rec[jnp.clip(c, 0)]
-        return jnp.where(node_on,
-                         jnp.where((c >= 0) & (cpos >= 0), cpos,
-                                   ~default_leaf),
-                         -1)
+        def child_ptr(crec, default_leaf):
+            c = crec[src]                                          # child record
+            cpos = pos_of_rec[jnp.clip(c, 0)]
+            return jnp.where(node_on,
+                             jnp.where((c >= 0) & (cpos >= 0), cpos,
+                                       ~default_leaf),
+                             -1)
 
-    left_child = child_ptr(child_left, leaf_id_of_node)
-    right_child = child_ptr(child_right, node_ids + 1)
+        left_child = child_ptr(child_left, leaf_id_of_node)
+        right_child = child_ptr(child_right, node_ids + 1)
 
-    # leaf stats: node j writes its left/right child's final-leaf slot when
-    # that child was not (selected-)split
-    lleaf = node_on & (left_child < 0)
-    rleaf = node_on & (right_child < 0)
-    lids = jnp.clip(leaf_id_of_node, 0, L - 1)
-    rids = jnp.clip(node_ids + 1, 0, L - 1)
+        # leaf stats: node j writes its left/right child's final-leaf slot when
+        # that child was not (selected-)split
+        lleaf = node_on & (left_child < 0)
+        rleaf = node_on & (right_child < 0)
+        lids = jnp.clip(leaf_id_of_node, 0, L - 1)
+        rids = jnp.clip(node_ids + 1, 0, L - 1)
 
-    def leafset(init, vl, vr):
-        a = jnp.zeros(L, init.dtype) + init
-        a = a.at[jnp.where(lleaf, lids, L)].set(vl, mode="drop")
-        a = a.at[jnp.where(rleaf, rids, L)].set(vr, mode="drop")
-        return a
+        def leafset(init, vl, vr):
+            a = jnp.zeros(L, init.dtype) + init
+            a = a.at[jnp.where(lleaf, lids, L)].set(vl, mode="drop")
+            a = a.at[jnp.where(rleaf, rids, L)].set(vr, mode="drop")
+            return a
 
-    no_split = nsel == 0
-    leaf_value = leafset(jnp.zeros(L, jnp.float32),
-                         state["sp_lout"][src], state["sp_rout"][src])
-    leaf_count = leafset(jnp.zeros(L, jnp.float32),
-                         state["sp_lcount"][src], state["sp_rcount"][src])
-    leaf_count = leaf_count.at[0].set(
-        jnp.where(no_split, tot[2], leaf_count[0]))
-    leaf_weight = leafset(jnp.zeros(L, jnp.float32),
-                          state["sp_lweight"][src], state["sp_rweight"][src])
-    leaf_weight = leaf_weight.at[0].set(
-        jnp.where(no_split, tot[1], leaf_weight[0]))
+        no_split = nsel == 0
+        leaf_value = leafset(jnp.zeros(L, jnp.float32),
+                             state["sp_lout"][src], state["sp_rout"][src])
+        leaf_count = leafset(jnp.zeros(L, jnp.float32),
+                             state["sp_lcount"][src], state["sp_rcount"][src])
+        leaf_count = leaf_count.at[0].set(
+            jnp.where(no_split, tot[2], leaf_count[0]))
+        leaf_weight = leafset(jnp.zeros(L, jnp.float32),
+                              state["sp_lweight"][src], state["sp_rweight"][src])
+        leaf_weight = leaf_weight.at[0].set(
+            jnp.where(no_split, tot[1], leaf_weight[0]))
 
-    tree = TreeArrays(
-        split_feature=jnp.where(node_on, state["sp_feature"][src], -1),
-        threshold=jnp.where(node_on, state["sp_threshold"][src], 0),
-        default_left=node_on & state["sp_dleft"][src],
-        is_cat_split=node_on & state["sp_iscat"][src],
-        cat_bits=jnp.where(node_on[:, None], state["sp_catbits"][src], 0),
-        split_gain=jnp.where(node_on, state["sp_gain"][src], 0.0),
-        left_child=left_child,
-        right_child=right_child,
-        leaf_value=leaf_value,
-        leaf_count=leaf_count,
-        leaf_weight=leaf_weight,
-        internal_value=jnp.where(node_on, state["sp_value"][src], 0.0),
-        internal_count=jnp.where(node_on, state["sp_count"][src], 0.0),
-        num_leaves=(nsel + 1).astype(jnp.int32),
-    )
+        tree = TreeArrays(
+            split_feature=jnp.where(node_on, state["sp_feature"][src], -1),
+            threshold=jnp.where(node_on, state["sp_threshold"][src], 0),
+            default_left=node_on & state["sp_dleft"][src],
+            is_cat_split=node_on & state["sp_iscat"][src],
+            cat_bits=jnp.where(node_on[:, None], state["sp_catbits"][src], 0),
+            split_gain=jnp.where(node_on, state["sp_gain"][src], 0.0),
+            left_child=left_child,
+            right_child=right_child,
+            leaf_value=leaf_value,
+            leaf_count=leaf_count,
+            leaf_weight=leaf_weight,
+            internal_value=jnp.where(node_on, state["sp_value"][src], 0.0),
+            internal_count=jnp.where(node_on, state["sp_count"][src], 0.0),
+            num_leaves=(nsel + 1).astype(jnp.int32),
+        )
 
-    # ---- node assignment from final leaf row ranges ------------------------
-    lbeg = state["sp_begin"][src]
-    lnl = state["sp_nleft"][src]
-    leaf_beg = leafset(jnp.zeros(L, jnp.int32), lbeg, lbeg + lnl)
-    leaf_nr = leafset(jnp.zeros(L, jnp.int32), lnl,
-                      state["sp_nrows"][src] - lnl)
-    leaf_nr = leaf_nr.at[0].set(jnp.where(no_split, n, leaf_nr[0]))
-    begins = jnp.where(leaf_nr > 0, leaf_beg,
-                       n + 1 + jnp.arange(L, dtype=jnp.int32))
-    lorder = jnp.argsort(begins)
-    sorted_begin = begins[lorder]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    rank = unrolled_rank(sorted_begin, pos, strict=False)
-    leaf_of_pos = jnp.take(lorder, jnp.maximum(rank - 1, 0))
-    node_assign = jnp.zeros(n, jnp.int32).at[state["perm"]].set(leaf_of_pos)
-    return tree, node_assign
+        # ---- node assignment from final leaf row ranges ------------------------
+        lbeg = state["sp_begin"][src]
+        lnl = state["sp_nleft"][src]
+        leaf_beg = leafset(jnp.zeros(L, jnp.int32), lbeg, lbeg + lnl)
+        leaf_nr = leafset(jnp.zeros(L, jnp.int32), lnl,
+                          state["sp_nrows"][src] - lnl)
+        leaf_nr = leaf_nr.at[0].set(jnp.where(no_split, n, leaf_nr[0]))
+        begins = jnp.where(leaf_nr > 0, leaf_beg,
+                           n + 1 + jnp.arange(L, dtype=jnp.int32))
+        lorder = jnp.argsort(begins)
+        sorted_begin = begins[lorder]
+        pos = jnp.arange(n, dtype=jnp.int32)
+        rank = unrolled_rank(sorted_begin, pos, strict=False)
+        leaf_of_pos = jnp.take(lorder, jnp.maximum(rank - 1, 0))
+        node_assign = jnp.zeros(n, jnp.int32).at[state["perm"]].set(leaf_of_pos)
+    if not with_stats:
+        return tree, node_assign
+    stats = jnp.stack([state["n_rounds"], state["rows_sel_hi"],
+                       state["rows_sel_lo"]])
+    if mode in ("data", "voting"):
+        # rows are sharded: the leaves' rows are local counts
+        stats = stats.at[1:].set(jax.lax.psum(stats[1:], axis))
+    return tree, node_assign, stats
 
 
 def _as_batch(s: SplitResult, m: int) -> SplitResult:

@@ -328,8 +328,11 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               forced: "Tuple[Tuple[int, int, int], ...]" = (),
               efb: "tuple | None" = None,
               feature_contri: "jax.Array | None" = None,
+              with_stats: bool = False,
               ) -> Tuple[TreeArrays, jax.Array]:
-    """Grow one tree.  Returns (tree, node_assignment[num_data]).
+    """Grow one tree.  Returns (tree, node_assignment[num_data]), and with
+    ``with_stats`` a third ``int32[3]``: the frontier grower's counters
+    (``frontier.grow_tree_frontier``), zeros from the serial grower.
 
     Optional feature-gating state:
       interaction_sets: ``[C, F]`` 0/1 — each row one interaction-constraint
@@ -360,7 +363,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         return grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                                   num_bins, default_bins, nan_bins,
                                   is_categorical, monotone, key, cfg,
-                                  efb=efb, feature_contri=feature_contri)
+                                  efb=efb, feature_contri=feature_contri,
+                                  with_stats=with_stats)
     n, n_cols = bins.shape
     if efb is not None:
         efb_bundle_np, efb_off_np, efb_nb_np = efb
@@ -532,8 +536,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         return jax.lax.psum(h, axis)
 
     # lgbm/* named scopes label the phases inside the single fused program
-    # so device traces (jax.profiler / obs_trace_device) decompose the
-    # grower the way the host-paced streaming loop does naturally
+    # so a device trace (jax.profiler, read through obs.device_scopes())
+    # decomposes the grower the way the host-paced streaming loop does
     @jax.named_scope("lgbm/partition")
     def partition_and_hist(perm, begin, rows, feat, thr, dleft, f_is_cat,
                            cbits, ok, left_smaller):
@@ -1385,8 +1389,9 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         internal_count=state["node_count"],
         num_leaves=state["num_leaves"],
     )
+    no_stats = (jnp.zeros(3, jnp.int32),) if with_stats else ()
     if not use_partition:
-        return tree, state["node_assign"]
+        return (tree, state["node_assign"]) + no_stats
 
     # ---- node assignment from the partition (once per tree) ----------------
     # positions [begin_i, begin_i + nrows_i) belong to leaf i; empty leaves
@@ -1400,4 +1405,4 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     rank = unrolled_rank(sorted_begin, pos, strict=False)
     leaf_of_pos = jnp.take(order, jnp.maximum(rank - 1, 0))
     node_assign = jnp.zeros(n, jnp.int32).at[state["perm"]].set(leaf_of_pos)
-    return tree, node_assign
+    return (tree, node_assign) + no_stats

@@ -29,13 +29,13 @@ from ..config import Config
 from ..io.dataset import Dataset
 from ..metric import create_metrics
 from ..models.gbdt import GBDT, bag_mask_from_uniform
+from ..obs import get_tracer
 from ..obs import health as obs_health
 from ..models.goss import goss_mask_from_importance
 from ..models.tree import Tree
 from ..objective import create_objective
 from ..utils.log import Log, LightGBMError, check
 from ..utils.random_gen import key_for_iteration
-from ..utils.timer import global_timer
 from .grower import StreamTreeGrower, make_shards
 from .pipeline import PipelineStats
 
@@ -305,11 +305,24 @@ class StreamGBDT(GBDT):
             return True
 
         obs = self._obs
+        tracer = get_tracer()
+        tracer.begin("lgbm/update", iteration=it)
+        try:
+            should_stop = self._stream_one_iter(grad, hess, it, tracer)
+        finally:
+            tracer.end("lgbm/update")
         if obs is not None:
-            obs.phase_mark()
-            obs.tracer.begin("train/iteration", step=it)
+            obs.iteration_event(it, trees=K)
+        elif self._health_enabled:
+            obs_health.set_status(stage="stream", iteration=it)
+        return should_stop
 
-        with global_timer.scope("StreamGBDT::gradients"):
+    def _stream_one_iter(self, grad, hess, it: int, tracer) -> bool:
+        cfg = self.config
+        K = self.num_tree_per_iteration
+        n = self.train_data.num_data
+        obs = self._obs
+        with tracer.span("lgbm/update/gradients"):
             if grad is None or hess is None:
                 g, h = self._compute_gradients_stream()
             else:
@@ -324,7 +337,7 @@ class StreamGBDT(GBDT):
 
         should_stop = True
         for k in range(K):
-            with global_timer.scope("StreamGBDT::grow_tree"):
+            with tracer.span("lgbm/update/grow_dispatch"):
                 tree_arrays, node_assign = self._stream_grower.grow(
                     g[k], h[k], rw, fmask,
                     key_for_iteration(cfg.seed, it, salt=k + 1))
@@ -367,7 +380,7 @@ class StreamGBDT(GBDT):
                     tree.leaf_value = np.full_like(tree.leaf_value,
                                                    self.init_scores[k])
 
-            with global_timer.scope("StreamGBDT::update_score"):
+            with tracer.span("lgbm/update/score_dispatch"):
                 if nl > 1:
                     delta = (np.asarray(tree_arrays.leaf_value, np.float32)
                              * np.float32(self.shrinkage_rate))
@@ -379,11 +392,6 @@ class StreamGBDT(GBDT):
             self._tree_weights.append(self.shrinkage_rate)
 
         self.iter_ += 1
-        if obs is not None:
-            obs.tracer.end("train/iteration")
-            obs.iteration_event(it, trees=K)
-        elif self._health_enabled:
-            obs_health.set_status(stage="stream", iteration=it)
         if should_stop:
             Log.warning("Stopped training because there are no more leaves "
                         "that meet the split requirements")

@@ -1,10 +1,9 @@
 from .log import Log, LogLevel, LightGBMError, register_log_callback, reset_log_level, check
-from .timer import Timer, global_timer
 from .random_gen import Random, key_for_iteration
 from . import common
 
 __all__ = [
     "Log", "LogLevel", "LightGBMError", "register_log_callback",
-    "reset_log_level", "check", "Timer", "global_timer", "Random",
+    "reset_log_level", "check", "Random",
     "key_for_iteration", "common",
 ]

@@ -34,6 +34,12 @@ DEFAULT_DIR = os.path.join(
 
 def configure() -> None:
     """Apply the placement rule above."""
+    from ..obs.tracer import get_tracer
+    with get_tracer().span("lgbm/booster/init/compile_cache"):
+        _configure()
+
+
+def _configure() -> None:
     pinned_to_cpu = (jax.config.jax_platforms or "").split(",")[0] == "cpu"
     if not os.environ.get(ENV_VAR) and not pinned_to_cpu:
         jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)

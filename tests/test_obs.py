@@ -4,8 +4,8 @@ CPU-only and fast.  Covers ISSUE 16's acceptance criteria: the structured
 event schema round-trips and is thread-safe; the report layer tolerates
 the legacy (pre-schema) journal lines the six old writers produced; the
 serve-path metrics are correct under concurrent load; a CPU training run
-emits one schema-valid event per boosting iteration and exports a Chrome
-trace with nested spans; and every ``scripts/bench_*.py`` is statically
+emits one schema-valid event per boosting iteration and leaves nested spans
+in the process tracer; and every ``scripts/bench_*.py`` is statically
 held to the one-JSON-line summary contract.
 """
 import glob
@@ -18,11 +18,11 @@ import pytest
 
 from lightgbm_tpu.obs import (EventLog, SCHEMA_VERSION, classify_record,
                               make_event, new_run_id, validate_event)
+from lightgbm_tpu import obs
 from lightgbm_tpu.obs import metrics as obs_metrics
 from lightgbm_tpu.obs import report as obs_report
 from lightgbm_tpu.obs.events import SUMMARY_EVENT, perf_log_path
 from lightgbm_tpu.obs.tracer import Tracer, get_tracer
-from lightgbm_tpu.utils.timer import Timer, global_timer
 
 pytestmark = pytest.mark.obs
 
@@ -318,26 +318,35 @@ def test_batcher_shed_metric():
 
 
 # ---------------------------------------------------------------------------
-# tracer + timer bridge
-def test_tracer_nested_spans_and_chrome_export(tmp_path):
+# tracer
+def test_tracer_nested_spans_parent_and_iteration():
     tr = Tracer()
-    with tr.span("outer"):
+    with tr.span("outer", iteration=7):
         with tr.span("inner", leaf=3):
             pass
-        with tr.span("inner"):
-            pass
+        tr.begin("inner")
+        tr.end("inner", found="late")       # end() may add what it learned
+    with tr.span("alone"):
+        pass
     spans = tr.spans()
-    assert [s.name for s in spans] == ["inner", "inner", "outer"]
-    assert [s.depth for s in spans] == [1, 1, 0]
-    assert spans[0].args == {"leaf": 3}
+    assert [s.name for s in spans] == ["inner", "inner", "outer", "alone"]
+    assert [s.depth for s in spans] == [1, 1, 0, 0]
+    outer = spans[2]
+    assert outer.parent is None and spans[3].parent is None
+    assert spans[0].parent == outer.id and spans[1].parent == outer.id
+    assert len({s.id for s in spans}) == 4
+    # children inherit the iteration of the span that caused them
+    assert [s.iteration for s in spans] == [7, 7, 7, None]
+    assert spans[0].args == {"leaf": 3} and spans[1].args == {"found": "late"}
+    # on time.time_ns(), the clock the profiler stamps host events with
+    import time
+    assert abs(outer.end - time.time_ns()) < 60e9
+    assert outer.start <= spans[0].start <= spans[0].end <= outer.end
+    assert outer.duration == (outer.end - outer.start) / 1e9
     agg = tr.aggregate()
     assert agg["inner"]["count"] == 2
-    out = str(tmp_path / "trace.json")
-    assert tr.export_chrome_trace(out) == 3
-    doc = json.load(open(out))
-    assert {e["ph"] for e in doc["traceEvents"]} == {"X"}
-    names = [e["name"] for e in doc["traceEvents"]]
-    assert names.count("inner") == 2 and "outer" in names
+    assert set(outer.as_dict()) == {"id", "parent", "name", "start", "end",
+                                    "tid", "depth", "iteration", "args"}
 
 
 def test_tracer_unbalanced_end_is_ignored_and_capacity_bounds():
@@ -346,7 +355,8 @@ def test_tracer_unbalanced_end_is_ignored_and_capacity_bounds():
     for i in range(4):
         with tr.span(f"s{i}"):
             pass
-    assert len(tr.spans()) == 2 and tr.dropped == 2
+    # a ring: the oldest go, the newest stay
+    assert [s.name for s in tr.spans()] == ["s2", "s3"] and tr.dropped == 2
     tr.reset()
     assert tr.spans() == [] and tr.dropped == 0
 
@@ -370,34 +380,69 @@ def test_tracer_threads_get_independent_stacks():
     assert all(s.depth == 0 for s in spans)     # no cross-thread nesting
 
 
-def test_timer_bridge_mirrors_scopes_into_tracer():
-    timer = Timer()
-    tr = Tracer()
-    timer.attach_tracer(tr)
-    with timer.scope("GBDT::grow_tree"):
-        with timer.scope("GBDT::grow_tree"):    # same name may nest
-            pass
-    timer.detach_tracer()
-    with timer.scope("GBDT::grow_tree"):        # detached: no span
-        pass
-    assert timer.calls("GBDT::grow_tree") == 3
-    assert timer.seconds("GBDT::grow_tree") > 0.0
-    spans = tr.spans()
-    assert len(spans) == 2
-    assert {s.depth for s in spans} == {0, 1}
+def test_spans_record_without_telemetry_and_annotate_the_profiler(tmp_path):
+    """What replaced the timer bridge: the span sites of the boosting loop
+    feed the process tracer with ``obs_telemetry`` off, and every span is
+    also a ``jax.profiler`` annotation with no knob (an operator's own
+    capture shows them)."""
+    import jax
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.config import Config
+
+    # no parameter turns the spans or their annotations on or off
+    assert not [f for f in vars(Config()) if "trace" in f]
+    get_tracer().reset()
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(300, 5))
+    y = X[:, 0] - X[:, 1]
+    p = {"objective": "regression", "num_leaves": 7, "verbose": -1}
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        bst = lgb.train(p, lgb.Dataset(X, label=y, params=p),
+                        num_boost_round=3)
+        bst.dump_model()
+    finally:
+        jax.profiler.stop_trace()
+    spans = get_tracer().spans()
+    names = [s.name for s in spans]
+    by_id = {s.id: s for s in spans}
+    for want in ("lgbm/dataset/construct", "lgbm/dataset/construct/find_bins",
+                 "lgbm/dataset/construct/bin_values", "lgbm/booster/init",
+                 "lgbm/booster/init/to_device", "lgbm/update",
+                 "lgbm/update/gradients", "lgbm/update/grow_dispatch",
+                 "lgbm/update/score_dispatch", "lgbm/update/drain",
+                 "lgbm/dump"):
+        assert want in names, want
+    updates = [s for s in spans if s.name == "lgbm/update"]
+    assert [s.iteration for s in updates] == [0, 1, 2]
+    for s in spans:
+        if s.name.startswith("lgbm/update/") and s.parent is not None:
+            # the last tree's drain is caused by the dump, not by an update
+            assert s.name.startswith(by_id[s.parent].name) or (
+                s.name == "lgbm/update/drain"
+                and by_id[s.parent].name == "lgbm/dump")
+    # a dozen spans a tree, not one per split or row
+    assert len([s for s in spans if s.iteration == 1]) <= 16
+    # the capture holds the same names as host events
+    from jax.profiler import ProfileData
+    paths = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert paths
+    seen = {ev.name for plane in ProfileData.from_file(paths[-1]).planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for ev in line.events}
+    assert {"lgbm/update", "lgbm/update/grow_dispatch"} <= seen
 
 
 # ---------------------------------------------------------------------------
 # boosting loop: per-iteration events + nested training trace
 @pytest.fixture
 def train_telemetry_env(tmp_path):
-    """Isolated event sink + clean global tracer/timer around one run."""
+    """Isolated event sink + clean global tracer around one run."""
     path = str(tmp_path / "train_events.jsonl")
     obs_metrics.reset()
     get_tracer().reset()
-    global_timer.reset()
     yield path
-    global_timer.detach_tracer()
     get_tracer().reset()
 
 
@@ -422,9 +467,12 @@ def test_training_emits_one_event_per_iteration(train_telemetry_env, tmp_path):
     assert len(iters) == rounds           # exactly one per boosting round
     assert [r["iteration"] for r in iters] == list(range(rounds))
     assert len({r["run_id"] for _, r in recs}) == 1
-    # phase seconds cover the boosting loop's three phases
-    assert set(iters[0]["phase_seconds"]) == {"gradients", "grow_tree",
-                                              "update_score"}
+    # the iteration's spans under their own names: host seconds of issuing
+    # and waiting, not a device phase's seconds
+    assert {"lgbm/update", "lgbm/update/gradients",
+            "lgbm/update/grow_dispatch",
+            "lgbm/update/score_dispatch"} <= set(iters[0]["span_seconds"])
+    assert "lgbm/update/drain" in iters[1]["span_seconds"]
     # per-tree stats landed via the async drain (no forced sync)
     assert len(trees) >= rounds - 1
     assert all(t["num_leaves"] >= 2 for t in trees)
@@ -433,19 +481,247 @@ def test_training_emits_one_event_per_iteration(train_telemetry_env, tmp_path):
     # metrics registry mirrors the stream
     snap = obs_metrics.snapshot()
     assert snap["train.iterations"]["value"] == rounds
-    assert snap["train.grow_tree_seconds"]["count"] == rounds
+    assert snap["train.grow_dispatch_seconds"]["count"] == rounds
+    assert snap["train.update_seconds"]["count"] == rounds
     assert snap["train.num_leaves"]["count"] == len(trees)
-    # the global tracer holds nested spans: timer scopes under the
-    # per-iteration span, exportable as a Chrome trace
+    # the global tracer holds nested spans: the loop's steps under the
+    # per-iteration span, each tagged with its iteration
     spans = get_tracer().spans()
-    step_spans = [s for s in spans if s.name == "train/iteration"]
-    assert len(step_spans) == rounds
-    nested = [s for s in spans if s.name.startswith("GBDT::")]
-    assert nested and all(s.depth >= 1 for s in nested)
-    out = str(tmp_path / "trace.json")
-    n = get_tracer().export_chrome_trace(out)
-    assert n == len(spans)
-    json.load(open(out))
+    step_spans = [s for s in spans if s.name == "lgbm/update"]
+    assert [s.iteration for s in step_spans] == list(range(rounds))
+    ids = {s.id for s in step_spans}
+    nested = [s for s in spans if s.name.startswith("lgbm/update/")
+              and s.name != "lgbm/update/drain"]
+    assert nested and all(s.depth >= 1 and s.parent in ids for s in nested)
+
+
+def _binary_job(seed=0, rows=1500, **params):
+    """A small binary job on the one-chip frontier fast path with a
+    validation set."""
+    import lightgbm_tpu as lgb
+
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(rows + 500, 8))
+    y = (X[:, 0] + X[:, 1] ** 2 + 0.3 * rng.normal(size=len(X)) > 0.5
+         ).astype(np.float64)
+    p = {"objective": "binary", "num_leaves": 15, "verbose": -1,
+         "min_data_in_leaf": 5, "metric": "binary_logloss,auc", **params}
+    train = lgb.Dataset(X[:rows], label=y[:rows], params=p)
+    valid = lgb.Dataset(X[rows:], label=y[rows:], reference=train, params=p)
+    bst = lgb.Booster(p, train)
+    bst.add_valid(valid, "valid")
+    return bst, train, valid
+
+
+def test_compile_span_is_parented_to_the_step_that_compiled(
+        train_telemetry_env):
+    """One ``jax.monitoring`` listener turns every compilation into an
+    ``lgbm/compile`` span under whatever span was open: a shape first jitted
+    inside ``update()`` shows with that step as its parent."""
+    assert obs.install_compile_listener()       # idempotent
+    bst, _, _ = _binary_job(seed=11, rows=1237)     # shapes no other test has
+    bst.update()
+    bst.eval_valid()
+    spans = get_tracer().spans()
+    by_id = {s.id: s for s in spans}
+    compiles = [s for s in spans if s.name == "lgbm/compile"]
+    grow = [s for s in compiles if "grow_tree_step" in s.args["fun"]]
+    assert len(grow) == 1               # compiled once; the scope table read
+    #                                     the executable jax already held
+    assert by_id[grow[0].parent].name == "lgbm/update/grow_dispatch"
+    assert grow[0].iteration == 0
+    assert grow[0].args["seconds"] == pytest.approx(grow[0].duration)
+    assert "cache_hit" in grow[0].args
+    for s in compiles:
+        assert s.parent is not None and by_id[s.parent].name.startswith("lgbm/")
+    snap = obs_metrics.snapshot()
+    assert snap["compile.count"]["value"] == len(compiles)
+    # a second tree compiles nothing
+    bst.update()
+    assert len([s for s in get_tracer().spans()
+                if s.name == "lgbm/compile"]) == len(compiles)
+    # the metrics and their wait for the device are apart
+    names = [s.name for s in get_tracer().spans()]
+    for want in ("lgbm/eval", "lgbm/eval/wait", "lgbm/eval/fetch",
+                 "lgbm/eval/metric", "lgbm/booster/init",
+                 "lgbm/dataset/construct/reference_bin"):
+        assert want in names, want
+    metrics = [s.args["metric"] for s in get_tracer().spans()
+               if s.name == "lgbm/eval/metric"]
+    assert metrics[:2] == ["BinaryLoglossMetric", "AUCMetric"]
+
+
+def test_device_scopes_hold_every_phase_and_outlive_the_booster():
+    import gc
+
+    import jax
+    from lightgbm_tpu.obs import scopes
+
+    gc.collect()
+    live_before = len(jax.live_arrays())
+    scopes.reset_scopes()
+    bst, train, valid = _binary_job(seed=5, rows=1100, bagging_fraction=0.5,
+                                    bagging_freq=1)
+    for _ in range(2):
+        bst.update()
+    bst.eval_valid()
+    table = obs.device_scopes()
+    assert table and all(type(k) is str and type(v) is str
+                         for k, v in table.items())
+    found = set(table.values())
+    R = "lgbm/frontier_round/"
+    for scope in ("lgbm/gradients", "lgbm/sample", "lgbm/root",
+                  R + "select", R + "partition/decide", R + "partition/rank",
+                  R + "partition/scatter", R + "bookkeeping",
+                  R + "hist_gather", R + "hist", "lgbm/split_search",
+                  "lgbm/finalize", "lgbm/score_update",
+                  "lgbm/valid_traverse"):
+        assert scope in found, scope
+    assert found <= set(scopes.SCOPES) | {"", scopes.AMBIGUOUS}
+    # filled during the first update() and not again
+    assert obs.device_scopes() == table
+    recorded = [s.args["program"] for s in get_tracer().spans()
+                if s.name == "lgbm/scope_table"]
+    assert "train.grow_tree" in recorded
+    assert len(recorded) == len(set(recorded))
+    del bst, train, valid
+    gc.collect()
+    assert obs.device_scopes() == table         # the table is still there
+    assert len(jax.live_arrays()) <= live_before   # and holds no device array
+
+
+def test_op_key_and_scope_of_read_hlo_lines():
+    from lightgbm_tpu.obs.scopes import op_key, scope_of
+
+    line = ("  %fusion.968 = s32[13281250]{0:T(1024)} fusion(s32[13281251]"
+            "{0:T(1024)S(1)} %custom-call.262), kind=kCustom, calls=%f, "
+            'metadata={op_name="jit(grow_tree_step)/jit(main)/lgbm/'
+            'frontier_round/while/body/partition/rank/gather"}')
+    assert op_key(line) == "fusion.968 s32[13281250]"
+    # a device trace names the event by the same line without the metadata
+    assert op_key(line.split(", metadata")[0].strip()) == op_key(line)
+    assert op_key("ROOT %t = (s32[], f32[4]{0}) tuple(%a, %b)") == "t (...)"
+    assert op_key("not an instruction") is None
+    pre = "jit(grow_tree_step)/jit(main)/"
+    assert scope_of(pre + "lgbm/frontier_round/while/body/partition/rank/"
+                    "gather") == "lgbm/frontier_round/partition/rank"
+    # control flow and transforms are no scopes
+    assert scope_of(pre + "lgbm/frontier_round/while/body/cond/branch_1_fun/"
+                    "hist/mul") == "lgbm/frontier_round/hist"
+    assert scope_of(pre + "lgbm/frontier_round/while/body/hist_gather/"
+                    "jit(_take)/jit(_where)/select_n") \
+        == "lgbm/frontier_round/hist_gather"
+    assert scope_of(pre + "lgbm/frontier_round/while/body/lgbm/split_search/"
+                    "vmap(reduce_max)") == "lgbm/split_search"
+    assert scope_of(pre + "lgbm/frontier_round/while/cond/select/top_k") \
+        == "lgbm/frontier_round/select"
+    assert scope_of(pre + "lgbm/frontier_round/while/body/copy") \
+        == "lgbm/frontier_round"
+    assert scope_of(pre + "convert_element_type") == ""
+
+
+def _frontier_counts(num_leaves, seed=0, rows=2000):
+    """(rounds, rows_passed, rows_selected, dumped trees) of a two-tree job."""
+    obs_metrics.reset()
+    bst, _, _ = _binary_job(seed=seed, rows=rows, num_leaves=num_leaves)
+    bst.update()
+    bst.update()
+    trees = bst.dump_model()["tree_info"]       # drains: the counters land
+    snap = obs_metrics.snapshot()
+    assert snap["train.frontier_rounds_per_tree"]["count"] == 2
+    return (snap["train.frontier_rounds"]["value"],
+            snap["train.rows_passed"]["value"],
+            snap["train.rows_selected"]["value"], trees)
+
+
+def _internal_counts(node):
+    if "split_index" not in node:
+        return []
+    return ([node["internal_count"]] + _internal_counts(node["left_child"])
+            + _internal_counts(node["right_child"]))
+
+
+def test_frontier_counters_agree_with_the_dumped_trees():
+    rows = 2000
+    # one round a tree: the root is the only leaf there is to split, and the
+    # round passes and selects every row
+    rounds, passed, selected, trees = _frontier_counts(2, rows=rows)
+    assert [t["num_leaves"] for t in trees] == [2, 2]
+    assert rounds == 2 and passed == 2 * rows
+    assert selected == sum(t["tree_structure"]["internal_count"]
+                           for t in trees) == 2 * rows
+    # monotone in num_leaves, and bounded by the trees' own structure: every
+    # split the tree kept was a selected leaf of some round (a round may
+    # select more than the replay keeps), and a round selects no row twice
+    last = (rounds, passed, selected)
+    for num_leaves in (8, 31):
+        rounds, passed, selected, trees = _frontier_counts(num_leaves,
+                                                           rows=rows)
+        assert passed == rounds * rows
+        kept = sum(sum(_internal_counts(t["tree_structure"])) for t in trees)
+        assert kept <= selected <= passed
+        assert (rounds, passed, selected) >= last
+        assert rounds > last[0]
+        last = (rounds, passed, selected)
+    # 30 splits at up to 16 a round after the doubling rounds
+    assert rounds >= 2 * 6
+
+
+_GROW_N, _GROW_F, _GROW_BINS = 3000, 6, 32
+
+
+@pytest.fixture(scope="module")
+def grow_with_and_without_stats():
+    """The frontier grower jitted twice, ``with_stats`` off and on; compiled
+    once for every seed."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.grower import GrowerConfig, grow_tree
+    from lightgbm_tpu.ops.split import SplitParams
+
+    n, f, nb = _GROW_N, _GROW_F, _GROW_BINS
+    sp = SplitParams(lambda_l1=0.0, lambda_l2=0.0, min_data_in_leaf=5,
+                     min_sum_hessian_in_leaf=1e-3, min_gain_to_split=0.0,
+                     max_delta_step=0.0, path_smooth=0.0, cat_smooth=10.0,
+                     cat_l2=10.0, max_cat_to_onehot=4)
+    cfg = GrowerConfig(num_leaves=31, max_depth=-1, max_bin=nb, split=sp,
+                       feature_fraction_bynode=1.0, hist_method="scatter",
+                       hist_chunk_rows=65536, grower_mode="frontier")
+
+    def grow(with_stats):
+        return jax.jit(lambda b, g, h, key: grow_tree(
+            b, g, h, jnp.ones(n, jnp.float32), jnp.ones(f, bool),
+            jnp.full(f, nb, jnp.int32), jnp.zeros(f, jnp.int32),
+            jnp.full(f, -1, jnp.int32), jnp.zeros(f, bool),
+            jnp.zeros(f, jnp.int32), key, cfg, with_stats=with_stats))
+    return grow(False), grow(True)
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_counters_in_the_loop_state_change_no_tree(
+        seed, grow_with_and_without_stats):
+    """``with_stats`` adds three scalars to the frontier loop's state and a
+    third result; the tree and the row assignment are bit for bit what the
+    program without them gives."""
+    import jax
+    import jax.numpy as jnp
+
+    plain, with_stats = grow_with_and_without_stats
+    rng = np.random.default_rng(seed)
+    n = _GROW_N
+    args = (jnp.asarray(rng.integers(0, _GROW_BINS, size=(n, _GROW_F)),
+                        jnp.uint8),
+            jnp.asarray(rng.normal(size=n), jnp.float32),
+            jnp.asarray(rng.uniform(0.1, 1.0, size=n), jnp.float32),
+            jax.random.PRNGKey(seed))
+    tree, assign = plain(*args)
+    tree_s, assign_s, stats = with_stats(*args)
+    assert int(tree.num_leaves) == 31
+    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(tree_s)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(assign), np.asarray(assign_s))
+    rounds, hi, lo = (int(v) for v in np.asarray(stats))
+    assert rounds >= 6 and n <= (hi << 20) + lo <= rounds * n
 
 
 def test_telemetry_off_keeps_journal_untouched(train_telemetry_env):

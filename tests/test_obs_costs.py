@@ -23,7 +23,6 @@ from lightgbm_tpu.obs import metrics as obs_metrics
 from lightgbm_tpu.obs import report as obs_report
 from lightgbm_tpu.obs.events import EventLog, classify_record
 from lightgbm_tpu.obs.tracer import get_tracer
-from lightgbm_tpu.utils.timer import global_timer
 
 pytestmark = pytest.mark.obs
 
@@ -145,13 +144,11 @@ def test_ledger_records_jitted_hist_cost_and_memory(tmp_path):
 def clean_obs_state(tmp_path):
     obs_metrics.reset()
     get_tracer().reset()
-    global_timer.reset()
     saved = costs.get_ledger()
     costs.reset_ledger()
     yield str(tmp_path / "train_events.jsonl")
     costs.set_stats_provider(None)
     costs._LEDGER = saved
-    global_timer.detach_tracer()
     get_tracer().reset()
     obs_metrics.reset()
 
