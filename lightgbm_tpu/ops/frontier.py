@@ -26,9 +26,9 @@ Splits applied beyond the budget ("overshoot") revert for free: a dropped
 split's two child segments are contiguous inside the parent's recorded row
 range, so the parent simply remains a leaf over that range.
 
-Per round the heavy work is batched: ONE element-gather decides every
-selected leaf's split column, ONE pass of segmented cumsums stable-partitions
-all k segments of the row permutation, ONE leaf-grouped row gather feeds the
+Per round the heavy work is batched: ONE element-gather reads every row's
+bin in its leaf's split column, ONE segmented cumsum stable-partitions all k
+segments of the row permutation, ONE leaf-grouped row gather feeds the
 batched Pallas histogram kernel (``build_histogram_leaves``), and the 2k
 child split searches ride a single vmapped ``find_best_split``.  This
 amortizes the sequential tail (per-split small-op overhead, ~33% of round-3
@@ -46,6 +46,7 @@ stream of the same structure as the sequential grower's step-keyed one.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -75,6 +76,14 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     ``hi * 2**20 + lo`` (no 64-bit integers without x64; exact up to 2047
     rounds of 2**31 rows).  Each round passes every one of the ``n`` rows
     whatever it splits, so the two say what share of that work was useful.
+
+    The invariant the rounds rest on: a leaf is one contiguous range of the
+    row permutation ``perm``, ``[leaf_begin, leaf_begin + leaf_nrows)``, and a
+    split stable-partitions that range in place.  So a position's leaf is
+    derived from the ranges wherever it is needed and is never stored per
+    row: a round compares each position against the at most ``frontier_k``
+    ranges it splits (``_spread_by_range``), and the final node assignment
+    ranks it among the leaves' begins.
 
     The device phases carry ``jax.named_scope`` names (``obs/scopes.py``
     lists them): compile-time metadata, nothing at run time.
@@ -121,18 +130,22 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             bin0 = jnp.take(totals, _efb_bundle, axis=0) - jnp.sum(g, axis=1)
             return jnp.concatenate([bin0[:, None, :], g], axis=1)
 
-        def decode_col(colv, feat):
-            off = off_of_feat[feat]
-            nbf = num_bins[feat]
+        def col_tables(feat):
+            # per split feature: its bundle column, and what decode_col needs
+            # of it (its offset inside the bundle, its bin count)
+            return col_of_feat[feat], (off_of_feat[feat], num_bins[feat])
+
+        def decode_col(colv, off, nbf):
             return jnp.where((colv >= off) & (colv < off + nbf - 1),
                              colv - off + 1, 0)
     else:
-        col_of_feat = None
-
         def expand_hist(hb):
             return hb
 
-        def decode_col(colv, feat):
+        def col_tables(feat):
+            return feat, ()
+
+        def decode_col(colv):
             return colv
 
     # ---- combined row payload: (grad, hess, row_weight) packed as trailing
@@ -318,7 +331,6 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
 
         state = dict(
             perm=jnp.arange(n, dtype=jnp.int32),
-            pos_leaf=jnp.zeros(n, jnp.int32),
             leaf_begin=jnp.zeros(LS, jnp.int32),
             leaf_nrows=jnp.zeros(LS, jnp.int32).at[0].set(n),
             leaf_depth=jnp.zeros(LS, jnp.int32),
@@ -398,6 +410,10 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             sel_dleft = b.default_left[sel]
             sel_cbits = b.cat_bits[sel]                       # [k, CW]
             sel_iscat = is_categorical[sel_feat]
+            sel_nanbin = nan_bins[sel_feat]
+            sel_col, sel_decode = col_tables(sel_feat)
+            sel_beg = st["leaf_begin"][sel]
+            sel_rows = st["leaf_nrows"][sel]
             sel_gain = b.gain[sel]
             sp_ghat_i = jnp.minimum(sel_gain, st["leaf_cghat"][sel])
             right_slot = applied + 1 + i_ar                   # leaf slot of right child
@@ -407,65 +423,59 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             left_smaller = b.lc[sel] <= b.rc[sel]
 
         # ---- [N]-pass: decide + segmented stable partition ----------------
+        # A leaf is one contiguous range of ``perm``, so everything a position
+        # needs of its leaf's split is piecewise constant over the at most k
+        # selected ranges: _spread_by_range has it by comparisons.  The one
+        # per-row gather is the bin byte, the one scatter is ``perm``.
         with jax.named_scope("partition"):
+            pos = jnp.arange(n, dtype=jnp.int32)
+            spread = functools.partial(_spread_by_range, pos, sel_beg,
+                                       sel_rows, valid)
             with jax.named_scope("decide"):
-                slot_of_leaf = jnp.full(LS, -1, jnp.int32).at[
-                    jnp.where(valid, sel, LS)].set(i_ar, mode="drop")
-                lf = st["pos_leaf"]
-                si = slot_of_leaf[lf]
-                act = si >= 0
-                sic = jnp.maximum(si, 0)
-                feat_p = sel_feat[sic]
+                act, (col_p, nb_p, thr_p, dleft_p, iscat_p,
+                      *decode_p) = spread((sel_col, sel_nanbin, sel_thr,
+                                           sel_dleft, sel_iscat, *sel_decode))
                 rowid = st["perm"]
                 if mode == "feature":
-                    # columns are sharded: the owner shard selects its local column
+                    # columns are sharded (col_p is the split feature's global
+                    # index): the owner shard selects its local column
                     # and ONE [N] psum broadcasts it (rows are replicated, so every
                     # shard's perm/selection state is identical; grower.py
                     # partition_and_hist does the same per split — here it is once
                     # per ROUND)
-                    local_ix = jnp.clip(feat_p - f_start, 0, f - 1)
-                    owns = (feat_p >= f_start) & (feat_p < f_start + f)
+                    local_ix = jnp.clip(col_p - f_start, 0, f - 1)
+                    owns = (col_p >= f_start) & (col_p < f_start + f)
                     colv_loc = jnp.take(comb_flat,
                                         rowid * ncc + local_ix).astype(jnp.int32)
                     colv = jax.lax.psum(jnp.where(owns & act, colv_loc, 0), axis)
                 else:
-                    col_id_p = col_of_feat[feat_p] if efb is not None else feat_p
                     colv = jnp.take(comb_flat,
-                                    rowid * ncc + col_id_p).astype(jnp.int32)
-                    colv = decode_col(colv, feat_p)
-                nb_p = nan_bins[feat_p]
+                                    rowid * ncc + col_p).astype(jnp.int32)
+                    colv = decode_col(colv, *decode_p)
                 is_miss = (colv == nb_p) & (nb_p >= 0)
-                wsel = jnp.take(sel_cbits.reshape(-1),
-                                sic * cw + jnp.clip(colv >> 5, 0, cw - 1))
+                # word min(colv >> 5, cw - 1) of the leaf's categorical bit set
+                _, words_p = spread([sel_cbits[:, w] for w in range(cw)])
+                wsel = words_p[0]
+                for w in range(1, cw):
+                    wsel = jnp.where(colv >> 5 >= w, words_p[w], wsel)
                 gl_cat = ((wsel >> (colv & 31)) & 1) > 0
-                gl = jnp.where(sel_iscat[sic], gl_cat,
-                               jnp.where(is_miss, sel_dleft[sic],
-                                         colv <= sel_thr[sic]))
+                gl = jnp.where(iscat_p, gl_cat,
+                               jnp.where(is_miss, dleft_p, colv <= thr_p))
                 gl_a = gl & act
             with jax.named_scope("rank"):
                 cumL = jnp.concatenate([jnp.zeros(1, jnp.int32),
                                         jnp.cumsum(gl_a.astype(jnp.int32))])
-                cumA = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                        jnp.cumsum(act.astype(jnp.int32))])
-                beg_p = st["leaf_begin"][lf]
-                baseL = jnp.take(cumL, beg_p)
-                baseA = jnp.take(cumA, beg_p)
-                rankL = cumL[1:] - gl_a.astype(jnp.int32) - baseL     # exclusive
-                rankA = cumA[1:] - act.astype(jnp.int32) - baseA
+                baseL_i = jnp.take(cumL, sel_beg)
+                nl_i = jnp.take(cumL, sel_beg + sel_rows) - baseL_i   # [k] raw left
+                _, (beg_p, baseL_p, nl_p) = spread((sel_beg, baseL_i, nl_i))
+                rankL = cumL[1:] - gl_a.astype(jnp.int32) - baseL_p   # exclusive
+                rankA = pos - beg_p        # every row of a selected range is active
                 rankR = rankA - rankL
-                sel_beg = st["leaf_begin"][sel]
-                sel_rows = st["leaf_nrows"][sel]
-                nl_i = (jnp.take(cumL, sel_beg + sel_rows)
-                        - jnp.take(cumL, sel_beg))                    # [k] raw left
-                nl_p = nl_i[sic]
             with jax.named_scope("scatter"):
-                pos_idx = jnp.arange(n, dtype=jnp.int32)
                 new_pos = jnp.where(act,
                                     beg_p + jnp.where(gl, rankL, nl_p + rankR),
-                                    pos_idx)
+                                    pos)
                 perm_new = jnp.zeros(n, jnp.int32).at[new_pos].set(rowid)
-                pos_leaf_new = jnp.zeros(n, jnp.int32).at[new_pos].set(
-                    jnp.where(gl | ~act, lf, right_slot[sic]))
 
         # ---- leaf bookkeeping --------------------------------------------
         with jax.named_scope("bookkeeping"):
@@ -639,7 +649,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                             right_slot, jnp.minimum(sr.gain, sp_ghat_i), valid)
 
         return dict(
-            perm=perm_new, pos_leaf=pos_leaf_new,
+            perm=perm_new,
             leaf_begin=leaf_begin, leaf_nrows=leaf_nrows,
             leaf_depth=leaf_depth, leaf_sum_g=leaf_sum_g,
             leaf_weight=leaf_weight, leaf_count=leaf_count,
@@ -804,6 +814,27 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
         # rows are sharded: the leaves' rows are local counts
         stats = stats.at[1:].set(jax.lax.psum(stats[1:], axis))
     return tree, node_assign, stats
+
+
+def _spread_by_range(pos, beg, rows, valid, tables):
+    """Per-position values of per-slot tables, by range comparison.
+
+    Slot ``i`` owns the positions ``[beg[i], beg[i] + rows[i])`` if
+    ``valid[i]``; the valid slots' ranges do not overlap, and an empty or
+    invalid slot owns nothing.  Returns ``(act, values)``: ``act[p]`` says
+    that some slot owns position ``p``, and ``values[j][p]`` is
+    ``tables[j][i]`` of that slot (zero where there is none).  The loop over
+    the ``k`` slots is unrolled into selects that XLA fuses into elementwise
+    work over ``pos``: no per-row gather, no ``[N, k]`` intermediate.
+    """
+    end = beg + jnp.where(valid, rows, 0)
+    act = jnp.zeros(pos.shape, bool)
+    values = [jnp.zeros(pos.shape, t.dtype) for t in tables]
+    for i in range(beg.shape[0]):
+        in_i = (pos >= beg[i]) & (pos < end[i])
+        act = act | in_i
+        values = [jnp.where(in_i, t[i], v) for t, v in zip(tables, values)]
+    return act, values
 
 
 def _as_batch(s: SplitResult, m: int) -> SplitResult:

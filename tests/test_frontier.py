@@ -84,6 +84,41 @@ def test_categorical_parity(clf_data):
     _assert_identical(bs, bf, Xc)
 
 
+def test_categorical_many_words_partition():
+    # 200 categories: a split's bit set spans seven 32-bit words, so the
+    # partition has to pick the word of each row's bin, not only word 0.
+    # The rows the grower put in a leaf are the rows the model sends there.
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(3000, 6)).astype(np.float32)
+    X[:, 0] = rng.integers(0, 200, len(X))
+    good = rng.random(200) < 0.5
+    y = (good[X[:, 0].astype(int)]
+         ^ (rng.random(len(X)) < 0.1)).astype(np.float64)
+    p = {"objective": "binary", "num_leaves": 31, "max_cat_to_onehot": 4,
+         "min_data_per_group": 5, "cat_smooth": 1.0, "max_cat_threshold": 128,
+         "min_data_in_leaf": 5, "tree_grower": "frontier", "verbose": -1}
+    bst = lgb.train(p, lgb.Dataset(X, label=y, params=p,
+                                   categorical_feature=[0]), 2)
+    words = [int(w) for line in bst.model_to_string().splitlines()
+             if line.startswith("cat_threshold=")
+             for w in line.split("=")[1].split()]
+    assert sum(w != 0 for w in words) > 8
+
+    def leaf_counts(node, out):
+        if "leaf_index" in node:
+            out[node["leaf_index"]] = node["leaf_count"]
+        else:
+            leaf_counts(node["left_child"], out)
+            leaf_counts(node["right_child"], out)
+        return out
+
+    leaves = bst.predict(X, pred_leaf=True)
+    for t, info in enumerate(bst.dump_model()["tree_info"]):
+        said = leaf_counts(info["tree_structure"], {})
+        got = np.bincount(leaves[:, t], minlength=len(said))
+        assert {i: int(c) for i, c in enumerate(got)} == said
+
+
 def test_max_depth_and_bagging_parity(clf_data):
     X, y = clf_data
     bs, bf = _models({"objective": "binary", "num_leaves": 63, "max_depth": 4,
@@ -331,3 +366,119 @@ def test_bynode_extra_trees_parallel_frontier(clf_data, learner):
     np.testing.assert_array_equal(b1.predict(X, pred_leaf=True),
                                   b2.predict(X, pred_leaf=True))
     assert b1.num_trees() == 3
+
+
+# ---------------------------------------------------------------------------
+# the [N]-pass rests on one invariant: a leaf is one contiguous range of
+# ``perm``, so what a position needs of its leaf comes from range comparisons
+@pytest.mark.parametrize("layout", ["random", "whole"])
+@pytest.mark.parametrize("k", [1, 3, 16])
+def test_spread_by_range_matches_gathers(k, layout):
+    """_spread_by_range against the per-row gathers it replaced
+    (``slot_of_leaf[pos_leaf]``, then ``table[slot]``)."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.frontier import _spread_by_range
+    n, LS = 997, 40
+    for seed in range(5):
+        rng = np.random.default_rng(100 * k + seed)
+        if layout == "whole":           # one leaf is the whole array
+            nrows = np.zeros(LS, np.int64)
+            nrows[rng.integers(LS)] = n
+        else:                           # about half of the leaves are empty
+            live = rng.random(LS) < 0.5
+            live[rng.integers(LS)] = True
+            cuts = np.sort(rng.integers(0, n + 1, live.sum() - 1))
+            nrows = np.zeros(LS, np.int64)
+            nrows[live] = np.diff(np.concatenate([[0], cuts, [n]]))
+        order = rng.permutation(LS)     # leaf slots in no order of position
+        begin = np.zeros(LS, np.int64)
+        begin[order] = np.cumsum(nrows[order]) - nrows[order]
+        pos_leaf = np.zeros(n, np.int64)
+        for leaf in range(LS):
+            pos_leaf[begin[leaf]:begin[leaf] + nrows[leaf]] = leaf
+        sel = rng.permutation(LS)
+        valid = rng.random(k) < 0.7
+        if layout == "whole":           # ... and a valid slot selects it
+            whole = np.argmax(nrows)
+            sel = np.concatenate([[whole], sel[sel != whole]])
+            valid[0] = True
+        sel = sel[:k]
+        tables = (rng.integers(-5, 300, k).astype(np.int32),
+                  rng.random(k) < 0.5,
+                  rng.integers(0, 2 ** 31 - 1, k).astype(np.int32))
+
+        slot_of_leaf = np.full(LS, -1)
+        slot_of_leaf[sel[valid]] = np.arange(k)[valid]
+        si = slot_of_leaf[pos_leaf]
+        want_act = si >= 0
+        act, got = _spread_by_range(
+            jnp.arange(n, dtype=jnp.int32), jnp.asarray(begin[sel], jnp.int32),
+            jnp.asarray(nrows[sel], jnp.int32), jnp.asarray(valid),
+            tuple(jnp.asarray(t) for t in tables))
+        np.testing.assert_array_equal(np.asarray(act), want_act)
+        for t, g in zip(tables, got):
+            assert g.dtype == t.dtype
+            np.testing.assert_array_equal(
+                np.asarray(g), np.where(want_act, t[np.maximum(si, 0)], 0))
+
+
+def _walk_eqns(jaxpr, scope=""):
+    """Every equation of a jaxpr and of the jaxprs nested in it, with the
+    scope names it sits under (outer equations' name stacks included)."""
+    for eqn in jaxpr.eqns:
+        here = scope + "/" + str(eqn.source_info.name_stack)
+        yield eqn, here
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk_eqns(sub, here)
+
+
+def test_partition_gathers_one_byte_and_scatters_perm_only():
+    """Under the ``partition`` scope the only [n]-sized gather is the bin
+    look-up and the only [n]-sized scatter is ``perm``; ``perm`` is the only
+    [n]-sized array the round loop carries (no per-position leaf id)."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.frontier import grow_tree_frontier
+    from lightgbm_tpu.ops.grower import GrowerConfig
+    from lightgbm_tpu.ops.split import SplitParams
+    n, f = 1000, 5
+    cfg = GrowerConfig(
+        num_leaves=8, max_depth=-1, max_bin=32,
+        split=SplitParams(0.0, 0.0, 1, 1e-3, 0.0, 0.0, 0.0, 10.0, 10.0, 4),
+        feature_fraction_bynode=1.0, hist_method="scatter",
+        hist_chunk_rows=8192, sorted_cat=False, frontier_k=3)
+    rng = np.random.default_rng(0)
+    args = (jnp.asarray(rng.integers(0, 32, (n, f)), jnp.uint8),
+            jnp.asarray(rng.normal(size=n), jnp.float32),
+            jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32),
+            jnp.ones(f, bool), jnp.full(f, 32, jnp.int32),
+            jnp.zeros(f, jnp.int32), jnp.full(f, -1, jnp.int32),
+            jnp.zeros(f, bool).at[0].set(True), jnp.zeros(f, jnp.int32),
+            jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(
+        lambda *a: grow_tree_frontier(*a, cfg, with_stats=True))(*args)
+
+    gathers, scatters, loops = [], [], []
+    for eqn, scope in _walk_eqns(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if name == "while" and any(
+                "partition" in s for _, s in _walk_eqns(
+                    eqn.params["body_jaxpr"].jaxpr)):
+            loops.append(eqn)
+        if "partition" not in scope:
+            continue
+        if eqn.outvars and eqn.outvars[0].aval.shape == (n,):
+            if name == "gather":
+                gathers.append(eqn.invars[0].aval)
+            elif name.startswith("scatter"):
+                scatters.append(eqn.outvars[0].aval)
+    ncc = f + 12                               # bins + packed (g, h, w) bytes
+    assert [(a.shape, a.dtype) for a in gathers] == [((n * ncc,), jnp.uint8)]
+    assert [(a.shape, a.dtype) for a in scatters] == [((n,), jnp.int32)]
+    assert len(loops) == 1
+    n_carried = len(loops[0].params["body_jaxpr"].out_avals)
+    carried = [v.aval for v in loops[0].invars[-n_carried:]]
+    assert [a.dtype for a in carried if a.shape == (n,)] == [jnp.int32]
