@@ -144,7 +144,8 @@ class GBDT:
             host = jax.device_get(arrs)
             tracer.end("lgbm/update/drain",
                        **(self._count_frontier(*stats_dev)
-                          if stats_dev is not None else {}))
+                          if stats_dev is not None else {}),
+                       **self._count_leaves(host))
             self._observe_drain(_it)
             if health_dev is not None:
                 # sentinel scalars rode the same async materialization —
@@ -199,6 +200,36 @@ class GBDT:
                         ("train.rows_selected", counts["rows_selected"])):
             obs_metrics.counter(name).inc(v)
             obs_metrics.histogram(name + "_per_tree").observe(v)
+        return counts
+
+    @staticmethod
+    def _count_leaves(host) -> Dict[str, float]:
+        """What one tree's leaves look like, from the arrays the drain
+        already holds on the host (no device work): how many there are, how
+        many hold under 100 rows, the smallest hessian sum and the deepest
+        leaf, into ``obs.metrics`` and returned for the drain span to carry.
+        A tree of small leaves is where a sum's error shows (frontier.py,
+        "Sums"), and its depth is the rounds and the levels it costs."""
+        nl = int(host.num_leaves)
+        if nl <= 1:
+            return {}
+        depth = np.zeros(nl - 1, np.int64)      # a node's id is above its parent's
+        for child in (np.asarray(host.left_child)[:nl - 1],
+                      np.asarray(host.right_child)[:nl - 1]):
+            inner = child >= 0
+            depth[child[inner]] = np.flatnonzero(inner)
+        for j in range(1, nl - 1):
+            depth[j] = depth[depth[j]] + 1      # held the parent's id until now
+        counts = {"leaves": nl,
+                  "leaves_under_100_rows": int(np.sum(
+                      np.asarray(host.leaf_count)[:nl] < 100)),
+                  "tree_depth": int(depth.max()) + 1,
+                  "min_leaf_hessian": float(np.min(
+                      np.asarray(host.leaf_weight)[:nl]))}
+        for name in ("leaves", "leaves_under_100_rows", "tree_depth"):
+            obs_metrics.counter("train." + name).inc(counts[name])
+        obs_metrics.histogram("train.min_leaf_hessian_per_tree").observe(
+            counts["min_leaf_hessian"])
         return counts
 
     def _record_program(self, name: str, fn, *args, **meta) -> None:
@@ -779,7 +810,8 @@ class GBDT:
             tracer.begin("lgbm/update/drain", tree_iteration=it)
             tree_host = jax.device_get(tree_arrays)
             tracer.end("lgbm/update/drain",
-                       **self._count_frontier(stats_dev, n))
+                       **self._count_frontier(stats_dev, n),
+                       **self._count_leaves(tree_host))
             if self._health_due(it, k):
                 # the slow path already syncs per tree; check in line
                 self._run_numeric_check(it, self._health_stats_fn()(

@@ -48,6 +48,9 @@ SCOPES = (
     "lgbm/frontier_round/hist_gather",
     "lgbm/frontier_round/hist",
     "lgbm/split_search",
+    # pair subtraction of siblings and a leaf's totals from its own
+    # histogram, wherever nested (both growers)
+    "lgbm/sum_repair",
     "lgbm/finalize",
     "lgbm/score_update",
     "lgbm/valid_traverse",

@@ -53,7 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .histogram import build_histogram, build_histogram_leaves, unrolled_rank
+from .histogram import (build_histogram, build_histogram_leaves, fold_hist,
+                        hist_totals, psum_hist, sub_hist, unrolled_rank)
 from .split import (NEG_INF, SplitResult, cat_words, find_best_split,
                     pack_bin_bitset)
 
@@ -84,6 +85,14 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     row: a round compares each position against the at most ``frontier_k``
     ranges it splits (``_spread_by_range``), and the final node assignment
     ranks it among the leaves' begins.
+
+    Sums: the histogram store holds pairs (``histogram.py``: a float32 sum
+    and what it rounds away), siblings are subtracted in pairs, a leaf's
+    totals are the sum of its own histogram's first column
+    (``hist_totals``), and the split search sums the smaller side of every
+    candidate from its own bins.  So a leaf's gradient sum, hessian sum and
+    count are right relative to that leaf's size, down to 20 rows under a
+    root of 2**25, and nothing is carried down from a parent but its rows.
 
     The device phases carry ``jax.named_scope`` names (``obs/scopes.py``
     lists them): compile-time metadata, nothing at run time.
@@ -184,7 +193,18 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     def reduce_hist(h):
         # data: full-histogram allreduce; feature/voting keep shard-local
         # stores (voting reduces only ELECTED slices inside the search)
-        return jax.lax.psum(h, axis) if mode == "data" else h
+        return psum_hist(h, axis) if mode == "data" else h
+
+    @jax.named_scope("lgbm/sum_repair")
+    def totals_of(h):
+        """[..., 3] (sum_g, sum_h, count) of the leaves whose stored pair
+        histograms are ``h`` [..., n_cols, Bb, 6], the same on every shard."""
+        t = hist_totals(h)
+        if mode == "voting":        # rows are sharded and the store is local
+            t = jax.lax.psum(t, axis)
+        elif mode == "feature":     # columns are sharded: shard 0's first
+            t = jax.lax.psum(jnp.where(dev == 0, t, 0.0), axis)
+        return t
 
     # --- per-node RNG streams (feature_fraction_bynode, extra_trees) ------
     # The sequential grower keys both draws by the split-step index; the
@@ -227,9 +247,10 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
         return monotone_gain_mult(depth, monotone, cfg.monotone_penalty)
 
     @jax.named_scope("lgbm/split_search")
-    def find(hist_fb, sum_g, sum_h, count, fmask=None, rand=None,
+    def find(hist_pair, sum_g, sum_h, count, fmask=None, rand=None,
              lo=NEG_INF, hi=POS_INF, mult=None):
         fmask = feature_mask if fmask is None else fmask
+        hist_fb = expand_hist(fold_hist(hist_pair))
         if mode == "feature":
             from .grower import _reduce_split_global
             s = find_best_split(hist_fb, num_bins_l, default_bins_l,
@@ -305,12 +326,8 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                             method=cfg.hist_method,
                             chunk_rows=cfg.hist_chunk_rows,
                             variant=cfg.hist_variant))
-        tot = jnp.stack([jnp.sum(grad * row_weight),
-                         jnp.sum(hess * row_weight), jnp.sum(row_weight)])
-        if mode in ("data", "voting"):
-            # feature mode replicates rows, so local sums are already global
-            tot = jax.lax.psum(tot, axis)
-        root_split = find(expand_hist(root_hist), tot[0], tot[1], tot[2],
+        tot = totals_of(root_hist)
+        root_split = find(root_hist, tot[0], tot[1], tot[2],
                           fmask=node_mask_for(0), rand=rand_thr_for(0),
                           mult=mult_for(0))
 
@@ -343,7 +360,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             pend=pend0,
             pend_ghat=jnp.full(LS, NEG_INF, jnp.float32).at[0].set(
                 jnp.minimum(root_split.gain, POS_INF)),
-            hist=jnp.zeros((LS, n_cols, Bb, 3), jnp.float32).at[0].set(
+            hist=jnp.zeros((LS, n_cols, Bb, 6), jnp.float32).at[0].set(
                 root_hist),
             # split records
             sp_ghat=jnp.full(S, NEG_INF, jnp.float32),
@@ -357,8 +374,6 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             sp_gain=jnp.zeros(S, jnp.float32),
             sp_lout=jnp.zeros(S, jnp.float32),
             sp_rout=jnp.zeros(S, jnp.float32),
-            sp_lsumg=jnp.zeros(S, jnp.float32),
-            sp_rsumg=jnp.zeros(S, jnp.float32),
             sp_lweight=jnp.zeros(S, jnp.float32),
             sp_rweight=jnp.zeros(S, jnp.float32),
             sp_lcount=jnp.zeros(S, jnp.float32),
@@ -489,12 +504,6 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                              right_slot, nr_i, valid)
             leaf_depth = upd(upd(st["leaf_depth"], sel, depth_c, valid),
                              right_slot, depth_c, valid)
-            leaf_sum_g = upd(upd(st["leaf_sum_g"], sel, b.lg[sel], valid),
-                             right_slot, b.rg[sel], valid)
-            leaf_weight = upd(upd(st["leaf_weight"], sel, b.lh[sel], valid),
-                              right_slot, b.rh[sel], valid)
-            leaf_count = upd(upd(st["leaf_count"], sel, b.lc[sel], valid),
-                             right_slot, b.rc[sel], valid)
             leaf_cghat = upd(upd(st["leaf_cghat"], sel, sp_ghat_i, valid),
                              right_slot, sp_ghat_i, valid)
             leaf_cs = upd(upd(st["leaf_cs"], sel, s_idx, valid),
@@ -538,12 +547,6 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                 sp_gain=rec(st["sp_gain"], sel_gain),
                 sp_lout=rec(st["sp_lout"], b.lout[sel]),
                 sp_rout=rec(st["sp_rout"], b.rout[sel]),
-                sp_lsumg=rec(st["sp_lsumg"], b.lg[sel]),
-                sp_rsumg=rec(st["sp_rsumg"], b.rg[sel]),
-                sp_lweight=rec(st["sp_lweight"], b.lh[sel]),
-                sp_rweight=rec(st["sp_rweight"], b.rh[sel]),
-                sp_lcount=rec(st["sp_lcount"], b.lc[sel]),
-                sp_rcount=rec(st["sp_rcount"], b.rc[sel]),
                 sp_value=rec(st["sp_value"], sp_value_i),
                 sp_count=rec(st["sp_count"], st["leaf_count"][sel]),
                 sp_begin=rec(st["sp_begin"], sel_beg),
@@ -592,24 +595,25 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
         hist_small = jax.lax.switch(idx, [mk_branch(c) for c in caps2],
                                     perm_new)
         with jax.named_scope("hist"):
-            hist_small = reduce_hist(hist_small)              # [k, NC, Bb, 3]
+            hist_small = reduce_hist(hist_small)              # [k, NC, Bb, 6]
 
+        # the larger child is what the smaller leaves of the parent, in
+        # pairs; each child's totals are its own histogram's
+        with jax.named_scope("lgbm/sum_repair"):
             parent_hist = st["hist"][sel]
-            large_hist = parent_hist - hist_small
+            large_hist = sub_hist(parent_hist, hist_small)
             ls4 = left_smaller[:, None, None, None]
             lhist = jnp.where(ls4, hist_small, large_hist)
-            rhist = parent_hist - lhist
+            rhist = jnp.where(ls4, large_hist, hist_small)
             v4 = valid[:, None, None, None]
             hist = st["hist"].at[sel].set(jnp.where(v4, lhist, parent_hist))
             hist = hist.at[jnp.where(valid, right_slot, LS)].set(
                 rhist, mode="drop")
+            hist2 = jnp.concatenate([lhist, rhist])       # [2k, NC, Bb, 6]
+            tot2 = totals_of(hist2)                       # [2k, 3]
+            g2, h2, c2 = tot2[:, 0], tot2[:, 1], tot2[:, 2]
 
         # ---- 2k child split searches (one vmapped program) ----------------
-        with jax.named_scope("lgbm/split_search"):
-            hist2 = jnp.concatenate([lhist, rhist])       # [2k, NC, Bb, 3]
-            g2 = jnp.concatenate([b.lg[sel], b.rg[sel]])
-            h2 = jnp.concatenate([b.lh[sel], b.rh[sel]])
-            c2 = jnp.concatenate([b.lc[sel], b.rc[sel]])
         if use_mono:
             # bounds per child, penalty factor per child depth; the step
             # keying rides along (node_mask_for/rand_thr_for ignore the
@@ -619,7 +623,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             hi2 = jnp.concatenate([l_hi, r_hi])
             d2 = jnp.concatenate([depth_c, depth_c])
             s2 = jax.vmap(lambda hc, g_, h_, c_, st_, lo_, hi_, d_: find(
-                expand_hist(hc), g_, h_, c_,
+                hc, g_, h_, c_,
                 fmask=node_mask_for(st_), rand=rand_thr_for(st_),
                 lo=lo_, hi=hi_, mult=mult_for(d_)))(
                 hist2, g2, h2, c2, steps2, lo2, hi2, d2)
@@ -629,13 +633,11 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             # like the sequential grower's per-step draw)
             steps2 = jnp.concatenate([s_idx, s_idx]) + 1
             s2 = jax.vmap(lambda hc, g_, h_, c_, st_: find(
-                expand_hist(hc), g_, h_, c_,
+                hc, g_, h_, c_,
                 fmask=node_mask_for(st_), rand=rand_thr_for(st_)))(
                 hist2, g2, h2, c2, steps2)
         else:
-            s2 = jax.vmap(lambda hc, g_, h_, c_: find(expand_hist(hc),
-                                                      g_, h_, c_))(
-                hist2, g2, h2, c2)
+            s2 = jax.vmap(find)(hist2, g2, h2, c2)
         with jax.named_scope("bookkeeping"):
             depth_ok = (cfg.max_depth <= 0) | (depth_c < cfg.max_depth)
             dok2 = jnp.concatenate([depth_ok, depth_ok])
@@ -647,6 +649,19 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             pend_ghat = upd(upd(st["pend_ghat"], sel,
                                 jnp.minimum(sl.gain, sp_ghat_i), valid),
                             right_slot, jnp.minimum(sr.gain, sp_ghat_i), valid)
+            # the children's sums, into their leaf slots and the split record
+            (lh, rh), (lc, rc) = ((t[:k], t[k:]) for t in (h2, c2))
+            leaf_sum_g = upd(upd(st["leaf_sum_g"], sel, g2[:k], valid),
+                             right_slot, g2[k:], valid)
+            leaf_weight = upd(upd(st["leaf_weight"], sel, lh, valid),
+                              right_slot, rh, valid)
+            leaf_count = upd(upd(st["leaf_count"], sel, lc, valid),
+                             right_slot, rc, valid)
+            recs.update(
+                sp_lweight=rec(st["sp_lweight"], lh),
+                sp_rweight=rec(st["sp_rweight"], rh),
+                sp_lcount=rec(st["sp_lcount"], lc),
+                sp_rcount=rec(st["sp_rcount"], rc))
 
         return dict(
             perm=perm_new,

@@ -27,10 +27,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .histogram import build_histogram, gather_rows, unrolled_rank
+from .histogram import (build_histogram, fold_hist, gather_rows, hist_totals,
+                        psum_hist, psum_scatter_hist, sub_hist, unrolled_rank)
 from .split import (NEG_INF, SplitParams, SplitResult, bitset_contains,
-                    cat_words, find_best_split, leaf_gain, leaf_output,
-                    pack_bin_bitset, per_feature_gains)
+                    cat_words, derive_larger, find_best_split, leaf_gain,
+                    leaf_output, pack_bin_bitset, per_feature_gains)
 
 
 def _reduce_split_global(s: SplitResult, axis_name: str) -> SplitResult:
@@ -531,9 +532,20 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             return h
         if dp_scatter:
             hp = jnp.pad(h, ((0, shard_wp - n_cols), (0, 0), (0, 0)))
-            return jax.lax.psum_scatter(hp, axis, scatter_dimension=0,
-                                        tiled=True)
-        return jax.lax.psum(h, axis)
+            return psum_scatter_hist(hp, axis, cfg.num_shards)
+        return psum_hist(h, axis)
+
+    @jax.named_scope("lgbm/sum_repair")
+    def totals_of(h):
+        """[..., 3] (sum_g, sum_h, count) of the leaves whose stored pair
+        histograms are ``h`` [..., store_w, Bb, 6], the same on every shard:
+        a leaf's sums come from its own histogram (frontier.py, "Sums")."""
+        t = hist_totals(h)
+        if mode == "voting":        # rows are sharded and the store is local
+            t = jax.lax.psum(t, axis)
+        elif mode == "feature" or dp_scatter:   # shard 0 holds column 0
+            t = jax.lax.psum(jnp.where(dev == 0, t, 0.0), axis)
+        return t
 
     # lgbm/* named scopes label the phases inside the single fused program
     # so a device trace (jax.profiler, read through obs.device_scopes())
@@ -670,7 +682,10 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     def find(hist, sum_g, sum_h, count, fmask, parent_output=0.0,
              lo=NEG_INF, hi=-NEG_INF, penalty=None, rand=None, mult=None):
         """Mode-dispatched best-split search (the analog of the reference's
-        learner-specific FindBestSplitsFromHistograms overrides)."""
+        learner-specific FindBestSplitsFromHistograms overrides) of a leaf
+        whose stored pair histogram is ``hist`` and whose totals
+        (``totals_of``) are ``sum_g``, ``sum_h``, ``count``."""
+        hist = expand_hist(fold_hist(hist))
         if mode == "feature" or dp_scatter:
             w = f if mode == "feature" else shard_w
 
@@ -800,13 +815,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
     # ---- root --------------------------------------------------------------
     root_hist = hist_of(row_weight)
-    tot = jnp.stack([jnp.sum(grad * row_weight), jnp.sum(hess * row_weight),
-                     jnp.sum(row_weight)])
-    if mode in ("data", "voting"):
-        # root grad/hess sums are global (reference Allreduce,
-        # data_parallel_tree_learner.cpp:126-152); feature-parallel replicates
-        # rows so local sums are already global
-        tot = jax.lax.psum(tot, axis)
+    tot = totals_of(root_hist)
     fmask0 = node_feature_mask(0)
     if interaction_sets is not None:
         fmask0 = fmask0 * interaction_allowed(jnp.zeros(f_full, jnp.float32))
@@ -816,7 +825,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             rw_pos, tot[2],
             jnp.zeros(f_full, bool) if cegb_coupled is not None else None,
             cegb_used_data)
-    root_split = find(expand_hist(root_hist), tot[0], tot[1], tot[2], fmask0,
+    root_split = find(root_hist, tot[0], tot[1], tot[2], fmask0,
                       penalty=pen0, rand=rand_thresholds(0),
                       mult=gain_mult_for(0))
 
@@ -824,7 +833,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # searches expand to feature space on the fly.  Under dp_scatter each
     # shard stores only its owned feature block: memory / num_shards.
     store_w = shard_w if dp_scatter else n_cols
-    hist_store = jnp.zeros((L, store_w, Bb, 3), jnp.float32).at[0].set(root_hist)
+    hist_store = jnp.zeros((L, store_w, Bb, 6), jnp.float32).at[0].set(root_hist)
     best = _BestSplits.empty(L, cw).set_leaf(0, root_split)
     # depth gate for root handled trivially (max_depth >= 1 always allows root)
 
@@ -896,11 +905,12 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         if mode == "feature":
             local_ix = jnp.clip(feat - f_start, 0, f - 1)
             owns = (feat >= f_start) & (feat < f_start + f)
-            h = expand_hist(st["hist"][leaf])[local_ix]              # [B, 3]
+            h = expand_hist(fold_hist(st["hist"][leaf]))[local_ix]   # [B, 3]
         elif mode == "voting":
-            h = jax.lax.psum(expand_hist(st["hist"][leaf])[feat], axis)
+            h = jax.lax.psum(expand_hist(fold_hist(st["hist"][leaf]))[feat],
+                             axis)
         else:
-            h = expand_hist(st["hist"][leaf])[feat]                  # [B, 3]
+            h = expand_hist(fold_hist(st["hist"][leaf]))[feat]       # [B, 3]
         total = jnp.stack([st["leaf_sum_g"][leaf], st["leaf_weight"][leaf],
                            st["leaf_count"][leaf]])
         bin_ids = jnp.arange(B)
@@ -909,12 +919,12 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # gather which excludes the NaN bin from the RIGHT accumulation and
         # sets default_left=true (GatherInfoForThresholdNumericalInner,
         # feature_histogram.hpp)
-        num_left = jnp.sum(
-            jnp.where(((bin_ids <= thr) | (bin_ids == miss_b))[:, None], h, 0.0),
-            axis=0)
         f_cat = is_categorical[feat]
-        left = jnp.where(f_cat, h[thr], num_left)
-        right = total - left
+        goes_left = jnp.where(f_cat, bin_ids == thr,
+                              (bin_ids <= thr) | (bin_ids == miss_b))[:, None]
+        left, right = derive_larger(
+            jnp.sum(jnp.where(goes_left, h, 0.0), axis=0),
+            jnp.sum(jnp.where(goes_left, 0.0, h), axis=0), total)
         lo, hi = st["leaf_lo"][leaf], st["leaf_hi"][leaf]
         lout = leaf_output(left[0], left[1], p, 0.0, left[2], lo, hi)
         rout = leaf_output(right[0], right[1], p, 0.0, right[2], lo, hi)
@@ -1029,23 +1039,24 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             small_mask = jnp.where(in_leaf & (goes_left == left_smaller),
                                    row_weight, 0.0)
             small_hist = hist_of(small_mask, jnp.sum(small_mask > 0))
-        parent_hist = st["hist"][leaf]
-        large_hist = parent_hist - small_hist
-        lhist = jnp.where(left_smaller, small_hist, large_hist)
-        rhist = parent_hist - lhist
-        hist = setw(setw(st["hist"], leaf, lhist), new_id, rhist)
+        with jax.named_scope("lgbm/sum_repair"):
+            parent_hist = st["hist"][leaf]
+            large_hist = sub_hist(parent_hist, small_hist)
+            lhist = jnp.where(left_smaller, small_hist, large_hist)
+            rhist = jnp.where(left_smaller, large_hist, small_hist)
+            hist = setw(setw(st["hist"], leaf, lhist), new_id, rhist)
+            hist2 = jnp.stack([lhist, rhist])
+            tot2 = totals_of(hist2)      # each child's sums, from its own rows
+            g2, h2, c2 = tot2[:, 0], tot2[:, 1], tot2[:, 2]
 
         # --- child bookkeeping ---
         depth = st["leaf_depth"][leaf] + 1
         leaf_depth = setw(setw(st["leaf_depth"], leaf, depth), new_id, depth)
         leaf_value = setw(setw(st["leaf_value"], leaf, b.lout[leaf]),
                           new_id, b.rout[leaf])
-        leaf_count = setw(setw(st["leaf_count"], leaf, b.lc[leaf]),
-                          new_id, b.rc[leaf])
-        leaf_weight = setw(setw(st["leaf_weight"], leaf, b.lh[leaf]),
-                           new_id, b.rh[leaf])
-        leaf_sum_g = setw(setw(st["leaf_sum_g"], leaf, b.lg[leaf]),
-                          new_id, b.rg[leaf])
+        leaf_count = setw(setw(st["leaf_count"], leaf, c2[0]), new_id, c2[1])
+        leaf_weight = setw(setw(st["leaf_weight"], leaf, h2[0]), new_id, h2[1])
+        leaf_sum_g = setw(setw(st["leaf_sum_g"], leaf, g2[0]), new_id, g2[1])
         leaf_parent = setw(setw(st["leaf_parent"], leaf, j), new_id, j)
         leaf_is_left = setw(setw(st["leaf_is_left"], leaf, True),
                             new_id, False)
@@ -1107,12 +1118,21 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                                              new_out[:, None], NEG_INF))
                     return lo_c, hi_c
 
+                def set_children(arr, left_val, right_val):
+                    # a select over leaf ids, not ``.at[].set``: with the
+                    # indexed update XLA:CPU's optimized program handed the
+                    # next search a stale, looser bound (a leaf pinched to
+                    # [b, b] split with an output under b; not at
+                    # --xla_backend_optimization_level=0), which broke
+                    # monotonicity on every seed of tests/test_constraints.py
+                    new = jnp.where(lid == leaf, left_val,
+                                    jnp.where(lid == new_id, right_val, arr))
+                    return new if unconditional else jnp.where(ok, new, arr)
+
                 al_lo, al_hi = derive(prl, l_rh, leaf)
                 ar_lo, ar_hi = derive(r_rl, prh, new_id)
-                leaf_lo = setw(setw(st["leaf_lo"], leaf, al_lo),
-                               new_id, ar_lo)
-                leaf_hi = setw(setw(st["leaf_hi"], leaf, al_hi),
-                               new_id, ar_hi)
+                leaf_lo = set_children(st["leaf_lo"], al_lo, ar_lo)
+                leaf_hi = set_children(st["leaf_hi"], al_hi, ar_hi)
                 extra_mono["leaf_out"] = new_out
 
             # Propagate the new child outputs to every active leaf that
@@ -1198,10 +1218,6 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # both children's split searches ride ONE vmapped call: the search is
         # dominated by fixed small-op overhead at [F, B] scale, so batching
         # the pair halves the per-split serial op count
-        hist2 = jnp.stack([lhist, rhist])
-        g2 = jnp.stack([b.lg[leaf], b.rg[leaf]])
-        h2 = jnp.stack([b.lh[leaf], b.rh[leaf]])
-        c2 = jnp.stack([b.lc[leaf], b.rc[leaf]])
         # search under the FINAL stored bounds: advanced re-derivation and
         # cross-leaf propagation may have moved them past the inherited
         # pinch (cached gains computed under stale-tighter bounds would
@@ -1215,13 +1231,13 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         if use_cegb:
             s2 = jax.vmap(
                 lambda hc, g_, h_, c_, lo_, hi_, pen_: find(
-                    expand_hist(hc), g_, h_, c_, fmask, 0.0, lo_, hi_,
+                    hc, g_, h_, c_, fmask, 0.0, lo_, hi_,
                     penalty=pen_, rand=rand, mult=mult2)
             )(hist2, g2, h2, c2, lo2, hi2, pen2)
         else:
             s2 = jax.vmap(
                 lambda hc, g_, h_, c_, lo_, hi_: find(
-                    expand_hist(hc), g_, h_, c_, fmask, 0.0, lo_, hi_,
+                    hc, g_, h_, c_, fmask, 0.0, lo_, hi_,
                     rand=rand, mult=mult2)
             )(hist2, g2, h2, c2, lo2, hi2)
         s2 = s2._replace(gain=jnp.where(depth_ok, s2.gain, NEG_INF))
@@ -1332,7 +1348,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 lm, st["leaf_count"][leaf],
                 st["feat_used"] if cegb_coupled is not None else None,
                 st["used_data"] if cegb_lazy is not None else None)
-        s_new = find(expand_hist(st["hist"][leaf]), st["leaf_sum_g"][leaf],
+        s_new = find(st["hist"][leaf], st["leaf_sum_g"][leaf],
                      st["leaf_weight"][leaf], st["leaf_count"][leaf],
                      fmask_j, 0.0,
                      st["leaf_lo"][leaf], st["leaf_hi"][leaf],

@@ -11,9 +11,19 @@ where the one-hot is built per row-chunk and never materialized in HBM
 planned fast path).  An XLA scatter-add variant is kept for CPU tests and as a
 fallback (``method='scatter'``).
 
-Output layout: ``[num_features, max_bin, 3]`` float32 with channels
-(sum_grad, sum_hess, count) — dense and uniform so the whole tree learner is
-one compiled program (features with fewer bins simply leave the tail at zero).
+Output layout: ``[num_features, max_bin, 6]`` float32, the channels
+(sum_grad, sum_hess, count) as a pair (below; ``fold_hist`` gives ``[...,
+3]``) — dense and uniform so the whole tree learner is one compiled program
+(features with fewer bins simply leave the tail at zero).
+
+Every builder adds its row blocks with a compensated float32 sum
+(``two_sum``), so a bin's sum is right to a float32 ulp of *itself* however
+many millions of rows went into it, and every builder returns ``[..., 6]``:
+the float32 sum in channels 0:3 and what it rounds away in 3:6.  The
+growers store that pair and subtract siblings in it (``sub_hist``), so a
+child that is what a 17M-row parent leaves over keeps sums that are right
+relative to its own size; ``fold_hist`` gives the float32 view the split
+search reads.
 """
 from __future__ import annotations
 
@@ -21,6 +31,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from .onehot_variants import accumulate_block, two_sum
 
 # Shared parity bar for every one-hot/Pallas histogram kernel vs the exact
 # scatter-add (or the true-f32 XLA one-hot): the kernels accumulate a bf16
@@ -38,6 +50,115 @@ import jax.numpy as jnp
 # tests/test_onehot_variants.py) — a tolerance re-derived in one place and
 # drifted in another is how the round-4 incident stayed hidden.
 HIST_PARITY_TOL = 5e-4
+
+
+# rows ``_hist_scatter`` sums at once (``_scatter_block``)
+_XLA_BLOCK_ROWS = 512
+
+
+def fold_hist(h: jax.Array) -> jax.Array:
+    """``[..., 6]`` pair -> its ``[..., 3]`` float32 value."""
+    return h[..., :3] + h[..., 3:]
+
+
+def add_hist(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a + b`` of two ``[..., 6]`` pairs, as a normalized pair: the error
+    is relative to the *result's* size times float32's epsilon squared, not
+    to the operands'."""
+    s, e = two_sum(a[..., :3], b[..., :3])
+    hi, lo = two_sum(s, e + (a[..., 3:] + b[..., 3:]))
+    return jnp.concatenate([hi, lo], axis=-1)
+
+
+def sub_hist(parent: jax.Array, child: jax.Array) -> jax.Array:
+    """Sibling histogram by subtraction (reference
+    ``FeatureHistogram::Subtract``, ``feature_histogram.hpp:79``), in pairs."""
+    return add_hist(parent, -child)
+
+
+def psum_hist(h: jax.Array, axis_name: str) -> jax.Array:
+    """Sum of every shard's ``[..., 6]`` pair over ``axis_name``, as a pair:
+    a plain ``psum`` would round each bin to an ulp of the global sum, which
+    is the error the pairs exist to keep out of the store."""
+    parts = jax.lax.all_gather(h, axis_name)             # [shards, ..., 6]
+    return functools.reduce(add_hist, list(parts))
+
+
+def psum_scatter_hist(h: jax.Array, axis_name: str, shards: int) -> jax.Array:
+    """``psum_hist`` of which each of the ``shards`` keeps its own block of
+    the leading axis (``lax.psum_scatter(..., tiled=True)`` for pairs): the
+    shards exchange blocks, then each adds up its own."""
+    parts = jax.lax.all_to_all(
+        h.reshape((shards, h.shape[0] // shards) + h.shape[1:]), axis_name,
+        split_axis=0, concat_axis=0)                     # [shards, block, ...]
+    return functools.reduce(add_hist, list(parts))
+
+
+def hist_totals(h: jax.Array) -> jax.Array:
+    """``[..., 3]`` (sum_grad, sum_hess, count) of the rows a ``[..., C, B,
+    6]`` pair histogram was built from: the sum of its first column's bins.
+    Every row falls in one bin of every column, so any column would do; a
+    leaf's totals taken here are right relative to the leaf's own sums, and
+    its count is exact up to 2**24 rows."""
+    return jnp.sum(fold_hist(h[..., 0, :, :]), axis=-2)
+
+
+def _scatter_block(flat, gh, size):
+    """One row block's histogram by scatter-add, exactly: ``flat`` [R, F] flat
+    bin indices below ``size``, ``gh`` [R, 3] -> the pair ``(sum [size, 3],
+    what it rounds away [size, 3])``.
+
+    A float32 scatter-add rounds every add to an ulp of the running sum, so a
+    row of weight 0.01 that lands on a row of weight 100 loses half its
+    digits.  Here each channel's values are cut into limbs on a
+    power-of-two grid under the block's largest (integers of so few bits
+    that a bin's sum over the block stays under 2**24, which float32 adds
+    exactly, in any order), the limbs are scattered, and their exact sums
+    are put together with ``two_sum``: at least 40 bits under the block's
+    largest value (3 x 14 at 512 rows), so that the same rows give the same
+    float32 sums in whatever blocks they come."""
+    r, f = flat.shape
+    bits = 23 - max(0, (r - 1).bit_length())
+    n_limbs = -(-40 // bits)
+    _, e = jnp.frexp(jnp.max(jnp.abs(gh), axis=0))            # [3]: max < 2**e
+    q = jnp.ldexp(jnp.float32(1.0), jnp.maximum(e, -60) - bits)
+    limbs, scales, rest = [], [], gh
+    for _ in range(n_limbs):
+        limb = jnp.round(rest / q)
+        rest = rest - limb * q                               # exact
+        limbs.append(limb)
+        scales.append(q)
+        q = q * jnp.float32(2.0 ** -(bits + 1))
+    vals = jnp.concatenate(limbs, axis=-1)                    # [R, 3 limbs]
+    vals = jnp.broadcast_to(vals[:, None, :], (r, f, 3 * n_limbs)).reshape(
+        r * f, 3 * n_limbs)
+    sums = jnp.zeros((size, 3 * n_limbs), jnp.float32).at[
+        flat.reshape(-1)].add(vals)
+    x, xe = two_sum(sums[:, :3] * scales[0], sums[:, 3:6] * scales[1])
+    for i in range(2, n_limbs):
+        xe = xe + sums[:, 3 * i:3 * i + 3] * scales[i]
+    return x, xe
+
+
+def _as_pair(s, c) -> jax.Array:
+    """A running sum and its compensation -> the normalized ``[..., 6]``."""
+    hi, lo = two_sum(s, c)
+    return jnp.concatenate([hi, lo], axis=-1)
+
+
+def _compensated_sum(block_fn, xs, shape) -> jax.Array:
+    """Sum over the leading axis of the pytree ``xs`` of ``block_fn(x)`` (a
+    sum ``shape = [..., 3]`` and what it rounds away), as a ``[..., 6]``
+    pair."""
+    def body(carry, x):
+        s, c = carry
+        x, xe = block_fn(x)
+        s, e = two_sum(s, x)
+        return (s, c + (e + xe)), None
+
+    zero = jnp.zeros(shape, jnp.float32)
+    (s, c), _ = jax.lax.scan(body, (zero, zero), xs)
+    return _as_pair(s, c)
 
 
 def _pallas_interpret_default() -> bool:
@@ -64,7 +185,10 @@ def build_histogram(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
     variant: one-hot build strategy for the pallas kernels (a registry name
     from ops/onehot_variants.py — lane packing, staged compare, int8 MXU,
-    ...); ignored by the XLA fallbacks."""
+    ...); ignored by the XLA fallbacks.
+
+    Returns ``[F, B, 6]``, the sums and what float32 rounds off them (module
+    docstring)."""
     if method == "pallas":
         return _hist_pallas(bins, grad, hess, mask, max_bin, f_limit=f_limit,
                             variant=variant)
@@ -84,7 +208,7 @@ def _build_histogram_xla(bins, grad, hess, mask, max_bin, *,
       max_bin: static histogram width ``B``.
       method: 'onehot' (MXU matmul) or 'scatter' (XLA scatter-add).
 
-    Returns: ``[F, B, 3]`` float32.
+    Returns: ``[F, B, 6]`` float32 (module docstring).
     """
     if method == "scatter":
         return _hist_scatter(bins, grad, hess, mask, max_bin)
@@ -98,10 +222,16 @@ def _hist_scatter(bins, grad, hess, mask, max_bin):
     # inside their own column's space; the one-hot paths drop them by compare
     clipped = jnp.minimum(bins.astype(jnp.int32), max_bin - 1)
     flat = clipped + max_bin * jnp.arange(f, dtype=jnp.int32)[None, :]
-    out = jnp.zeros((f * max_bin, 3), dtype=jnp.float32)
-    vals = jnp.broadcast_to(gh[:, None, :], (n, f, 3)).reshape(n * f, 3)
-    out = out.at[flat.reshape(-1)].add(vals)
-    return out.reshape(f, max_bin, 3)
+    br = max(1, min(_XLA_BLOCK_ROWS, n))
+    pad = (-n) % br
+    if pad:     # padded rows weigh nothing
+        flat = jnp.pad(flat, ((0, pad), (0, 0)))
+        gh = jnp.pad(gh, ((0, pad), (0, 0)))
+
+    out = _compensated_sum(
+        lambda x: _scatter_block(*x, f * max_bin),
+        (flat.reshape(-1, br, f), gh.reshape(-1, br, 3)), (f * max_bin, 3))
+    return out.reshape(f, max_bin, 6)
 
 
 def _hist_onehot(bins, grad, hess, mask, max_bin, chunk_rows):
@@ -127,24 +257,19 @@ def _hist_onehot(bins, grad, hess, mask, max_bin, chunk_rows):
     bins_c = bins.reshape(n_chunks, chunk, f)
     gh_c = gh.reshape(3, n_chunks, chunk).transpose(1, 0, 2)        # [nc, 3, chunk]
 
-    def body(acc, xs):
+    def block(xs):
         b, g = xs                                   # [chunk, F], [3, chunk]
         onehot = (b.astype(jnp.int32)[:, :, None] ==
                   jnp.arange(max_bin, dtype=jnp.int32)[None, None, :])
         onehot = onehot.astype(jnp.float32).reshape(chunk, f * max_bin)
-        h = jax.lax.dot_general(
+        return jax.lax.dot_general(
             g, onehot,
             dimension_numbers=(((1,), (0,)), ((), ())),
             precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)     # [3, F*B]
-        return acc + h, None
+            preferred_element_type=jnp.float32).T, 0.0      # [F*B, 3]
 
-    init = jnp.zeros((3, f * max_bin), dtype=jnp.float32)
-    if n_chunks == 1:
-        hist, _ = body(init, (bins_c[0], gh_c[0]))
-    else:
-        hist, _ = jax.lax.scan(body, init, (bins_c, gh_c))
-    return hist.reshape(3, f, max_bin).transpose(1, 2, 0)
+    hist = _compensated_sum(block, (bins_c, gh_c), (f * max_bin, 3))
+    return hist.reshape(f, max_bin, 6)
 
 
 def _split_bf16_pair(gh: jax.Array) -> jax.Array:
@@ -183,8 +308,8 @@ def build_histogram_leaves(comb: jax.Array, grad: jax.Array, hess: jax.Array,
     ``comb`` is ``[C, NC]`` gathered rows laid out as consecutive
     ``block_rows``-sized blocks, each block belonging to ONE leaf slot
     (``block_leaf[C // block_rows]`` i32, sorted ascending); padded rows
-    carry ``mask == 0``.  Returns ``[num_slots, F, B, 3]`` where
-    ``F = f_limit or NC`` on every path (both the Pallas kernel and the XLA
+    carry ``mask == 0``.  Returns ``[num_slots, F, B, 6]`` (pairs, as
+    ``build_histogram``) where ``F = f_limit or NC`` on every path (both the Pallas kernel and the XLA
     fallback slice the trailing packed-gradient columns off before any
     histogramming, so neither pays for columns the caller discards).
 
@@ -205,21 +330,30 @@ def build_histogram_leaves(comb: jax.Array, grad: jax.Array, hess: jax.Array,
         return _hist_leaves_pallas(comb, grad, hess, mask, block_leaf,
                                    num_slots, max_bin, block_rows, f,
                                    variant=variant)
-    # XLA fallback: one scatter-add with the leaf slot folded into the flat
-    # bin index (fast on CPU, correct everywhere).  The packed-gradient tail
-    # columns are sliced off BEFORE the flat index is built: scattering them
-    # too made the CPU test path pay num_slots * gh_cols * max_bin extra
-    # scatter targets for garbage the caller discarded anyway.
+    # XLA fallback: a scatter-add a row block (fast on CPU, exact:
+    # ``_scatter_block``), each block's histogram added to its leaf slot's
+    # running pair.  The packed-gradient tail columns are sliced off BEFORE the flat
+    # index is built: scattering them too made the CPU test path pay
+    # gh_cols * max_bin extra scatter targets for garbage the caller
+    # discarded anyway.
     comb_f = comb[:, :f] if f < nc else comb
-    row_leaf = jnp.repeat(block_leaf, block_rows, total_repeat_length=n)
     gh = jnp.stack([grad * mask, hess * mask, mask], axis=-1)       # [C, 3]
     clipped = jnp.minimum(comb_f.astype(jnp.int32), max_bin - 1)
-    flat = (row_leaf[:, None] * (f * max_bin)
-            + jnp.arange(f, dtype=jnp.int32)[None, :] * max_bin + clipped)
-    out = jnp.zeros((num_slots * f * max_bin, 3), jnp.float32)
-    vals = jnp.broadcast_to(gh[:, None, :], (n, f, 3)).reshape(n * f, 3)
-    out = out.at[flat.reshape(-1)].add(vals)
-    return out.reshape(num_slots, f, max_bin, 3)
+    flat = jnp.arange(f, dtype=jnp.int32)[None, :] * max_bin + clipped
+    br = block_rows
+
+    def body(carry, x):
+        s, c = carry                                # [num_slots, F*B, 3]
+        fl, g, slot = x
+        h, he = _scatter_block(fl, g, f * max_bin)
+        new, e = two_sum(s[slot], h)
+        return (s.at[slot].set(new), c.at[slot].add(e + he)), None
+
+    zero = jnp.zeros((num_slots, f * max_bin, 3), jnp.float32)
+    (s, c), _ = jax.lax.scan(
+        body, (zero, zero),
+        (flat.reshape(-1, br, f), gh.reshape(-1, br, 3), block_leaf))
+    return _as_pair(s, c).reshape(num_slots, f, max_bin, 6)
 
 
 def _hist_leaves_pallas(comb, grad, hess, mask, block_leaf, num_slots,
@@ -258,33 +392,42 @@ def _hist_leaves_pallas(comb, grad, hess, mask, block_leaf, num_slots,
     # output block: it stays VMEM-resident across the entire grid (k=16
     # slots x 28 feats x 256 bins f32 = 2.8MB) and flushes to HBM once.
     # This zeroes every slot up front — a slot with no row blocks is
-    # well-defined zeros, not stale HBM.  The per-block accumulate routes
-    # through a SLOT ONE-HOT broadcast (sel * acc) rather than any dynamic
-    # index into out_ref: both dynamic-index formulations miscompiled
-    # data-dependently on real v5e hardware (a [1,6,f*Bp] output block
-    # keyed on bl[i], and an out_ref[pl.ds(sl,1)] += store whose 6-sublane
-    # slot slabs are not (8,128)-tile aligned, each dropped the lo-half
-    # bf16-residual contributions for some block_leaf patterns: relerr
-    # ~1.8e-2 vs the ~3e-5 this split-precision design gives — caught twice
-    # by scripts/bench_dual.py's hardware parity gate, round 4).  The
-    # select costs num_slots*6*f*Bp VPU mult-adds per block and benched
-    # FASTER than the aligned dynamic store on v5e.
-    def kernel(bl_ref, bins_ref, gh_ref, out_ref):
+    # well-defined zeros, not stale HBM.  The per-block accumulate never
+    # indexes out_ref dynamically: both dynamic-index formulations
+    # miscompiled data-dependently on real v5e hardware (a [1,6,f*Bp]
+    # output block keyed on bl[i], and an out_ref[pl.ds(sl,1)] += store
+    # whose 6-sublane slot slabs are not (8,128)-tile aligned, each dropped
+    # the lo-half bf16-residual contributions for some block_leaf patterns:
+    # relerr ~1.8e-2 vs the ~3e-5 this split-precision design gives —
+    # caught twice by scripts/bench_dual.py's hardware parity gate, round
+    # 4).  A block's slot is copied to a scratch and back under scalar
+    # predicates, one statically indexed copy a slot, and the compensated
+    # add is written once: sixteen copies of it made the Mosaic program (and
+    # the host's time to load it) several times the size.  A block touches
+    # one slot's rows, so a bad block's NaNs stay in its own slot.
+    def kernel(bl_ref, bins_ref, gh_ref, out_ref, cur_ref):
         i = pl.program_id(0)
 
         @pl.when(i == 0)
         def _init():
             out_ref[:] = jnp.zeros_like(out_ref)
 
+        slot = bl_ref[i]
+        for sl in range(num_slots):
+            @pl.when(slot == sl)
+            def _load(sl=sl):
+                cur_ref[:] = out_ref[sl]
+
         # the one-hot build + dot live in the variant registry
         # (ops/onehot_variants.py) — ONE set of kernel bodies shared with
         # _hist_pallas and the shootout
-        acc = spec.contrib(bins_ref[:], gh_ref[:],
-                           fc=f_pad, B=B, Bp=Bp, BR=BR)           # [6, lanes]
-        slot_id = jax.lax.broadcasted_iota(jnp.int32, (num_slots, 1, 1), 0)
-        # where, not sel*acc: 0.0 * inf would leak one bad block's NaNs
-        # into every slot's histogram instead of only its own
-        out_ref[:] += jnp.where(slot_id == bl_ref[i], acc[None], 0.0)
+        cur_ref[:] = accumulate_block(
+            cur_ref[:], spec.contrib(bins_ref[:], gh_ref[:],
+                                     fc=f_pad, B=B, Bp=Bp, BR=BR))  # [6, lanes]
+        for sl in range(num_slots):
+            @pl.when(slot == sl)
+            def _store(sl=sl):
+                out_ref[sl] = cur_ref[:]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -293,6 +436,7 @@ def _hist_leaves_pallas(comb, grad, hess, mask, block_leaf, num_slots,
                   pl.BlockSpec((rows.shape[0], BR), lambda i, bl: (0, i))],
         out_specs=pl.BlockSpec((num_slots, 6, lanes),
                                lambda i, bl: (0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((6, lanes), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
@@ -300,7 +444,7 @@ def _hist_leaves_pallas(comb, grad, hess, mask, block_leaf, num_slots,
         interpret=interpret,
     )(block_leaf.astype(jnp.int32), comb_t, rows)
 
-    return finish_hist(out, f, B, Bp, spec)                   # [k, f, B, 3]
+    return finish_hist(out, f, B, Bp, spec)                   # [k, f, B, 6]
 
 
 def unrolled_rank(sorted_vals: jax.Array, targets: jax.Array,
@@ -451,8 +595,9 @@ def _hist_pallas(bins, grad, hess, mask, max_bin, block_rows=None,
             # columns (packed gradient bytes) are dropped by the sublane
             # slice after the transpose.
             b = bins_ref[:].T[:f_pad]                         # [f_pad, BR]
-            out_ref[:] += spec.contrib(b, gh_ref[:],
-                                       fc=f_pad, B=B, Bp=Bp, BR=BR)
+            out_ref[:] = accumulate_block(
+                out_ref[:], spec.contrib(b, gh_ref[:],
+                                         fc=f_pad, B=B, Bp=Bp, BR=BR))
 
         out = pl.pallas_call(
             kernel_rm,
@@ -489,8 +634,9 @@ def _hist_pallas(bins, grad, hess, mask, max_bin, block_rows=None,
             def _init():
                 out_ref[:] = jnp.zeros_like(out_ref)
 
-            out_ref[:] += spec.contrib(bins_ref[:], gh_ref[:],
-                                       fc=FC, B=B, Bp=Bp, BR=BR)
+            out_ref[:] = accumulate_block(
+                out_ref[:], spec.contrib(bins_ref[:], gh_ref[:],
+                                         fc=FC, B=B, Bp=Bp, BR=BR))
 
         out = pl.pallas_call(
             kernel_fm,
@@ -534,27 +680,18 @@ def gather_rows(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             jnp.where(filled, jnp.take(mask, row_ids), 0.0))
 
 
-def subtract_histogram(parent: jax.Array, child: jax.Array) -> jax.Array:
-    """Sibling histogram via subtraction (reference ``FeatureHistogram::Subtract``,
-    ``feature_histogram.hpp:79``)."""
-    return parent - child
-
-
 def accumulate_histogram(acc: jax.Array, bins: jax.Array, grad: jax.Array,
                          hess: jax.Array, mask: jax.Array, max_bin: int, *,
                          method: str = "onehot", chunk_rows: int = 65536,
                          variant: str = "base") -> jax.Array:
-    """Block-accumulating entry point: ``acc + histogram(block)``.
+    """Block-accumulating entry point: ``acc + histogram(block)``, in pairs.
 
     The out-of-core trainer (lightgbm_tpu/stream, docs/STREAMING.md) folds
-    one streamed row block into a running ``[F, B, 3]`` accumulator with
-    this op — the same shape/kernels as ``build_histogram``, so the
-    accumulated result feeds ``split.find_best_split`` /
-    ``subtract_histogram`` unchanged, and the same structure the quantized
-    histogram collectives of ROADMAP item 4 will reduce over the wire.
-    Accumulation order is block-major (f32 adds reassociate vs the
-    single-pass kernels — the sharded-learner noise class, ~2^-23 relative
-    per add)."""
-    return acc + build_histogram(bins, grad, hess, mask, max_bin,
-                                 method=method, chunk_rows=chunk_rows,
-                                 variant=variant)
+    one streamed row block into a running ``[F, B, 6]`` pair with this op —
+    the same kernels as ``build_histogram``, so the accumulated
+    result is subtracted (``sub_hist``) and folded for
+    ``split.find_best_split`` like an in-memory grower's, and the streamed
+    blocks add up as exactly as a kernel's own row blocks do."""
+    return add_hist(acc, build_histogram(bins, grad, hess, mask, max_bin,
+                                         method=method, chunk_rows=chunk_rows,
+                                         variant=variant))

@@ -136,6 +136,35 @@ def _dot6(gh, onehot):
         preferred_element_type=jnp.float32)
 
 
+def two_sum(a, b):
+    """Error-free addition (Knuth): ``s = fl(a + b)`` and the ``e`` with
+    ``a + b == s + e`` exactly, whatever the magnitudes.  Six flops, no
+    branch; neither XLA nor Mosaic reassociates float adds, so it survives
+    compilation."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def accumulate_block(acc, contrib):
+    """Add one row block's ``contrib`` ([6, lanes]: the hi triple, the lo
+    triple) to the running ``acc`` ([6, lanes]: the sums, their
+    compensation) without losing what a float32 add rounds away.
+
+    A pass over 17M rows adds ~17,000 block sums into each accumulator; a
+    plain ``+=`` rounds each add to an ulp of the *running* sum, and that
+    error (1e-5 of a root bin) is what histogram subtraction later hands
+    whole to a 20-row leaf.  Here both adds (hi + lo of the block, then the
+    block onto the sums) are ``two_sum`` and what they lose goes into the
+    compensation rows, which hold nothing else: their own rounding is
+    relative to *their* size, a few ulps of the sums.  ``finish_hist``
+    returns the pair.  Shared by every kernel shell, like ``contrib``."""
+    import jax.numpy as jnp
+    x, xe = two_sum(contrib[:3], contrib[3:])
+    s, e = two_sum(acc[:3], x)
+    return jnp.concatenate([s, acc[3:] + (e + xe)], axis=0)
+
+
 def feat_geometry(spec: "VariantSpec", f: int, B: int, Bp: int):
     """(f_pad, lanes): the feature count padded to a lane-group multiple
     and the resulting output lane count (= MXU N-dim).  THE forward lane
@@ -387,20 +416,23 @@ def resolve(name: str, max_bin: int):
 
 
 def finish_hist(out, f, B, Bp, spec: VariantSpec):
-    """[..., 6, n_lanes] kernel output -> [..., f, B, 3] histograms: sum the
-    (hi, lo) triples and undo the lane layout (plain Bp-wide slots, or the
-    packed ``group*128 + f_local*B + bin`` layout).  Shared by every kernel
-    shell so the lane mapping exists exactly once."""
+    """[..., 6, n_lanes] kernel output -> [..., f, B, 6] histograms:
+    normalize the sums and their compensation (``accumulate_block``) into
+    the float32 sum (channels 0:3) and what it rounds away (3:6;
+    ``histogram.fold_hist`` adds them) and undo the lane layout (plain
+    Bp-wide slots, or the packed ``group*128 + f_local*B + bin`` layout).
+    Shared by every kernel shell so the lane mapping exists exactly once."""
+    import jax.numpy as jnp
     gl = spec.group_lanes(B, Bp)
     gf = spec.group_feats(B, Bp)
     lead = out.shape[:-2]
     ng = out.shape[-1] // gl
     o = out.reshape(lead + (2, 3, ng, gl))
-    hist = o[..., 0, :, :, :] + o[..., 1, :, :, :]       # [..., 3, ng, gl]
-    hist = hist[..., :gf * B].reshape(lead + (3, ng * gf, B))
+    hist, lo = two_sum(o[..., 0, :, :, :], o[..., 1, :, :, :])  # [..., 3, ng, gl]
+    hist = jnp.concatenate([hist, lo], axis=-3)          # [..., 6, ng, gl]
+    hist = hist[..., :gf * B].reshape(lead + (6, ng * gf, B))
     hist = hist[..., :f, :]
-    # [..., 3, f, B] -> [..., f, B, 3]
-    import jax.numpy as jnp
+    # [..., C, f, B] -> [..., f, B, C]
     return jnp.moveaxis(hist, -3, -1)
 
 
@@ -414,8 +446,8 @@ def make_bench_kernel(variant: str, f: int, max_bin: int, BR: int, *,
     once outside the timed loop, then ``run(bins_t [f, N] u8, rows)`` is the
     timed kernel — feature-major single-block, bins pre-transposed OUTSIDE
     (the production layout; the in-kernel transpose benched 35x slower).
-    Returns finished ``[f, B, 3]`` histograms so parity checks read off the
-    same surface the production kernels expose."""
+    Returns finished ``[f, B, 6]`` pair histograms so parity checks read off
+    the same surface the production kernels expose."""
     import jax
     from jax.experimental import pallas as pl
 
@@ -431,8 +463,9 @@ def make_bench_kernel(variant: str, f: int, max_bin: int, BR: int, *,
         def _init():
             out_ref[:] = jnp.zeros_like(out_ref)
 
-        out_ref[:] += spec.contrib(bins_ref[:], gh_ref[:],
-                                   fc=fc, B=B, Bp=Bp, BR=BR)
+        out_ref[:] = accumulate_block(
+            out_ref[:], spec.contrib(bins_ref[:], gh_ref[:],
+                                     fc=fc, B=B, Bp=Bp, BR=BR))
 
     def run(bins_t, rows):
         import jax.numpy as jnp
@@ -494,10 +527,10 @@ def _time_auto_candidate(variant, bins, g, h, m, max_bin, ref,
 
     import jax
     import jax.numpy as jnp
-    from .histogram import _hist_pallas
+    from .histogram import _hist_pallas, fold_hist
 
-    jfn = jax.jit(lambda b_, g_: _hist_pallas(
-        b_, g_, h, m, max_bin, variant=variant))
+    jfn = jax.jit(lambda b_, g_: fold_hist(_hist_pallas(
+        b_, g_, h, m, max_bin, variant=variant)))
     out = jfn(bins, g).block_until_ready()         # compile + warm
     err = float(jnp.max(jnp.abs(out - ref) / (jnp.abs(ref) + 1.0)))
     t0 = time.perf_counter()
@@ -540,12 +573,12 @@ def _run_auto_bench(max_bin: int, num_features: int) -> str:
     raises, because the only thing left to return would be a kernel that
     just failed on this device."""
     from ..utils.log import Log
-    from .histogram import HIST_PARITY_TOL, _hist_onehot
+    from .histogram import HIST_PARITY_TOL, _hist_onehot, fold_hist
     import jax
 
     bins, g, h, m = _auto_bench_data(max_bin, max(1, num_features))
-    ref = jax.jit(lambda b_, g_: _hist_onehot(b_, g_, h, m, max_bin,
-                                              65536))(bins, g)
+    ref = jax.jit(lambda b_, g_: fold_hist(_hist_onehot(
+        b_, g_, h, m, max_bin, 65536)))(bins, g)
     ref = ref.block_until_ready()
     best, best_t = None, float("inf")
     failures = []

@@ -19,6 +19,15 @@ Semantics preserved from the reference:
   one-hot branch; the sorted many-category scan is in the grower roadmap);
 - monotone constraint (basic): candidate rejected when child outputs violate
   the feature's direction, with per-leaf output bounds applied.
+
+Which side is summed.  The reference sweeps one side in float64 and takes the
+other as ``total - swept``.  In float32 that hands what ``total`` is off by
+(an ulp of a 400,000 hessian sum is 0.03) whole to the other side, however
+small it is.  Here every candidate's two sides are both summed over their own
+bins (a forward and a reverse cumulative sum), the side with fewer rows keeps
+its own sum and the larger one is ``total - smaller`` (``derive_larger``):
+each side's error is relative to its own size.  ``total`` must be the sum of
+the rows the histogram was built from (``histogram.hist_totals``).
 """
 from __future__ import annotations
 
@@ -102,13 +111,33 @@ def leaf_gain(sum_g, sum_h, p: SplitParams, parent_output=0.0, count=None,
     return g1 * g1 / (sum_h + p.lambda_l2 + 1e-35)
 
 
+def _sum_after(x):
+    """``[F, B, 3]`` -> the sum over the bins after each bin (axis 1)."""
+    at_or_after = jnp.flip(jnp.cumsum(jnp.flip(x, 1), axis=1), 1)
+    return jnp.concatenate([at_or_after[:, 1:], jnp.zeros_like(x[:, :1])],
+                           axis=1)
+
+
+def derive_larger(left, right, total):
+    """Of a candidate's two directly summed sides ``[..., 3]``, keep the one
+    with fewer rows and make the other ``total - it`` (module docstring).
+    The sides then add up to ``total`` to an ulp of it."""
+    left_small = (left[..., 2] <= right[..., 2])[..., None]
+    return (jnp.where(left_small, left, total - right),
+            jnp.where(left_small, total - left, right))
+
+
 def _split_gain_matrix(hist, num_bins, nan_bins, is_categorical, monotone,
                        total, p: SplitParams, feature_mask,
                        parent_output, output_lo, output_hi,
                        gain_penalty=None, rand_threshold=None, contri=None):
     """Candidate gains over all (feature, threshold) pairs.
 
-    Returns (gain_fb [F, B], use_left [F, B], cum [F, B, 3], miss [F, 3]).
+    Returns (gain_fb [F, B], use_left [F, B], sides): ``sides`` = (cum,
+    above, miss, others), the direct sums a chosen candidate's child sums are
+    rebuilt from (``find_best_split``): bins ``<= t`` and ``> t`` without the
+    missing bin [F, B, 3], the missing bin [F, 3], every bin but ``t``
+    [F, B, 3].
     """
     f, b, _ = hist.shape
     bin_ids = jnp.arange(b, dtype=jnp.int32)[None, :]                  # [1, B]
@@ -125,6 +154,7 @@ def _split_gain_matrix(hist, num_bins, nan_bins, is_categorical, monotone,
     swept = jnp.where(miss_sel[:, :, None], 0.0, hist)                 # [F, B, 3]
 
     cum = jnp.cumsum(swept, axis=1)                                    # [F, B, 3]
+    above = _sum_after(swept)                                          # bins > t
 
     # threshold t means: bins <= t go left (t in [0, num_bin-2]); when the
     # missing bin is the TRAILING bin the last real threshold drops with it,
@@ -134,7 +164,8 @@ def _split_gain_matrix(hist, num_bins, nan_bins, is_categorical, monotone,
 
     def eval_direction(missing_left):
         left = cum + jnp.where(missing_left, miss[:, None, :], 0.0)    # [F, B, 3]
-        right = total[None, None, :] - left
+        right = above + jnp.where(missing_left, 0.0, miss[:, None, :])
+        left, right = derive_larger(left, right, total)
         return _gain_at(left, right, total, monotone, p,
                         parent_output, output_lo, output_hi, valid_t)
 
@@ -150,8 +181,9 @@ def _split_gain_matrix(hist, num_bins, nan_bins, is_categorical, monotone,
     # it cannot be expressed in a category-VALUE bitset, so it is never a
     # left-set member — those rows always go right, like unseen categories
     # at predict time
-    cat_left = hist                                                     # [F, B, 3]
-    cat_right = total[None, None, :] - cat_left
+    others = (cum - swept) + above + jnp.where(miss_sel[:, :, None], 0.0,
+                                               miss[:, None, :])
+    cat_left, cat_right = derive_larger(hist, others, total)          # [F, B, 3]
     cat_valid = (bin_ids >= 1) & (bin_ids < num_bins[:, None]) & \
         (num_bins[:, None] <= p.max_cat_to_onehot)
     cat_gain, cat_out = _gain_at(cat_left, cat_right, total, monotone, p,
@@ -182,7 +214,7 @@ def _split_gain_matrix(hist, num_bins, nan_bins, is_categorical, monotone,
         keep = (bin_ids == rand_threshold[:, None]) | is_cat
         gain_fb = jnp.where(keep, gain_fb, NEG_INF)
     gain_fb = jnp.where(feature_mask[:, None] > 0, gain_fb, NEG_INF)
-    return gain_fb, use_left, cum, miss
+    return gain_fb, use_left, (cum, above, miss, others)
 
 
 def cat_words(b: int) -> int:
@@ -222,14 +254,16 @@ def _sorted_cat_best(hist, num_bins, is_categorical, monotone, total,
     deviation: the reference estimates bin counts from hessians
     (``cnt_factor``); the count channel here is exact.
 
-    Returns ``(gain [F], bits [F, CW] i32, left_sums [F, 3])`` with
-    ``NEG_INF`` gain for features where the sorted scan does not apply.
+    Returns ``(gain [F], bits [F, CW] i32, left_sums [F, 3], right_sums
+    [F, 3])``, both sides summed over their own bins, with ``NEG_INF`` gain
+    for features where the sorted scan does not apply.
     """
     f, b, _ = hist.shape
     cw = cat_words(b)
     if f == 0:
         z = jnp.zeros((0,), jnp.float32)
-        return z, jnp.zeros((0, cw), jnp.int32), jnp.zeros((0, 3), jnp.float32)
+        z3 = jnp.zeros((0, 3), jnp.float32)
+        return z, jnp.zeros((0, cw), jnp.int32), z3, z3
     maxT = max(1, min(p.max_cat_threshold, b))
     g, h, c = hist[..., 0], hist[..., 1], hist[..., 2]
     bin_ids = jnp.arange(b, dtype=jnp.int32)[None, :]
@@ -242,22 +276,26 @@ def _sorted_cat_best(hist, num_bins, is_categorical, monotone, total,
     used_bin = jnp.sum(elig, axis=1)                                # [F]
     max_num_cat = jnp.minimum(p.max_cat_threshold, (used_bin + 1) // 2)
     score = jnp.where(elig, g / (h + p.cat_smooth), jnp.inf)
+    left_out = jnp.sum(jnp.where(elig[:, :, None], 0.0, hist), axis=1)  # [F, 3]
     p_eff = p._replace(lambda_l2=p.lambda_l2 + p.cat_l2)
     pen = gain_penalty if gain_penalty is not None else jnp.zeros(f, jnp.float32)
     mono = monotone
 
     def scan_dir(order_score):
         idx = jnp.argsort(order_score, axis=1, stable=True)         # [F, B]
-        tk = lambda a: jnp.take_along_axis(jnp.where(elig, a, 0.0), idx, axis=1)
-        cum_g = jnp.cumsum(tk(g), axis=1)[:, :maxT]
-        cum_h = jnp.cumsum(tk(h), axis=1)[:, :maxT] + 1e-15         # kEpsilon
-        cum_c = jnp.cumsum(tk(c), axis=1)[:, :maxT]
-        sc_step = tk(c)[:, :maxT]
+        srt = jnp.take_along_axis(jnp.where(elig[:, :, None], hist, 0.0),
+                                  idx[:, :, None], axis=1)          # [F, B, 3]
+        cum = jnp.cumsum(srt, axis=1)[:, :maxT]
+        cum = cum.at[:, :, 1].add(1e-15)                            # kEpsilon
+        # the right side: the sorted bins after i, and every bin left out
+        after = _sum_after(srt)[:, :maxT] + left_out[:, None, :]
+        cum, after = derive_larger(cum, after, total)
+        sc_step = srt[:, :maxT, 2]
 
         def body(i, carry):
             cnt_grp, best_gain, best_i = carry
-            lg, lh, lc = cum_g[:, i], cum_h[:, i], cum_c[:, i]
-            rg, rh, rc = total[0] - lg, total[1] - lh, total[2] - lc
+            lg, lh, lc = cum[:, i, 0], cum[:, i, 1], cum[:, i, 2]
+            rg, rh, rc = after[:, i, 0], after[:, i, 1], after[:, i, 2]
             cnt_grp = cnt_grp + sc_step[:, i]
             in_range = i < jnp.minimum(used_bin, max_num_cat)
             gate1 = (lc >= p.min_data_in_leaf) & (lh >= p.min_sum_hessian_in_leaf)
@@ -305,7 +343,8 @@ def _sorted_cat_best(hist, num_bins, is_categorical, monotone, total,
         jnp.arange(f, dtype=jnp.int32)[:, None], idx].set(memb_sorted)
     bits = pack_bin_bitset(memb_bins)                               # [F, CW]
     left = jnp.sum(jnp.where(memb_bins[:, :, None], hist, 0.0), axis=1)
-    return best_gain, bits, left
+    right = jnp.sum(jnp.where(memb_bins[:, :, None], 0.0, hist), axis=1)
+    return best_gain, bits, left, right
 
 
 def per_feature_gains(hist, num_bins, nan_bins, is_categorical, monotone,
@@ -320,12 +359,12 @@ def per_feature_gains(hist, num_bins, nan_bins, is_categorical, monotone,
     SplitInfo gains that already include FeatureMetainfo::penalty), else a
     muted feature could crowd the elected set."""
     total = jnp.stack([sum_g, sum_h, count]).astype(jnp.float32)
-    gain_fb, _, _, _ = _split_gain_matrix(
+    gain_fb, _, _ = _split_gain_matrix(
         hist, num_bins, nan_bins, is_categorical, monotone, total, p,
         feature_mask, parent_output, output_lo, output_hi, contri=contri)
     best = jnp.max(gain_fb, axis=1)
     if sorted_cat:
-        gain_sorted, _, _ = _sorted_cat_best(
+        gain_sorted, _, _, _ = _sorted_cat_best(
             hist, num_bins, is_categorical, monotone, total, p, feature_mask,
             parent_output, output_lo, output_hi, contri=contri)
         best = jnp.maximum(best, gain_sorted)
@@ -348,17 +387,20 @@ def find_best_split(hist: jax.Array, num_bins: jax.Array, default_bins: jax.Arra
     """Find the best split of a leaf given its histogram.
 
     Args:
-      hist: ``[F, B, 3]`` (grad, hess, count) histogram of the leaf.
+      hist: ``[F, B, 3]`` (grad, hess, count) histogram of the leaf (a
+        grower's pair store folded, ``histogram.fold_hist``).
       num_bins/default_bins/nan_bins/is_categorical/monotone: ``[F]`` feature
         metadata from ``Dataset.device_data``.
-      sum_g/sum_h/count: leaf totals (scalars).
+      sum_g/sum_h/count: leaf totals (scalars): the sums of the rows ``hist``
+        was built from (``histogram.hist_totals``), so that a candidate's
+        larger side, ``total - smaller``, is right relative to its own size.
       feature_mask: ``[F]`` f32/bool — column sampling / interaction constraints.
       output_lo/output_hi: monotone bounds for this leaf's subtree.
     """
     f, b, _ = hist.shape
     cw = cat_words(b)
     total = jnp.stack([sum_g, sum_h, count]).astype(jnp.float32)       # [3]
-    gain_fb, use_left, cum, miss = _split_gain_matrix(
+    gain_fb, use_left, (cum, above, miss, others) = _split_gain_matrix(
         hist, num_bins, nan_bins, is_categorical, monotone, total, p,
         feature_mask, parent_output, output_lo, output_hi, gain_penalty,
         rand_threshold, contri=contri)
@@ -370,7 +412,7 @@ def find_best_split(hist: jax.Array, num_bins: jax.Array, default_bins: jax.Arra
     # sharding propagation (TileAssignment::Reshape 0-element CHECK,
     # jaxlib 0.4.37) besides being dead weight
     if sorted_cat:
-        gain_sorted, bits_sorted, left_sorted = _sorted_cat_best(
+        gain_sorted, bits_sorted, left_sorted, right_sorted = _sorted_cat_best(
             hist, num_bins, is_categorical, monotone, total, p, feature_mask,
             parent_output, output_lo, output_hi, gain_penalty,
             contri=contri)
@@ -419,15 +461,19 @@ def find_best_split(hist: jax.Array, num_bins: jax.Array, default_bins: jax.Arra
     if sorted_cat:
         cat_bits = jnp.where(use_sorted, bits_sorted[sorted_f], cat_bits)
 
-    # recompute chosen split's child sums
+    # the chosen split's child sums, each side from its own bins
     def pick(arr):
         return arr[best_f, best_t]
-    left_num = pick(cum) + jnp.where(bf_missing_left, miss[best_f], 0.0)
-    left_cat = pick(hist)
-    left = jnp.where(bf_cat, left_cat, left_num)
+    left = jnp.where(
+        bf_cat, pick(hist),
+        pick(cum) + jnp.where(bf_missing_left, miss[best_f], 0.0))
+    right = jnp.where(
+        bf_cat, pick(others),
+        pick(above) + jnp.where(bf_missing_left, 0.0, miss[best_f]))
     if sorted_cat:
         left = jnp.where(use_sorted, left_sorted[sorted_f], left)
-    right = total - left
+        right = jnp.where(use_sorted, right_sorted[sorted_f], right)
+    left, right = derive_larger(left, right, total)
 
     # categorical outputs use the categorical L2 (reference computes
     # CalculateSplittedLeafOutput with l2 += cat_l2 for cat splits)

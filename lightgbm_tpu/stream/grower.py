@@ -11,9 +11,12 @@ tests/test_stream.py.  What changes is WHERE the data lives:
 - bins stay in host RAM (``HostBinMatrix``); each histogram pass streams
   row blocks through the ``RowBlockPipeline`` (H2D of block k+1 behind the
   pass on block k);
-- per-leaf histograms accumulate block-wise into the same ``[F, B, 3]``
-  layout ``ops/histogram.build_histogram`` produces, so the split search
-  (``ops/split.find_best_split``) is byte-for-byte the shared one;
+- per-leaf histograms accumulate block-wise into the same ``[F, B, 6]``
+  pairs ``ops/histogram.build_histogram`` produces (a float32
+  sum and what it rounds away), siblings are subtracted in pairs and a
+  leaf's totals are its own histogram's (``hist_totals``), as in the
+  in-memory growers, so the split search (``ops/split.find_best_split``)
+  is byte-for-byte the shared one and sees the same contract;
 - leaf membership is a per-shard host ``leaf_vec`` int32 vector updated
   incrementally after each split (no device-resident permutation), and a
   per-(block, leaf) row-count table lets later passes SKIP blocks that
@@ -46,7 +49,8 @@ import numpy as np
 
 from ..ops.grower import (GrowerConfig, TreeArrays, monotone_gain_mult,
                           node_feature_mask_for, rand_thresholds_for)
-from ..ops.histogram import accumulate_histogram
+from ..ops.histogram import (accumulate_histogram, fold_hist, hist_totals,
+                             sub_hist)
 from ..ops.split import (NEG_INF, bitset_contains, cat_words,
                          find_best_split)
 from ..utils.log import LightGBMError, check
@@ -138,12 +142,7 @@ class StreamTreeGrower:
                                         chunk_rows=cfg.hist_chunk_rows,
                                         variant=cfg.hist_variant)
 
-        @jax.jit
-        def root_pass(hist_acc, tot_acc, bins_blk, g, h, rw):
-            tot = tot_acc + jnp.stack([jnp.sum(g * rw), jnp.sum(h * rw),
-                                       jnp.sum(rw)])
-            return hist_accum(hist_acc, bins_blk, g, h, rw), tot
-        self._root_pass = root_pass
+        self._root_pass = jax.jit(hist_accum)
 
         @jax.jit
         def split_pass(hist_acc, bins_blk, leafv, g, h, rw, rows, leaf,
@@ -184,19 +183,21 @@ class StreamTreeGrower:
                 mult = monotone_gain_mult(depth, md["monotone"],
                                           cfg.monotone_penalty)
             return find_best_split(
-                hist, md["num_bins"], md["default_bins"], md["nan_bins"],
+                fold_hist(hist), md["num_bins"], md["default_bins"],
+                md["nan_bins"],
                 md["is_categorical"], md["monotone"], sum_g, sum_h, count,
                 p, fmask, 0.0, lo, hi, rand_threshold=rand,
                 sorted_cat=cfg.sorted_cat, gain_mult=mult)
 
         @jax.jit
-        def root_find(hist, tot, fmask, key):
-            return find_inner(hist, tot[0], tot[1], tot[2], fmask, key,
-                              jnp.int32(0), jnp.int32(0),
-                              jnp.float32(NEG_INF), jnp.float32(-NEG_INF))
+        def root_find(hist, fmask, key):
+            tot = hist_totals(hist)
+            return tot, find_inner(hist, tot[0], tot[1], tot[2], fmask, key,
+                                   jnp.int32(0), jnp.int32(0),
+                                   jnp.float32(NEG_INF), jnp.float32(-NEG_INF))
         self._root_find = root_find
 
-        # donate the [L, F, B, 3] store (the largest device resident) so
+        # donate the [L, F, B, 6] store (the largest device resident) so
         # the functional .at[].set updates alias in place instead of
         # transiently doubling it every split; CPU doesn't implement
         # donation and would warn per call, so only donate off-CPU
@@ -204,24 +205,25 @@ class StreamTreeGrower:
 
         @functools.partial(jax.jit, donate_argnums=_donate)
         def child_step(store, small_hist, leaf, new_id, left_smaller,
-                       sums2, lo2, hi2, step, depth, fmask, key):
+                       lo2, hi2, step, depth, fmask, key):
             """Histogram subtraction + both children's split searches in one
             program (one device sync per split reads the pair).
 
-            sums2: [2, 3] child (sum_g, sum_h, count); lo2/hi2: [2] bounds.
+            lo2/hi2: [2] bounds.  Returns the store, the two searches and
+            ``sums2`` [2, 3], each child's (sum_g, sum_h, count) from its
+            own histogram.
             """
-            from ..ops.histogram import subtract_histogram
-            parent = store[leaf]
-            large = subtract_histogram(parent, small_hist)
+            large = sub_hist(store[leaf], small_hist)
             lhist = jnp.where(left_smaller, small_hist, large)
-            rhist = subtract_histogram(parent, lhist)
+            rhist = jnp.where(left_smaller, large, small_hist)
             store = store.at[leaf].set(lhist).at[new_id].set(rhist)
             hist2 = jnp.stack([lhist, rhist])
+            sums2 = hist_totals(hist2)
             s2 = jax.vmap(
                 lambda hc, s_, lo_, hi_: find_inner(
                     hc, s_[0], s_[1], s_[2], fmask, key, step, depth,
                     lo_, hi_))(hist2, sums2, lo2, hi2)
-            return store, s2
+            return store, s2, sums2
         self._child_step = child_step
 
     # ------------------------------------------------------------------
@@ -232,24 +234,21 @@ class StreamTreeGrower:
         return out
 
     def _accumulate_root(self, g, h, rw):
-        """Root histogram + totals over every shard's blocks."""
+        """Root histogram (a pair) over every shard's blocks."""
         import jax.numpy as jnp
-        hist = jnp.zeros((self._f, self._B, 3), jnp.float32)
-        tot = jnp.zeros(3, jnp.float32)
+        hist = jnp.zeros((self._f, self._B, 6), jnp.float32)
         for si, sh in enumerate(self.shards):
             off = self._shard_offsets[si]
             extras = {"g": g[off:off + sh.matrix.num_data],
                       "h": h[off:off + sh.matrix.num_data],
                       "rw": rw[off:off + sh.matrix.num_data]}
             for blk in sh.pipeline.blocks(extras):
-                hist, tot = self._root_pass(hist, tot, blk.bins,
-                                            blk.extras["g"],
-                                            blk.extras["h"],
-                                            blk.extras["rw"])
+                hist = self._root_pass(hist, blk.bins, blk.extras["g"],
+                                       blk.extras["h"], blk.extras["rw"])
             self._counts[si][:, :] = 0
             for b in range(sh.matrix.num_blocks):
                 self._counts[si][b, 0] = sh.matrix.block_rows_actual(b)
-        return self._reduce(hist), self._reduce(tot)
+        return self._reduce(hist)
 
     def _accumulate_split(self, si_extras, leaf, new_id, feat, thr, dleft,
                           cbits, left_smaller):
@@ -257,7 +256,7 @@ class StreamTreeGrower:
         shard's leaf_vec + count table, returns the smaller child's
         (locally accumulated) histogram."""
         import jax.numpy as jnp
-        hist = jnp.zeros((self._f, self._B, 3), jnp.float32)
+        hist = jnp.zeros((self._f, self._B, 6), jnp.float32)
         cbits_dev = jnp.asarray(cbits)
         for si, sh in enumerate(self.shards):
             touched = np.nonzero(self._counts[si][:, leaf] > 0)[0]
@@ -358,13 +357,13 @@ class StreamTreeGrower:
 
         # ---- root --------------------------------------------------------
         t0 = time.perf_counter()
-        root_hist, tot = self._accumulate_root(g, h, rw)
+        root_hist = self._accumulate_root(g, h, rw)
         self._m_hist.observe(time.perf_counter() - t0)
-        store = jnp.zeros((L, f, self._B, 3), jnp.float32
+        store = jnp.zeros((L, f, self._B, 6), jnp.float32
                           ).at[0].set(jnp.asarray(root_hist))
+        tot, s0 = jax.device_get(self._root_find(jnp.asarray(root_hist),
+                                                 fmask_dev, key))
         leaf_count[0], leaf_weight[0], leaf_sum_g[0] = tot[2], tot[1], tot[0]
-        s0 = jax.device_get(self._root_find(jnp.asarray(root_hist),
-                                            jnp.asarray(tot), fmask_dev, key))
         _set_best(best, 0, s0)
 
         si_extras = []
@@ -423,13 +422,6 @@ class StreamTreeGrower:
             leaf_depth[leaf] = leaf_depth[new_id] = depth
             leaf_value[leaf] = best["lout"][leaf]
             leaf_value[new_id] = best["rout"][leaf]
-            lsums = np.asarray([best["lg"][leaf], best["lh"][leaf],
-                                best["lc"][leaf]], np.float32)
-            rsums = np.asarray([best["rg"][leaf], best["rh"][leaf],
-                                best["rc"][leaf]], np.float32)
-            leaf_sum_g[leaf], leaf_weight[leaf], leaf_count[leaf] = lsums
-            leaf_sum_g[new_id], leaf_weight[new_id], leaf_count[new_id] = \
-                rsums
             leaf_parent[leaf] = leaf_parent[new_id] = j
             leaf_is_left[leaf], leaf_is_left[new_id] = True, False
 
@@ -453,14 +445,16 @@ class StreamTreeGrower:
 
             # --- both children's next best splits (one device sync) -------
             t0 = time.perf_counter()
-            store, s2 = self._child_step(
+            store, s2, sums2 = self._child_step(
                 store, small_hist, np.int32(leaf), np.int32(new_id),
                 np.bool_(left_smaller),
-                jnp.asarray(np.stack([lsums, rsums])),
                 jnp.asarray(np.asarray([l_lo, r_lo], np.float32)),
                 jnp.asarray(np.asarray([l_hi, r_hi], np.float32)),
                 np.int32(j + 1), np.int32(depth), fmask_dev, key)
-            s2 = jax.device_get(s2)
+            s2, (lsums, rsums) = jax.device_get((s2, sums2))
+            leaf_sum_g[leaf], leaf_weight[leaf], leaf_count[leaf] = lsums
+            leaf_sum_g[new_id], leaf_weight[new_id], leaf_count[new_id] = \
+                rsums
             self._m_split.observe(time.perf_counter() - t0)
             depth_ok = cfg.max_depth <= 0 or depth < cfg.max_depth
             sl = jax.tree.map(lambda a: a[0], s2)

@@ -62,7 +62,8 @@ def run_shootout(rows, max_bins, emit=emit, interpret=False):
     import numpy as np
 
     from lightgbm_tpu.ops import onehot_variants as ov
-    from lightgbm_tpu.ops.histogram import HIST_PARITY_TOL, _hist_onehot
+    from lightgbm_tpu.ops.histogram import (HIST_PARITY_TOL, _hist_onehot,
+                                            fold_hist)
 
     F = 28
     chip = COSTS.current_chip()
@@ -87,8 +88,8 @@ def run_shootout(rows, max_bins, emit=emit, interpret=False):
         bins_t = jnp.asarray(np.ascontiguousarray(bins.T))  # [F, N] u8, once
         bins_d = jnp.asarray(bins)
 
-        ref = jax.jit(lambda b_, g_: _hist_onehot(b_, g_, h, m, B, 65536))(
-            bins_d, g)
+        ref = jax.jit(lambda b_, g_: fold_hist(
+            _hist_onehot(b_, g_, h, m, B, 65536)))(bins_d, g)
         ref = ref.block_until_ready()
 
         for name, BR in entry_grid(ov.VARIANT_NAMES):
@@ -105,7 +106,7 @@ def run_shootout(rows, max_bins, emit=emit, interpret=False):
                 rows_arr = jax.jit(prep)(g, h, m).block_until_ready()
                 jfn = jax.jit(run)
                 hist = jfn(bins_t, rows_arr).block_until_ready()
-                err = float(jnp.max(jnp.abs(hist - ref)
+                err = float(jnp.max(jnp.abs(fold_hist(hist) - ref)
                                     / (jnp.abs(ref) + 1.0)))
                 if err > HIST_PARITY_TOL:
                     emit(stage="onehot_variant", name=tag, max_bin=B,

@@ -28,7 +28,8 @@ def main():
     h = jnp.asarray(rng.uniform(0.1, 1.0, size=n).astype(np.float32))
     m = jnp.asarray((rng.uniform(size=n) < 0.8).astype(np.float32))
 
-    def relerr(a, bb):
+    def relerr(a, bb):         # of two pair histograms
+        a, bb = H.fold_hist(a), H.fold_hist(bb)
         return float(jnp.max(jnp.abs(a - bb) / (jnp.abs(bb) + 1.0)))
 
     # truly-f32 references: scatter-add, and onehot at 'highest' precision

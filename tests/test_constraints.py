@@ -157,15 +157,17 @@ def test_monotone_intermediate_less_constraining_than_basic():
     assert not np.allclose(basic.predict(X[:100]), inter.predict(X[:100]))
 
 
-def test_monotone_advanced_holds_and_differs():
+@pytest.mark.parametrize("seed", [1, 3])
+def test_monotone_advanced_holds_and_differs(seed):
     """Advanced re-derives child bounds from rect comparability: it must
-    stay monotone, fit at least as well as intermediate on interaction
-    data (looser-but-valid bounds admit more splits), and actually be a
-    distinct mode (reference AdvancedLeafConstraints,
-    monotone_constraints.hpp:230-375)."""
-    X, y = _monotone_fixture(seed=1)
+    stay monotone in both constrained features, fit at least as well as
+    intermediate on interaction data (looser-but-valid bounds admit more
+    splits), and actually be a distinct mode (reference
+    AdvancedLeafConstraints, monotone_constraints.hpp:230-375)."""
+    X, y = _monotone_fixture(seed=seed)
     adv = _train_monotone(X, y, "advanced")
     assert _monotone_violation(adv, X, 0, +1) <= 1e-10
+    assert _monotone_violation(adv, X, 3, -1) <= 1e-10
     inter = _train_monotone(X, y, "intermediate")
     l2_adv = float(np.mean((adv.predict(X) - y) ** 2))
     l2_inter = float(np.mean((inter.predict(X) - y) ** 2))

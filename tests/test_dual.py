@@ -17,7 +17,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from lightgbm_tpu.ops.histogram import _hist_onehot, _hist_scatter
+from lightgbm_tpu.ops.histogram import (_hist_onehot, _hist_scatter,
+                                        fold_hist)
 
 
 def _data(n=20000, f=12, b=255, seed=3):
@@ -31,8 +32,9 @@ def _data(n=20000, f=12, b=255, seed=3):
 
 def test_scatter_vs_onehot_parity():
     bins, g, h, m = _data()
-    a = jax.jit(lambda *x: _hist_scatter(*x, 255))(bins, g, h, m)
-    b = jax.jit(lambda *x: _hist_onehot(*x, 255, 65536))(bins, g, h, m)
+    a = jax.jit(lambda *x: fold_hist(_hist_scatter(*x, 255)))(bins, g, h, m)
+    b = jax.jit(lambda *x: fold_hist(_hist_onehot(*x, 255, 65536)))(
+        bins, g, h, m)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-3)
 
@@ -74,8 +76,9 @@ def test_pallas_vs_onehot_parity_tpu():
     from lightgbm_tpu.ops.histogram import _hist_pallas
     bins, g, h, m = _data()
     from lightgbm_tpu.ops.histogram import HIST_PARITY_TOL
-    a = jax.jit(lambda *x: _hist_pallas(*x, 255))(bins, g, h, m)
-    b = jax.jit(lambda *x: _hist_onehot(*x, 255, 65536))(bins, g, h, m)
+    a = jax.jit(lambda *x: fold_hist(_hist_pallas(*x, 255)))(bins, g, h, m)
+    b = jax.jit(lambda *x: fold_hist(_hist_onehot(*x, 255, 65536)))(
+        bins, g, h, m)
     err = float(jnp.max(jnp.abs(a - b) / (jnp.abs(b) + 1.0)))
     # the shared lo-residual-floor tolerance (derivation on the constant in
     # ops/histogram.py), still >200x below the bare-bf16 failure mode

@@ -209,17 +209,17 @@ g = rng.normal(size=C).astype(np.float32)
 h = rng.random(C).astype(np.float32)
 m = (rng.random(C) > 0.2).astype(np.float32)
 bl = np.sort(rng.integers(0, k, size=NB)).astype(np.int32)
-ref = H.build_histogram_leaves(
+ref = H.fold_hist(H.build_histogram_leaves(
     jnp.asarray(comb), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
-    jnp.asarray(bl), k, B, method="scatter", block_rows=BR, f_limit=8)
+    jnp.asarray(bl), k, B, method="scatter", block_rows=BR, f_limit=8))
 orig = pl.pallas_call
 def interp(*a, **kw):
     kw["interpret"] = True
     return orig(*a, **kw)
 with mock.patch.object(pl, "pallas_call", interp):
-    got = H._hist_leaves_pallas(
+    got = H.fold_hist(H._hist_leaves_pallas(
         jnp.asarray(comb), jnp.asarray(g), jnp.asarray(h),
-        jnp.asarray(m), jnp.asarray(bl), k, B, BR, 8)
+        jnp.asarray(m), jnp.asarray(bl), k, B, BR, 8))
 np.testing.assert_allclose(np.asarray(ref)[:, :8], np.asarray(got),
                            atol=1e-3)
 print("INTERPRET_OK")
@@ -482,3 +482,98 @@ def test_partition_gathers_one_byte_and_scatters_perm_only():
     n_carried = len(loops[0].params["body_jaxpr"].out_avals)
     carried = [v.aval for v in loops[0].invars[-n_carried:]]
     assert [a.dtype for a in carried if a.shape == (n,)] == [jnp.int32]
+
+
+# ---- sums that stay right in a 20-row leaf under a root of 4e5 -------------
+def _pocket_rows(seed=28, n=120_000, n_heavy=20_000):
+    """120,000 rows over six 16-level columns at LightGBM's default leaf
+    limits.  The clicks are the rows of 40 of the 4,096 cells of the first
+    three columns, about 29 rows a cell, so trees of 255 leaves end in leaves
+    of 20 to 30 rows.  Sample weights: 20,000 rows weigh 80 (hessian 20 each
+    at the start: the root's hessian sum is 4e5) and come in pairs of one
+    feature vector, one a click and one not, so their gradients cancel in
+    every leaf and they steer no split; the others weigh 0.1, so a leaf of 20
+    of them sums to a hessian of 0.5."""
+    rng = np.random.default_rng(seed)
+    X = rng.integers(0, 16, (n, 6)).astype(np.float32)
+    cells = rng.choice(16 ** 3, 40, replace=False)
+    y = np.isin(X[:, 0] * 256 + X[:, 1] * 16 + X[:, 2], cells).astype(np.float32)
+    w = np.full(n, 0.1, np.float32)
+    heavy = rng.choice(n, n_heavy, replace=False)
+    X[heavy[1::2]] = X[heavy[0::2]]
+    w[heavy], y[heavy[0::2]], y[heavy[1::2]] = 80.0, 0.0, 1.0
+    return X, y, w
+
+
+def _follow_in_float64(X, y, w, trees, learning_rate=0.1):
+    """What a correct booster writes into the dumped ``trees``
+    (``benchmarks.reference.parse_tree``), in float64 numpy, by the method of
+    ``benchmarks/reference.py`` with sample weights: route the raw rows down
+    each tree, sum each leaf's weighted gradient and hessian from the
+    reference's own running score, and follow its own values into the next
+    tree.  Per tree: each leaf's rows, value and hessian sum."""
+    X, y, w = (np.asarray(a, np.float64) for a in (X, y, w))
+    rows = np.arange(len(y))
+    pavg = np.sum(w * y) / np.sum(w)
+    init = np.log(pavg / (1.0 - pavg))
+    score = np.full(len(y), init)
+    out = []
+    for k, t in enumerate(trees):
+        node = np.zeros(len(y), np.int64)
+        for _ in range(t["depth"]):
+            at = np.maximum(node, 0)
+            nxt = np.where(X[rows, t["feature"][at]] <= t["threshold"][at],
+                           t["left"][at], t["right"][at])
+            node = np.where(node >= 0, nxt, node)
+        leaf, nl = ~node, t["num_leaves"]
+        p = 1.0 / (1.0 + np.exp(-score))
+        g, h, c = (np.bincount(leaf, weights=v, minlength=nl)
+                   for v in ((p - y) * w, p * (1.0 - p) * w, np.ones(len(y))))
+        value = -g / h * learning_rate
+        out.append({"count": c, "hess": h,
+                    "value": value + (init if k == 0 else 0.0)})
+        score = score + value[leaf]
+    return out
+
+
+@pytest.fixture(scope="module")
+def pocket_rows():
+    return _pocket_rows()
+
+
+@pytest.mark.parametrize("grower,extra,rounds", [
+    ("frontier", {"frontier_k": 16}, 5),
+    ("frontier", {"frontier_k": 1}, 5),
+    ("serial", {}, 5),
+    # the streamed grower pays a device round trip a split: two trees
+    ("serial", {"stream_rows": 32768}, 2)],
+    ids=["frontier_k16", "frontier_k1", "serial", "streamed"])
+def test_small_leaf_sums_under_a_heavy_root(pocket_rows, grower, extra, rounds):
+    """Through ``lgb.train`` at ``min_data_in_leaf=20,
+    min_sum_hessian_in_leaf=1e-3, num_leaves=255``: every leaf's value
+    against the float64 reference that routes the raw rows down the dumped
+    trees.  Before PR 28 the root's sums' float32 error (an ulp of 4e5 is
+    0.03) went whole into the small leaves: a row-weighted gap of 1e-2 to 1,
+    single leaves 20 times off."""
+    from benchmarks.reference import parse_tree
+    X, y, w = pocket_rows
+    p = {"objective": "binary", "num_leaves": 255, "min_data_in_leaf": 20,
+         "min_sum_hessian_in_leaf": 1e-3, "verbose": -1,
+         "tree_grower": grower, **extra}
+    bst = lgb.train(p, lgb.Dataset(X, label=y, weight=w, params=p), rounds)
+    trees = [parse_tree(t) for t in bst.dump_model()["tree_info"]]
+    ref = _follow_in_float64(X, y, w, trees)
+    assert ref[0]["hess"].sum() > 3e5
+    small = 0
+    for t, r in zip(trees, ref):
+        nl = t["num_leaves"]
+        assert nl == 255
+        got, want = t["leaf_value"][:nl], r["value"]
+        np.testing.assert_array_equal(t["leaf_count"][:nl], r["count"])
+        l2 = np.sqrt(np.sum(r["count"] * (got - want) ** 2)
+                     / np.sum(r["count"] * want ** 2))
+        worst = np.max(np.abs(got - want)
+                       / np.maximum(np.abs(want), np.median(np.abs(want))))
+        assert l2 < 1e-3 and worst < 1e-2, (l2, worst)
+        small += int(np.sum((r["hess"] < 1.0) & (r["count"] < 40)))
+    assert small >= 20 * len(trees)     # the regime: small leaves, hessian under 1

@@ -165,13 +165,13 @@ for B in (64, 255):
     m = jnp.asarray(np.where(rng.uniform(size=n) < 0.8,
                              rng.uniform(0.1, 2.5, size=n),
                              0.0).astype(np.float32))
-    ref = H._hist_scatter(bins, g, h, m, B)
+    ref = H.fold_hist(H._hist_scatter(bins, g, h, m, B))
     for name, spec in ov.VARIANTS.items():
         if not spec.supports(B):
             assert name == "packed" and B == 255
             continue
-        got = jax.jit(lambda *x, v=name: H._hist_pallas(*x, B, variant=v))(
-            bins, g, h, m)
+        got = jax.jit(lambda *x, v=name: H.fold_hist(
+            H._hist_pallas(*x, B, variant=v)))(bins, g, h, m)
         err = float(jnp.max(jnp.abs(got - ref) / (jnp.abs(ref) + 1.0)))
         assert err < H.HIST_PARITY_TOL, (name, B, err)
         print("PROD_OK", name, B, err)
@@ -182,7 +182,7 @@ for B in (64, 255):
         if not spec.supports(B):
             continue
         prep, run = ov.make_bench_kernel(name, f, B, 128, interpret=True)
-        got = jax.jit(run)(bins_t, jax.jit(prep)(g, h, m))
+        got = H.fold_hist(jax.jit(run)(bins_t, jax.jit(prep)(g, h, m)))
         err = float(jnp.max(jnp.abs(got - ref) / (jnp.abs(ref) + 1.0)))
         assert err < H.HIST_PARITY_TOL, ("bench", name, B, err)
         print("BENCH_OK", name, B, err)
@@ -218,13 +218,12 @@ for B, names in ((64, ("base", "packed", "staged", "int8")),
     # slot k-2 deliberately empty: must come back zeros, not stale memory
     bl = np.sort(rng.integers(0, k, size=NB)).astype(np.int32)
     bl = jnp.asarray(np.where(bl == k - 2, k - 1, bl))
-    ref = H.build_histogram_leaves(comb, g, h, m, bl, k, B,
-                                   method="scatter", block_rows=BR,
-                                   f_limit=7)
+    ref = H.fold_hist(H.build_histogram_leaves(
+        comb, g, h, m, bl, k, B, method="scatter", block_rows=BR, f_limit=7))
     assert ref.shape[1] == 7       # fallback slices BEFORE scattering now
     for name in names:
-        got = jax.jit(lambda *x, v=name: H._hist_leaves_pallas(
-            *x, k, B, BR, 7, variant=v))(comb, g, h, m, bl)
+        got = jax.jit(lambda *x, v=name: H.fold_hist(H._hist_leaves_pallas(
+            *x, k, B, BR, 7, variant=v)))(comb, g, h, m, bl)
         err = float(jnp.max(jnp.abs(got - ref) / (jnp.abs(ref) + 1.0)))
         assert err < H.HIST_PARITY_TOL, (name, B, err)
         assert float(jnp.abs(got[k - 2]).max()) == 0.0

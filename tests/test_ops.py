@@ -6,7 +6,7 @@ import pytest
 
 pytestmark = pytest.mark.medium
 
-from lightgbm_tpu.ops.histogram import build_histogram
+from lightgbm_tpu.ops.histogram import build_histogram, fold_hist
 from lightgbm_tpu.ops.split import SplitParams, find_best_split, leaf_output
 
 
@@ -32,9 +32,9 @@ def test_histogram_matches_reference(method):
     grad = rng.normal(size=n).astype(np.float32)
     hess = rng.uniform(0.1, 1.0, size=n).astype(np.float32)
     mask = (rng.uniform(size=n) < 0.7).astype(np.float32)
-    got = np.asarray(build_histogram(jnp.asarray(bins), jnp.asarray(grad),
-                                     jnp.asarray(hess), jnp.asarray(mask), b,
-                                     method=method, chunk_rows=128))
+    got = np.asarray(fold_hist(build_histogram(
+        jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
+        jnp.asarray(mask), b, method=method, chunk_rows=128)))
     want = _ref_histogram(bins, grad, hess, mask, b)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
@@ -152,21 +152,22 @@ def test_gather_rows_compaction():
     bc, gc, hc, mc = gather_rows(bins, g, h, mask, cap)
     assert bc.shape == (cap, f)
     # same histogram from the compacted buffer as from the full masked pass
-    full = build_histogram(bins, g, h, mask, b, method="scatter")
-    comp = build_histogram(bc, gc, hc, mc, b, method="scatter")
+    full = fold_hist(build_histogram(bins, g, h, mask, b, method="scatter"))
+    comp = fold_hist(build_histogram(bc, gc, hc, mc, b, method="scatter"))
     np.testing.assert_allclose(np.asarray(full), np.asarray(comp), atol=1e-4)
 
 
 def test_hist_onehot_matches_scatter():
-    from lightgbm_tpu.ops.histogram import build_histogram
+    from lightgbm_tpu.ops.histogram import build_histogram, fold_hist
     rng = np.random.default_rng(4)
     n, f, b = 3000, 7, 32
     bins = jnp.asarray(rng.integers(0, b, size=(n, f), dtype=np.uint8))
     g = jnp.asarray(rng.normal(size=n).astype(np.float32))
     h = jnp.asarray(rng.uniform(0.1, 1.0, size=n).astype(np.float32))
     mask = jnp.asarray((rng.uniform(size=n) < 0.7).astype(np.float32))
-    a = build_histogram(bins, g, h, mask, b, method="scatter")
-    c = build_histogram(bins, g, h, mask, b, method="onehot", chunk_rows=1024)
+    a = fold_hist(build_histogram(bins, g, h, mask, b, method="scatter"))
+    c = fold_hist(build_histogram(bins, g, h, mask, b, method="onehot",
+                                  chunk_rows=1024))
     np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-4, atol=1e-3)
 
 
@@ -232,3 +233,64 @@ def test_node_feature_mask_sizes_from_allowed_subset():
     # works under jit (n_take must stay traceable)
     jitted = jax.jit(lambda k, m: node_feature_mask_for(k, 3, m, 0.5))
     assert int(jnp.sum(jitted(key, bytree) > 0)) == 5
+
+
+def _small_child_case(case):
+    """A seeded [3, 64, 3] histogram whose totals are of the order 4e5 in
+    hessian and 1e5 in rows and whose best split parts off a child of 20 or
+    25 light rows, hessian 0.514 or 0.6425; float64, from rows."""
+    rng = np.random.default_rng(28)
+    b, n_bulk, n_tail = 64, 100_000, 20
+    n_nan = {"missing_right": 5, "missing_left": 1000, "categorical": 0}[case]
+    n = n_bulk + n_tail + n_nan
+    tail = slice(n_bulk, n_bulk + n_tail)
+    h = np.full(n, 4.0) * rng.uniform(0.9, 1.1, n)
+    g = rng.normal(0.0, 1e-3, n) * h
+    light = np.r_[tail] if case != "missing_right" else np.r_[n_bulk:n]
+    h[light], g[light] = 0.0257, -0.15
+    bins = rng.integers(0, b, (n, 3))
+    if case == "categorical":       # bin 0 the catch-all, 1 the bulk, 2 and 3 the tail
+        bins[:n_bulk, 0] = 1
+        bins[tail, 0] = rng.integers(2, 4, n_tail)
+    else:                           # the tail in the last two bins before the NaN bin
+        bins[:n_bulk, 0] = rng.integers(0, b - 3, n_bulk)
+        bins[tail, 0] = rng.integers(b - 3, b - 1, n_tail)
+        bins[n_bulk + n_tail:, 0] = b - 1
+    hist = np.zeros((3, b, 3))
+    for f in range(3):
+        for c, v in enumerate((g, h, np.ones(n))):
+            hist[f, :, c] = np.bincount(bins[:, f], weights=v, minlength=b)
+    return hist, light
+
+
+@pytest.mark.parametrize("case", ["missing_right", "missing_left",
+                                  "categorical"])
+def test_small_child_sums_are_relative_to_the_child(case):
+    """The split step under totals of 4e5: the small right child's three sums
+    come from its own bins (within 1e-3 of float64, its count exact), and the
+    sides add up to the parent's.  ``right = total - left`` in float32 puts
+    an ulp of the total (0.03) on a hessian sum of 0.5."""
+    hist64, light = _small_child_case(case)
+    hist = hist64.astype(np.float32)
+    inp = _split_inputs(hist, [4 if case == "categorical" else 64, 64, 64])
+    if case == "categorical":
+        inp["is_categorical"] = jnp.asarray([True, False, False])
+    else:
+        inp["nan_bins"] = jnp.asarray([63, -1, -1], jnp.int32)
+    tot = hist.astype(np.float64)[0].sum(axis=0)
+    s = find_best_split(**inp, sum_g=np.float32(tot[0]), sum_h=np.float32(tot[1]),
+                        count=np.float32(tot[2]),
+                        p=_default_params(min_data_in_leaf=20,
+                                          min_sum_hessian_in_leaf=1e-3))
+    assert int(s.feature) == 0 and float(s.gain) > 0
+    assert bool(s.default_left) == (case == "missing_left")
+    # what float64 makes of the same float32 bins, on the child's side
+    side = {"missing_right": [61, 62, 63], "missing_left": [61, 62],
+            "categorical": [2, 3]}[case]
+    want = hist.astype(np.float64)[0, side].sum(axis=0)
+    assert want[2] == len(light) and want[1] < 1.0
+    got = np.array([s.right_sum_g, s.right_sum_h, s.right_count], np.float64)
+    assert got[2] == want[2]
+    np.testing.assert_allclose(got[:2], want[:2], rtol=1e-3)
+    left = np.array([s.left_sum_g, s.left_sum_h, s.left_count], np.float64)
+    np.testing.assert_allclose(left + got, tot, rtol=2e-7, atol=2e-7 * tot[1])

@@ -217,3 +217,65 @@ def test_reader_takes_its_number_from_the_table(metric, fixture):
     assert read(run) == run["_phase_table"]["metrics"][metric]
     # no table (an older program, a rehearsal on the CPU): no value, no raise
     assert read({"trace": None, "window_s": 1.0}) is None
+
+
+# ---- PR 28's readers: counters on the drain spans, a scope of their own ----
+def _program_with(monkeypatch, spans):
+    """Put ``spans`` where ``benchmarks/leaf_reduce.py`` looks for them."""
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.obs.tracer import Span, Tracer
+
+    tracer = Tracer()
+    for s in spans:
+        tracer._keep(Span(**s))
+    monkeypatch.setattr(obs, "get_tracer", lambda: tracer)
+
+
+LEAF_COUNTERS = {      # by tree_iteration; 0 is the warm-up's, outside the window
+    0: {"leaves": 255, "leaves_under_100_rows": 10, "tree_depth": 30},
+    1: {"leaves": 255, "leaves_under_100_rows": 200, "tree_depth": 14},
+    2: {"leaves": 200, "leaves_under_100_rows": 164, "tree_depth": 17}}
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("small_leaf_share", 100.0 * (200 + 164) / (255 + 200)),
+    ("tree_depth", (14 + 17) / 2)])
+def test_leaf_counter_readers(metric, want, fixture, monkeypatch):
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
+    assert set(listed[metric + ".train"]["workloads"]) == {
+        "criteo67-msh100.train-valid", "criteo67.train-valid"}
+    read = _reader(metric + ".train")
+    run = {"trace": fixture["trace"], "window_s": WINDOW_S}
+    # a program whose drains carry no leaf counters (the parent's): no value
+    _program_with(monkeypatch, fixture["spans"])
+    assert read(run) is None
+    for s in fixture["spans"]:
+        if s["name"] == "lgbm/update/drain":
+            s["args"].update(LEAF_COUNTERS[s["args"]["tree_iteration"]],
+                             min_leaf_hessian=0.6)
+    _program_with(monkeypatch, fixture["spans"])
+    assert read(run) == pytest.approx(want)
+    # a rehearsal on the CPU has no device plane: no value, no raise
+    assert read({"trace": None, "window_s": 1.0}) is None
+
+
+def test_sum_repair_share_reader(fixture, monkeypatch):
+    from lightgbm_tpu.obs import scopes
+    assert "lgbm/sum_repair" in scopes.SCOPES
+    read = _reader("sum_repair_share.train")
+    run = {"trace": fixture["trace"], "window_s": WINDOW_S,
+           "_phase_table": _reduce(fixture)}
+    # the program names the scope and no operation carries it
+    assert read(run) == 0.0
+    # copy.7, 0.05 ms in the fixture, put under the scope
+    fixture["scopes"]["copy.7 s32[4096]"] = "lgbm/sum_repair"
+    run["_phase_table"] = table = _reduce(fixture)
+    assert table["scope_seconds"]["lgbm/sum_repair"] > 0
+    assert read(run) == pytest.approx(
+        100 * table["scope_seconds"]["lgbm/sum_repair"] / WINDOW_S)
+    # a program that names no such scope (the parent's), and no device plane
+    monkeypatch.setattr(scopes, "SCOPES", tuple(
+        s for s in scopes.SCOPES if s != "lgbm/sum_repair"))
+    assert read(run) is None
+    assert read({"trace": None, "window_s": 1.0}) is None
