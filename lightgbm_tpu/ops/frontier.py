@@ -84,7 +84,8 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     derived from the ranges wherever it is needed and is never stored per
     row: a round compares each position against the at most ``frontier_k``
     ranges it splits (``_spread_by_range``), and the final node assignment
-    ranks it among the leaves' begins.
+    is a running sum of the leaf id's steps at the leaves' begins
+    (``grower.node_assign_from_ranges``).
 
     Sums: the histogram store holds pairs (``histogram.py``: a float32 sum
     and what it rounds away), siblings are subtracted in pairs, a leaf's
@@ -97,7 +98,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     The device phases carry ``jax.named_scope`` names (``obs/scopes.py``
     lists them): compile-time metadata, nothing at run time.
     """
-    from .grower import TreeArrays, _BestSplits
+    from .grower import TreeArrays, _BestSplits, node_assign_from_ranges
 
     n, n_cols = bins.shape
     if efb is not None:
@@ -813,14 +814,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
         leaf_nr = leafset(jnp.zeros(L, jnp.int32), lnl,
                           state["sp_nrows"][src] - lnl)
         leaf_nr = leaf_nr.at[0].set(jnp.where(no_split, n, leaf_nr[0]))
-        begins = jnp.where(leaf_nr > 0, leaf_beg,
-                           n + 1 + jnp.arange(L, dtype=jnp.int32))
-        lorder = jnp.argsort(begins)
-        sorted_begin = begins[lorder]
-        pos = jnp.arange(n, dtype=jnp.int32)
-        rank = unrolled_rank(sorted_begin, pos, strict=False)
-        leaf_of_pos = jnp.take(lorder, jnp.maximum(rank - 1, 0))
-        node_assign = jnp.zeros(n, jnp.int32).at[state["perm"]].set(leaf_of_pos)
+        node_assign = node_assign_from_ranges(state["perm"], leaf_beg, leaf_nr)
     if not with_stats:
         return tree, node_assign
     stats = jnp.stack([state["n_rounds"], state["rows_sel_hi"],

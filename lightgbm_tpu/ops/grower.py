@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .histogram import (build_histogram, fold_hist, gather_rows, hist_totals,
-                        psum_hist, psum_scatter_hist, sub_hist, unrolled_rank)
+                        psum_hist, psum_scatter_hist, sub_hist)
 from .split import (NEG_INF, SplitParams, SplitResult, bitset_contains,
                     cat_words, derive_larger, find_best_split, leaf_gain,
                     leaf_output, pack_bin_bitset, per_feature_gains)
@@ -315,6 +315,26 @@ def _frontier_eligible(cfg: "GrowerConfig", n_cols: int, interaction_sets,
         Log.warning("tree_grower=frontier is not compatible with the "
                     "requested features; using the serial grower")
     return ok
+
+
+def node_assign_from_ranges(perm, leaf_begin, leaf_nrows):
+    """Leaf id of every row from the leaves' ranges of ``perm``.
+
+    Leaf ``i`` owns the positions ``[leaf_begin[i], leaf_begin[i] +
+    leaf_nrows[i])``; the non-empty leaves' ranges tile ``[0, n)`` and an
+    empty leaf owns nothing.  A position's leaf is piecewise constant, so it
+    is a running sum of its steps: each non-empty leaf writes, at its begin,
+    its id less the id of the range before it (a scatter of ``L`` values),
+    and one ``cumsum`` over positions spreads them.  Nothing is looked up per
+    position; the one ``[n]``-sized scatter takes the ids to row order.
+    """
+    n = perm.shape[0]
+    begins = jnp.where(leaf_nrows > 0, leaf_begin, n)       # empty: dropped
+    order = jnp.argsort(begins).astype(jnp.int32)           # leaf ids by begin
+    before = jnp.concatenate([jnp.zeros(1, jnp.int32), order[:-1]])
+    steps = jnp.zeros(n, jnp.int32).at[begins[order]].set(order - before,
+                                                          mode="drop")
+    return jnp.zeros(n, jnp.int32).at[perm].set(jnp.cumsum(steps))
 
 
 def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
@@ -1409,16 +1429,6 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     if not use_partition:
         return (tree, state["node_assign"]) + no_stats
 
-    # ---- node assignment from the partition (once per tree) ----------------
-    # positions [begin_i, begin_i + nrows_i) belong to leaf i; empty leaves
-    # get out-of-range sentinels so they never match.  Unrolled binary search
-    # over the L sorted begins, then one scatter to row order.
-    begins = jnp.where(state["leaf_nrows"] > 0, state["leaf_begin"],
-                       n + 1 + jnp.arange(L, dtype=jnp.int32))
-    order = jnp.argsort(begins)
-    sorted_begin = begins[order]
-    pos = jnp.arange(n, dtype=jnp.int32)
-    rank = unrolled_rank(sorted_begin, pos, strict=False)
-    leaf_of_pos = jnp.take(order, jnp.maximum(rank - 1, 0))
-    node_assign = jnp.zeros(n, jnp.int32).at[state["perm"]].set(leaf_of_pos)
+    node_assign = node_assign_from_ranges(state["perm"], state["leaf_begin"],
+                                          state["leaf_nrows"])
     return (tree, node_assign) + no_stats

@@ -2,14 +2,19 @@
 
 Used for training/validation score updates: validation sets are binned with
 the training set's mappers, so bin-threshold comparison is exactly equivalent
-to the reference's raw-value traversal (``tree.h:133``), but vectorized over
-all rows with a ``lax.while_loop`` instead of per-row recursion.
+to the reference's raw-value traversal (``tree.h:133``).  The tree is walked
+node by node, not row by row: every grower numbers node ``j`` as the ``j``-th
+split applied, so a node's children carry a higher index than the node, and
+one pass over the nodes in index order, each applied to all rows at once,
+takes every row to its leaf.  A node's fields are scalars and its bins one
+contiguous column; nothing is gathered per row.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ..io.efb import decode_bundle_column
 from .grower import TreeArrays
 
 
@@ -21,42 +26,38 @@ def predict_leaf_binned(tree: TreeArrays, bins: jax.Array, nan_bins: jax.Array,
     when ``bins`` is an EFB bundle matrix (io/efb.py) — the per-feature bin
     decodes through the uniform ``col - off + 1`` range mapping."""
     n = bins.shape[0]
+    cols = bins.T                   # feature-major: a column is a contiguous row
+    nan_bins = jnp.asarray(nan_bins, jnp.int32)
     if efb is not None:
-        fb = jnp.asarray(efb[0].astype("int32"))
-        fo = jnp.asarray(efb[1].astype("int32"))
-        fnb = jnp.asarray(efb[2].astype("int32"))
+        fb, fo, fnb = (jnp.asarray(a.astype("int32")) for a in efb)
+    cw = tree.cat_bits.shape[1]
 
-    def cond(cur):
-        return jnp.any(cur >= 0)
-
-    def body(cur):
-        node = jnp.maximum(cur, 0)
-        feat = tree.split_feature[node]                      # [N]
-        col_id = jnp.take(fb, feat) if efb is not None else feat
-        col = jnp.take_along_axis(bins, col_id[:, None].astype(jnp.int32),
-                                  axis=1)[:, 0].astype(jnp.int32)  # [N]
+    def step(j, cur):
+        feat = tree.split_feature[j]
+        col = jax.lax.dynamic_index_in_dim(
+            cols, fb[feat] if efb is not None else feat, keepdims=False
+        ).astype(jnp.int32)                                  # [N]
         if efb is not None:
-            from ..io.efb import decode_bundle_column
-            col = decode_bundle_column(col, jnp.take(fo, feat),
-                                       jnp.take(fnb, feat)).astype(jnp.int32)
-        thr = tree.threshold[node]
-        is_cat = tree.is_cat_split[node]
-        dleft = tree.default_left[node]
+            col = decode_bundle_column(col, fo[feat], fnb[feat])
         nb = nan_bins[feat]
         is_miss = (col == nb) & (nb >= 0)
-        # categorical: bin-bitset membership (one-hot and sorted subsets)
-        bits = tree.cat_bits[node]                           # [N, CW]
-        word = jnp.take_along_axis(bits, (col >> 5)[:, None], axis=1)[:, 0]
+        # categorical: bin-bitset membership (one-hot and sorted subsets);
+        # word min(col >> 5, cw - 1) of the node's bit set, by selects
+        bits = tree.cat_bits[j]                              # [CW]
+        word = bits[0]
+        for w in range(1, cw):
+            word = jnp.where(col >> 5 >= w, bits[w], word)
         cat_left = ((word >> (col & 31)) & 1) == 1
-        goes_left = jnp.where(is_cat, cat_left,
-                              jnp.where(is_miss, dleft, col <= thr))
-        nxt = jnp.where(goes_left, tree.left_child[node], tree.right_child[node])
-        return jnp.where(cur >= 0, nxt, cur)
+        goes_left = jnp.where(tree.is_cat_split[j], cat_left,
+                              jnp.where(is_miss, tree.default_left[j],
+                                        col <= tree.threshold[j]))
+        nxt = jnp.where(goes_left, tree.left_child[j], tree.right_child[j])
+        return jnp.where(cur == j, nxt, cur)
 
-    has_splits = tree.num_leaves > 1
-    init = jnp.where(has_splits, jnp.zeros(n, jnp.int32), jnp.full(n, -1, jnp.int32))
-    final = jax.lax.while_loop(cond, body, init)
-    return (~final).astype(jnp.int32)
+    # rows stand at node 0, or at leaf 0 (~0) where the tree did not split
+    init = jnp.full(n, jnp.where(tree.num_leaves > 1, 0, -1), jnp.int32)
+    final = jax.lax.fori_loop(0, tree.num_leaves - 1, step, init)
+    return ~final
 
 
 def add_score_from_leaves(score: jax.Array, leaf_idx: jax.Array,

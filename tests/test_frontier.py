@@ -435,6 +435,30 @@ def _walk_eqns(jaxpr, scope=""):
                     yield from _walk_eqns(sub, here)
 
 
+def _small_grow_case(n=1000, f=5, categorical=True, **cfg_kw):
+    """``(args, cfg)`` of a small call of either grower: ``f`` columns of 32
+    bins, the first categorical, eight leaves."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.grower import GrowerConfig
+    from lightgbm_tpu.ops.split import SplitParams
+    kw = dict(
+        num_leaves=8, max_depth=-1, max_bin=32,
+        split=SplitParams(0.0, 0.0, 1, 1e-3, 0.0, 0.0, 0.0, 10.0, 10.0, 4),
+        feature_fraction_bynode=1.0, hist_method="scatter",
+        hist_chunk_rows=8192, sorted_cat=False, frontier_k=3)
+    kw.update(cfg_kw)
+    rng = np.random.default_rng(0)
+    args = (jnp.asarray(rng.integers(0, 32, (n, f)), jnp.uint8),
+            jnp.asarray(rng.normal(size=n), jnp.float32),
+            jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32),
+            jnp.ones(f, bool), jnp.full(f, 32, jnp.int32),
+            jnp.zeros(f, jnp.int32), jnp.full(f, -1, jnp.int32),
+            jnp.zeros(f, bool).at[0].set(categorical), jnp.zeros(f, jnp.int32),
+            jax.random.PRNGKey(0))
+    return args, GrowerConfig(**kw)
+
+
 def test_partition_gathers_one_byte_and_scatters_perm_only():
     """Under the ``partition`` scope the only [n]-sized gather is the bin
     look-up and the only [n]-sized scatter is ``perm``; ``perm`` is the only
@@ -442,22 +466,8 @@ def test_partition_gathers_one_byte_and_scatters_perm_only():
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.ops.frontier import grow_tree_frontier
-    from lightgbm_tpu.ops.grower import GrowerConfig
-    from lightgbm_tpu.ops.split import SplitParams
     n, f = 1000, 5
-    cfg = GrowerConfig(
-        num_leaves=8, max_depth=-1, max_bin=32,
-        split=SplitParams(0.0, 0.0, 1, 1e-3, 0.0, 0.0, 0.0, 10.0, 10.0, 4),
-        feature_fraction_bynode=1.0, hist_method="scatter",
-        hist_chunk_rows=8192, sorted_cat=False, frontier_k=3)
-    rng = np.random.default_rng(0)
-    args = (jnp.asarray(rng.integers(0, 32, (n, f)), jnp.uint8),
-            jnp.asarray(rng.normal(size=n), jnp.float32),
-            jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32),
-            jnp.ones(f, bool), jnp.full(f, 32, jnp.int32),
-            jnp.zeros(f, jnp.int32), jnp.full(f, -1, jnp.int32),
-            jnp.zeros(f, bool).at[0].set(True), jnp.zeros(f, jnp.int32),
-            jax.random.PRNGKey(0))
+    args, cfg = _small_grow_case(n, f)
     jaxpr = jax.make_jaxpr(
         lambda *a: grow_tree_frontier(*a, cfg, with_stats=True))(*args)
 
@@ -482,6 +492,84 @@ def test_partition_gathers_one_byte_and_scatters_perm_only():
     n_carried = len(loops[0].params["body_jaxpr"].out_avals)
     carried = [v.aval for v in loops[0].invars[-n_carried:]]
     assert [a.dtype for a in carried if a.shape == (n,)] == [jnp.int32]
+
+
+def _per_row_ops(jaxpr, n, under):
+    """Under the scope ``under``: the operands' shapes of the gathers with an
+    [n]-sized result, and the results' shapes of the scatters to [n] places."""
+    gathers, scatters = [], []
+    for eqn, scope in _walk_eqns(jaxpr):
+        name = eqn.primitive.name
+        if under not in scope:
+            continue
+        if name == "gather" and eqn.outvars[0].aval.shape[:1] == (n,):
+            gathers.append(eqn.invars[0].aval.shape)
+        elif name.startswith("scatter") and eqn.invars[1].aval.shape[:1] == (n,):
+            scatters.append(eqn.outvars[0].aval.shape)
+    return gathers, scatters
+
+
+def test_finalize_looks_nothing_up_per_position():
+    """Under ``lgbm/finalize`` no gather has an [n]-sized result, and the one
+    scatter to [n] places takes the positions' leaves to row order."""
+    import jax
+    from lightgbm_tpu.ops.frontier import grow_tree_frontier
+    n = 1000
+    args, cfg = _small_grow_case(n)
+    jaxpr = jax.make_jaxpr(lambda *a: grow_tree_frontier(*a, cfg))(*args)
+    gathers, scatters = _per_row_ops(jaxpr.jaxpr, n, "lgbm/finalize")
+    assert gathers == []
+    assert scatters == [(n,)]
+
+
+def test_valid_traverse_looks_nothing_up_per_row():
+    """The program that scores a validation set (``GBDT._valid_update_jit``):
+    under ``lgbm/valid_traverse`` the traversal gathers nothing per row; the
+    one [n]-sized gather left is the score update's ``delta[leaf]``."""
+    import jax
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(1200, 5))
+    X[:, 0] = rng.integers(0, 40, 1200)
+    y = (X[:, 1] + (X[:, 0] % 3 == 0) > 0.5).astype(float)
+    ds = lgb.Dataset(X[:800], label=y[:800], categorical_feature=[0])
+    bst = lgb.train({"objective": "binary", "num_leaves": 8, "verbose": -1},
+                    ds, 1, valid_sets=[ds.create_valid(X[800:], label=y[800:])],
+                    verbose_eval=False)
+    g = bst._gbdt
+    tree = g._device_trees[0]
+    bins = g.valid_sets[0].device_data().bins
+    n, n_leaves = bins.shape[0], tree.leaf_value.shape[0]
+    jaxpr = jax.make_jaxpr(g._valid_update_jit, static_argnums=4)(
+        g._valid_scores[0], tree, tree.leaf_value, bins, 0)
+    gathers, scatters = _per_row_ops(jaxpr.jaxpr, n, "lgbm/valid_traverse")
+    assert gathers == [(n_leaves,)]
+    assert scatters == []
+    whiles = [e for e, s in _walk_eqns(jaxpr.jaxpr)
+              if e.primitive.name == "while" and "lgbm/valid_traverse" in s]
+    assert len(whiles) == 1             # one loop, over the nodes
+
+
+@pytest.mark.parametrize("categorical", [False, True], ids=["plain", "categorical"])
+@pytest.mark.parametrize("grower", ["frontier", "serial"])
+def test_node_assign_is_the_trees_own_traversal(grower, categorical):
+    """The rows' leaves as the grower returns them, from the ranges of its
+    partition, are the leaves the binned traversal finds for the same rows."""
+    import jax
+    from lightgbm_tpu.ops.frontier import grow_tree_frontier
+    from lightgbm_tpu.ops.grower import grow_tree
+    from lightgbm_tpu.ops.predict import predict_leaf_binned
+    # 6,000 rows over a first rung of 1,024: the serial grower partitions
+    args, cfg = _small_grow_case(6000, categorical=categorical, num_leaves=31,
+                                 hist_compact_min_cap=1024, sorted_cat=categorical)
+    if categorical:     # gradients that follow the first column's categories
+        col = np.asarray(args[0][:, 0]).astype(int)
+        args = (args[0], args[1] + 3.0 * (col * 7 % 5 < 2), *args[2:])
+    grow = grow_tree_frontier if grower == "frontier" else grow_tree
+    tree, node_assign = jax.jit(lambda *a: grow(*a, cfg))(*args)
+    assert int(tree.num_leaves) == 31
+    assert bool(np.asarray(tree.is_cat_split).any()) == categorical
+    want = predict_leaf_binned(tree, args[0], args[7])
+    np.testing.assert_array_equal(np.asarray(node_assign), np.asarray(want))
 
 
 # ---- sums that stay right in a 20-row leaf under a root of 4e5 -------------

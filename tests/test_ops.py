@@ -294,3 +294,128 @@ def test_small_child_sums_are_relative_to_the_child(case):
     np.testing.assert_allclose(got[:2], want[:2], rtol=1e-3)
     left = np.array([s.left_sum_g, s.left_sum_h, s.left_count], np.float64)
     np.testing.assert_allclose(left + got, tot, rtol=2e-7, atol=2e-7 * tot[1])
+
+
+# ---- the binned traversal: a scan over the nodes, held to a per-row walk ----
+def _random_tree_arrays(rng, n_leaves, n_slots, n_feat, n_bins, cw, cat_feats=()):
+    """A tree of ``n_leaves`` leaves in arrays that hold ``n_slots``, numbered
+    as every grower numbers it: node ``j`` is the ``j``-th split, its left
+    child keeps the split leaf's id and its right child is leaf ``j + 1``."""
+    from lightgbm_tpu.ops.grower import TreeArrays
+    m = n_slots - 1
+    left, right = np.full(m, -1, np.int32), np.full(m, -1, np.int32)
+    feat = np.full(m, -1, np.int32)
+    thr, dleft, iscat = np.zeros(m, np.int32), np.zeros(m, bool), np.zeros(m, bool)
+    bits = np.zeros((m, cw), np.int64)
+    points_at = {0: None}               # leaf -> (node, side) whose child it is
+    for j in range(n_leaves - 1):
+        leaf = int(rng.choice(sorted(points_at)))
+        if points_at[leaf] is not None:
+            node, side = points_at[leaf]
+            (left if side else right)[node] = j
+        left[j], right[j] = ~leaf, ~(j + 1)
+        points_at[leaf], points_at[j + 1] = (j, True), (j, False)
+        feat[j] = rng.integers(0, n_feat)
+        thr[j] = rng.integers(n_bins // 4, 3 * n_bins // 4)
+        dleft[j] = rng.random() < 0.5
+        iscat[j] = feat[j] in cat_feats
+        bits[j] = rng.integers(0, 2 ** 32, cw)
+    z = lambda k: jnp.zeros(k, jnp.float32)
+    return TreeArrays(
+        split_feature=jnp.asarray(feat), threshold=jnp.asarray(thr),
+        default_left=jnp.asarray(dleft), is_cat_split=jnp.asarray(iscat),
+        cat_bits=jnp.asarray(bits.astype(np.uint32).view(np.int32).reshape(m, cw)),
+        split_gain=z(m), left_child=jnp.asarray(left), right_child=jnp.asarray(right),
+        leaf_value=z(n_slots), leaf_count=z(n_slots), leaf_weight=z(n_slots),
+        internal_value=z(m), internal_count=z(m), num_leaves=jnp.int32(n_leaves))
+
+
+def _walk_rows(tree, bins, nan_bins, efb=None):
+    """Each row down the tree on its own, in NumPy."""
+    from lightgbm_tpu.io.efb import decode_bundle_column
+    t = {k: np.asarray(v) for k, v in tree._asdict().items()}
+    out = np.zeros(len(bins), np.int32)
+    for i, row in enumerate(np.asarray(bins).astype(np.int64)):
+        node = 0 if t["num_leaves"] > 1 else -1
+        while node >= 0:
+            f = t["split_feature"][node]
+            if efb is None:
+                b = row[f]
+            else:
+                b = int(decode_bundle_column(row[efb[0][f]], efb[1][f], efb[2][f]))
+            if t["is_cat_split"][node]:
+                word = int(t["cat_bits"][node].view(np.uint32)[b >> 5])
+                go_left = (word >> (b & 31)) & 1 == 1
+            elif nan_bins[f] >= 0 and b == nan_bins[f]:
+                go_left = t["default_left"][node]
+            else:
+                go_left = b <= t["threshold"][node]
+            node = t["left_child"][node] if go_left else t["right_child"][node]
+        out[i] = ~node
+    return out
+
+
+@pytest.mark.parametrize("case", ["missing_default_left", "missing_default_right",
+                                  "categorical_words", "efb_bundles", "uint16_bins",
+                                  "no_split", "fewer_nodes_than_slots"])
+def test_predict_leaf_binned_matches_a_per_row_walk(case):
+    from lightgbm_tpu.ops.predict import predict_leaf_binned
+    rng = np.random.default_rng(29)
+    n, f, n_bins, dtype, efb = 700, 6, 90, np.uint8, None
+    n_leaves = n_slots = 31
+    cat_feats = ()
+    if case == "categorical_words":
+        cat_feats = (1, 4)                      # 90 bins: three bit-set words
+    elif case == "uint16_bins":
+        n_bins, dtype, cat_feats = 600, np.uint16, (2,)
+    elif case == "no_split":
+        n_leaves = 1
+    elif case == "fewer_nodes_than_slots":
+        n_leaves = 9
+    cw = (n_bins + 31) // 32
+    tree = _random_tree_arrays(rng, n_leaves, n_slots, f, n_bins, cw, cat_feats)
+    nan_bins = np.where(np.arange(f) % 2 == 0, n_bins - 1, -1).astype(np.int32)
+    if case.startswith("missing_default"):
+        tree = tree._replace(default_left=jnp.full(
+            n_slots - 1, case == "missing_default_left"))
+    if case == "efb_bundles":
+        # features 0..5 over three bundle columns, each feature's range
+        # [off, off + nb - 1) of its column (io/efb.py)
+        efb = (np.array([0, 0, 1, 1, 1, 2]), np.array([1, 40, 1, 30, 60, 1]),
+               np.array([40, 50, 30, 31, 30, 90]))
+        bins = rng.integers(0, 90, (n, 3)).astype(dtype)
+        nan_bins = np.where(np.arange(f) % 2 == 0, efb[2] - 1, -1).astype(np.int32)
+    else:
+        bins = rng.integers(0, n_bins, (n, f)).astype(dtype)
+    got = np.asarray(jax.jit(lambda t, b: predict_leaf_binned(
+        t, b, jnp.asarray(nan_bins), efb=efb))(tree, jnp.asarray(bins)))
+    want = _walk_rows(tree, bins, nan_bins, efb)
+    np.testing.assert_array_equal(got, want)
+    assert len(np.unique(want)) >= min(n_leaves, 5)     # the rows do spread over the tree
+
+
+@pytest.mark.parametrize("grower", ["frontier_k16", "frontier_k1", "serial", "streamed"])
+def test_children_are_numbered_after_their_parent(grower):
+    """What the traversal's one pass over the nodes rests on: node ``j`` is the
+    ``j``-th split, so an internal child's index exceeds its parent's."""
+    import lightgbm_tpu as lgb
+    params = {"frontier_k16": {"tree_grower": "frontier", "frontier_k": 16},
+              "frontier_k1": {"tree_grower": "frontier", "frontier_k": 1},
+              "serial": {"tree_grower": "serial"},
+              "streamed": {"tree_grower": "serial", "stream_rows": 2048}}[grower]
+    rng = np.random.default_rng(3)
+    X = rng.normal(size=(6000, 6))
+    X[:, 0] = rng.integers(0, 40, 6000)
+    y = X[:, 1] * 2 + np.sin(X[:, 2] * 3) + (X[:, 0] % 5 > 2) + 0.1 * rng.normal(size=6000)
+    bst = lgb.train(dict(params, objective="regression", num_leaves=31, max_bin=63,
+                         min_data_in_leaf=5, verbose=-1),
+                    lgb.Dataset(X, label=y, categorical_feature=[0]), 3)
+    if grower == "streamed":
+        from lightgbm_tpu.stream.booster import StreamGBDT
+        assert isinstance(bst._gbdt, StreamGBDT)
+    for tree in bst._gbdt.models:
+        m = tree.num_leaves - 1
+        assert m > 10
+        parents = np.arange(m)
+        for child in (tree.left_child[:m], tree.right_child[:m]):
+            assert np.all((child < 0) | (child > parents))
