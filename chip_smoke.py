@@ -1,8 +1,8 @@
 """chip_smoke.py: the quickest proof that the system still starts on the chip.
 
 Drives the main path once through the public API on ONE TPU process (no
-children, no probe) at the full width of the bench model: synthetic Higgs
-geometry (``bench.make_higgs_like``), 28 columns, ``max_bin=255``,
+children, no probe) at full width: synthetic Higgs geometry
+(``make_higgs_like``), 28 columns, ``max_bin=255``,
 ``num_leaves=255``, ``min_data_in_leaf=100``, binary objective, every speed
 knob at its default (``hist_variant=auto``, ``tree_grower=auto``).  Depth is
 cut to 5 trees; columns, bins and leaves are never cut on the chip.
@@ -43,11 +43,14 @@ switches the device check off.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
 import time
 import traceback
+
+import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -106,6 +109,30 @@ def parse_args(argv):
     return args
 
 
+def make_higgs_like(n_rows: int, n_feat: int = 28, seed: int = 42):
+    """Synthetic stand-in with Higgs geometry (dense floats, ~even classes)."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n_rows, n_feat)).astype(np.float32)
+    # nonlinear signal over a few features so trees have structure to find
+    logit = (1.2 * X[:, 0] - 0.8 * X[:, 1] + X[:, 2] * X[:, 3]
+             + 0.5 * np.sin(3.0 * X[:, 4]) + 0.3 * X[:, 5] ** 2)
+    y = (logit + rng.logistic(size=n_rows) > 0).astype(np.float32)
+    return X, y
+
+
+def auc_of(scores, labels) -> float:
+    """AUC by the package's own metric (the one training gates on)."""
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import Metadata
+    from lightgbm_tpu.metric.base import AUCMetric
+    md = Metadata(len(labels))
+    md.set_field("label", labels)
+    m = AUCMetric(Config())
+    m.init(md, len(labels))
+    (_, v, _), = m.eval(np.asarray(scores, np.float64))
+    return v
+
+
 def versions() -> dict:
     import importlib.metadata as md
 
@@ -136,12 +163,94 @@ def placements(gbdt) -> dict:
 # phases
 # --------------------------------------------------------------------------
 
+def run_kernel_checks(variants=("base",), max_bin=256, n_feat=28, slots=16,
+                      block_rows=512, rows=200_000) -> dict:
+    """The two production Pallas kernels (``_hist_pallas``, the whole-data
+    pass, and ``_hist_leaves_pallas``, the frontier grower's batched-leaf
+    pass) against the EXACT scatter-add at one width, for each one-hot
+    variant named.  Rows are both masked (weight 0) and fractionally
+    weighted, as bagging and GOSS make them.  Defaults are this file's
+    shape: 28 columns, the 256-wide kernel histogram ``max_bin=255`` trains
+    with, 16 leaf slots of 512-row blocks.  The chip runs it as phase 1 and
+    tier-1 runs it in interpret mode (``tests/test_onehot_variants.py``).
+
+    Returns ``{"hist_pallas/<variant>": relerr, "hist_leaves_pallas/<variant>":
+    relerr}``; a kernel that fails to compile or run records ``inf``.  The
+    caller compares against ``HIST_PARITY_TOL``."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.histogram import (_hist_leaves_pallas, _hist_pallas,
+                                            _hist_scatter,
+                                            build_histogram_leaves, fold_hist)
+    rng = np.random.default_rng(3)
+
+    def emit(**kv):
+        log("  " + json.dumps({"stage": "kernel_parity", **kv}))
+
+    def weights(n):
+        keep = rng.uniform(size=n) < 0.8
+        return jnp.asarray(np.where(keep, rng.uniform(0.25, 1.0, size=n),
+                                    0.0).astype(np.float32))
+
+    def relerr(a, b):          # of two pair histograms
+        a, b = fold_hist(a), fold_hist(b)
+        return float(jnp.max(jnp.abs(a - b) / (jnp.abs(b) + 1.0)))
+
+    # whole-data kernel: a row count that is no block multiple (pad path)
+    n = rows
+    bins = jnp.asarray(rng.integers(0, max_bin - 1, size=(n, n_feat),
+                                    dtype=np.uint8))
+    g = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    h = jnp.asarray(rng.uniform(0.1, 1.0, size=n).astype(np.float32))
+    m = weights(n)
+    ref = jax.jit(lambda *x: _hist_scatter(*x, max_bin))(bins, g, h, m)
+
+    # batched-leaf kernel: gathered rows carry 4 trailing packed-gradient
+    # columns the kernel must skip (f_limit); one slot is deliberately left
+    # EMPTY: a slot with no row blocks must come back as zeros (the kernel
+    # zero-inits its whole VMEM-resident accumulator at grid step 0), not
+    # stale HBM
+    nb = 4 * slots
+    c = block_rows * nb
+    comb = jnp.asarray(rng.integers(0, max_bin - 1, size=(c, n_feat + 4),
+                                    dtype=np.uint8))
+    gl = jnp.asarray(rng.normal(size=c).astype(np.float32))
+    hl = jnp.asarray(rng.uniform(0.1, 1.0, size=c).astype(np.float32))
+    ml = weights(c)
+    bl = np.sort(rng.integers(0, slots, size=nb)).astype(np.int32)
+    bl = jnp.asarray(np.where(bl == slots - 2, slots - 1, bl))
+    ref_l = jax.jit(lambda *x: build_histogram_leaves(
+        *x, slots, max_bin, method="scatter", block_rows=block_rows,
+        f_limit=n_feat))(comb, gl, hl, ml, bl)
+
+    cases = (
+        ("hist_pallas",
+         lambda v, *x: _hist_pallas(*x, max_bin, variant=v),
+         (bins, g, h, m), ref),
+        ("hist_leaves_pallas",
+         lambda v, *x: _hist_leaves_pallas(*x, slots, max_bin, block_rows,
+                                           n_feat, variant=v),
+         (comb, gl, hl, ml, bl), ref_l))
+    errs = {}
+    for v in variants:
+        for name, fn, args, want in cases:
+            key = f"{name}/{v}"
+            try:
+                errs[key] = relerr(
+                    jax.jit(functools.partial(fn, v))(*args), want)
+                emit(kernel=key, relerr=errs[key], n_feat=n_feat,
+                     max_bin=max_bin)
+            except Exception as e:     # a lowering crash is a result too
+                errs[key] = float("inf")
+                emit(kernel=key, error=f"{type(e).__name__}: {e}"[:300])
+    return errs
+
+
 def phase_parity(ctx) -> dict:
     from lightgbm_tpu.ops import onehot_variants as ov
     from lightgbm_tpu.ops.histogram import (HIST_PARITY_TOL,
                                             _pallas_interpret_default)
-    sys.path.insert(0, os.path.join(HERE, "scripts"))
-    import bench_dual
 
     on_tpu = ctx["device"]["platform"] == "tpu"
     check(_pallas_interpret_default() is (not on_tpu),
@@ -149,9 +258,8 @@ def phase_parity(ctx) -> dict:
     kernel_bins = MAX_BIN + 1      # the width GBDT._make_grower_cfg picks
     variants = [v for v in ov.AUTO_CANDIDATES
                 if ov.VARIANTS[v].supports(kernel_bins)]
-    errs = bench_dual.run_kernel_checks(
-        lambda **kv: log("  " + json.dumps(kv)), n_feat=N_FEAT,
-        max_bin=kernel_bins, variants=variants,
+    errs = run_kernel_checks(
+        variants, max_bin=kernel_bins, n_feat=N_FEAT,
         rows=3000 if ctx["args"].dry_run else 200_000,
         slots=6 if ctx["args"].dry_run else 16)
     # any of these can be elected, so any of them being wrong fails the run
@@ -165,7 +273,6 @@ def phase_parity(ctx) -> dict:
 def _train(ctx, extra_params: dict, tag: str) -> dict:
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     import lightgbm_tpu as lgb
     from lightgbm_tpu import native
@@ -264,9 +371,6 @@ def phase_train(ctx) -> dict:
 
 
 def _predict(ctx, tag: str) -> dict:
-    import numpy as np
-
-    import bench
     import lightgbm_tpu as lgb
 
     bst = ctx[tag]
@@ -274,7 +378,7 @@ def _predict(ctx, tag: str) -> dict:
     raw = bst.predict(Xv, raw_score=True)
     check(raw.shape == (Xv.shape[0],) and np.all(np.isfinite(raw)),
           f"predict returned shape {raw.shape} or non-finite values")
-    auc = float(bench.auc_of(raw, yv))
+    auc = float(auc_of(raw, yv))
     floor = DRY_RUN_AUC_FLOOR if ctx["args"].dry_run else AUC_FLOOR
     log(f"  {tag}: held-out AUC {auc:.5f} (floor {floor})")
     check(auc > floor, f"held-out AUC {auc:.5f} is not above {floor}")
@@ -305,8 +409,6 @@ def phase_predict(ctx) -> dict:
 
 
 def phase_serve(ctx) -> dict:
-    import numpy as np
-
     from lightgbm_tpu.serve import Predictor, PredictorArtifact
 
     bst = ctx["train"]
@@ -389,7 +491,6 @@ def main(argv=None) -> int:
         sys.exit(f"chip_smoke.py: --devices {args.devices} but jax has "
                  f"{jax.device_count()} {dev.platform} device(s)")
 
-    import bench
     from lightgbm_tpu.utils import compile_cache
 
     os.makedirs(args.out, exist_ok=True)
@@ -401,8 +502,8 @@ def main(argv=None) -> int:
         f": {cache['entries_before']} entries")
 
     ctx = {"args": args, "device": device, "results": {},
-           "train_xy": bench.make_higgs_like(args.rows),
-           "valid_xy": bench.make_higgs_like(args.valid_rows, seed=43)}
+           "train_xy": make_higgs_like(args.rows),
+           "valid_xy": make_higgs_like(args.valid_rows, seed=43)}
     plan = [("parity", phase_parity), ("train", phase_train),
             ("predict", phase_predict), ("serve", phase_serve)]
     if args.devices > 1:

@@ -163,8 +163,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     if not argv:
         print("usage: python -m lightgbm_tpu config=<file> [key=value ...]\n"
               "       python -m lightgbm_tpu obs-report [--format md|json] "
-              "[--roofline] [--regressions [--gate]] "
-              "[--health [--health-url HOST:PORT]]")
+              "[--roofline] [--health [--health-url HOST:PORT]]")
         return 1
     try:
         Application(parse_argv(argv)).run()

@@ -140,8 +140,8 @@ _OBJECTIVE_ALIASES = {
 
 TASK_TYPES = ("train", "predict", "convert_model", "refit")
 
-# canonical serving bucket defaults (the serve subsystem and bench_serve
-# source this ONE definition; retune here after hardware measurements)
+# canonical serving bucket defaults (the serve subsystem sources this ONE
+# definition; retune here after hardware measurements)
 SERVE_DEFAULT_BUCKETS = (1024, 16384, 262144)
 BOOSTING_TYPES = ("gbdt", "rf", "dart", "goss")
 TREE_LEARNER_TYPES = ("serial", "feature", "data", "voting")
@@ -361,8 +361,8 @@ class Config:
     # -- observability (lightgbm_tpu/obs, docs/OBSERVABILITY.md) --
     # master switch for training-loop telemetry: per-iteration structured
     # events, phase-seconds metrics and tracer spans.  Off = zero cost
-    # beyond one attribute check per iteration (the <2% overhead budget
-    # is measured by scripts/bench_obs_overhead.py)
+    # beyond one attribute check per iteration (what the tracing costs on
+    # the chip is in PERF.md section 6, PR 26)
     obs_telemetry: bool = False
     # event-sink override; "" = the shared journal (WATCHER_PERF_LOG env
     # var, else the repo-root perf_results.jsonl)
@@ -371,8 +371,8 @@ class Config:
     obs_reservoir_size: int = 512
     # live health plane (obs/health.py): serve /metrics (Prometheus text)
     # and /healthz (JSON) from a background thread on 127.0.0.1:<port>.
-    # 0 = off; the LGBM_OBS_HEALTH_PORT env var (exported by the watcher
-    # to its stages) enables it too
+    # 0 = off; the LGBM_OBS_HEALTH_PORT env var (exported by a parent
+    # process to its children) enables it too
     obs_health_port: int = 0
     # numeric divergence sentinels: every this many boosting rounds sample
     # device-side isfinite/max-abs reductions over gradients, hessians and

@@ -69,14 +69,14 @@ class GBDT:
         self._obs = TrainTelemetry(config) if config.obs_telemetry else None
         # live health plane: numeric sentinels every N rounds + the
         # /metrics //healthz exposition server (obs_health_port or the
-        # LGBM_OBS_HEALTH_PORT env var the watcher exports to stages)
+        # LGBM_OBS_HEALTH_PORT env var a parent process exports)
         self._health_every = int(
             getattr(config, "obs_health_check_iters", 0) or 0)
         server = obs_health.maybe_start(
             getattr(config, "obs_health_port", 0))
         self._health_enabled = bool(server is not None or self._health_every)
         if self._health_enabled and os.environ.get("LGBM_FLIGHT_DIR"):
-            # supervised stage (run_stage exports the dir): arm the flight
+            # a parent process named a directory for the dump: arm the flight
             # recorder so a divergence or kill leaves forensics even when
             # obs_telemetry is off
             from ..obs import flight as obs_flight

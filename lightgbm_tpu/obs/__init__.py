@@ -1,8 +1,7 @@
 """Unified telemetry: structured events, metrics, tracing, reporting.
 
-The observability subsystem (docs/OBSERVABILITY.md).  Four layers, all
-stdlib-only so the supervising processes (watcher, perf suite) can load
-them without importing jax:
+The observability subsystem (docs/OBSERVABILITY.md).  Its layers, all
+stdlib-only at import:
 
 - :mod:`.events` — versioned structured-event schema + the thread-safe
   jsonl :class:`~.events.EventLog` behind ``perf_results.jsonl``;
@@ -24,7 +23,7 @@ from __future__ import annotations
 import os
 from typing import Any, Dict, List, Optional
 
-from . import costs, flight, health, regress, scopes
+from . import costs, flight, health, scopes
 from .costs import CostLedger, get_ledger
 from .events import (EventLog, SCHEMA_VERSION, classify_record, make_event,
                      new_run_id, perf_log_path, validate_event)
@@ -40,7 +39,7 @@ __all__ = ["EventLog", "SCHEMA_VERSION", "classify_record", "make_event",
            "Counter", "Gauge", "Histogram", "MetricsRegistry",
            "get_registry", "Span", "Tracer", "get_tracer",
            "install_compile_listener", "device_scopes",
-           "costs", "regress", "scopes", "CostLedger", "get_ledger",
+           "costs", "scopes", "CostLedger", "get_ledger",
            "flight", "health", "FlightRecorder", "DivergenceError",
            "SLOMonitor", "TrainTelemetry"]
 

@@ -1,15 +1,13 @@
 """XLA cost ledger + roofline/MFU accounting (device-truth attribution).
 
-One audited peak table and one cost model for the whole repo: every MFU
-or peak-rate figure printed anywhere (bench.py, scripts/tpu_perf_suite.py,
-scripts/bench_onehot_variants.py, obs-report) must come through here —
-``tests/test_obs.py`` greps the tree to enforce it.  Before this module
-three hand-rolled formulas with three local peak tables disagreed about
-what "12% MFU" meant; now XLA's own compiled-program cost model is the
-source of truth and the analytic work models are labelled predictions.
+One audited peak table and one cost model for the package: every MFU or
+peak-rate figure the package prints (obs-report, the serve and training
+cost events) comes through here, and ``tests/test_obs.py`` greps the tree
+to enforce it (the benchmark keeps its own, ``benchmarks/peaks.json``).
+XLA's own compiled-program cost model is the source of truth and the
+analytic work models are labelled predictions.
 
-Stdlib-only at import (the watcher/suite load ``obs`` jax-free via
-``bench.load_obs()``): jax is imported lazily inside the few functions
+Stdlib-only at import: jax is imported lazily inside the few functions
 that touch a device, and the :class:`CostLedger` duck-types the
 ``Compiled`` objects callers hand it.
 
@@ -253,11 +251,11 @@ class CostLedger:
     # ------------------------------------------------------------------
     def record(self, name: str, compiled: Any = None, *,
                chip: Optional[str] = None, model_flops: Optional[float] = None,
-               predicted_mfu: Optional[float] = None, **meta: Any) -> Dict:
+               **meta: Any) -> Dict:
         """Register/refresh a program.  ``compiled`` is any object with
         ``cost_analysis``/``memory_analysis`` (jax ``Compiled``); pass
         ``model_flops`` for an analytic work model to report alongside
-        XLA's count, ``predicted_mfu`` for a work-model MFU bound."""
+        XLA's count."""
         # the device kind is stored as found; pricing it against the peak
         # table (rooflines) is where an unknown kind raises
         ent: Dict[str, Any] = {"program": name,
@@ -267,8 +265,6 @@ class CostLedger:
             ent["memory"] = _memory_dict(compiled)
         if model_flops is not None:
             ent["model_flops"] = float(model_flops)
-        if predicted_mfu is not None:
-            ent["predicted_mfu"] = float(predicted_mfu)
         if meta:
             ent["meta"] = {k: v for k, v in meta.items()}
         with self._lock:
@@ -312,7 +308,7 @@ class CostLedger:
             rec.update(program=ent["program"], calls=calls,
                        seconds_per_call=secs / calls,
                        flops_source=("xla" if "flops" in cost else "model"))
-            for k in ("model_flops", "predicted_mfu", "memory", "meta"):
+            for k in ("model_flops", "memory", "meta"):
                 if k in ent:
                     rec[k] = ent[k]
             if "model_flops" in ent:

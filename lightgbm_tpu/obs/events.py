@@ -1,12 +1,12 @@
 """Structured run events: one schema, one writer, one results file.
 
-Every measurement in the repo lands in ``perf_results.jsonl`` (or the
-file ``WATCHER_PERF_LOG`` points at).  Historically each bench script
-carried its own copy of the path resolution and a bare ``json.dumps``
-append; this module is the single replacement:
+Every event the package emits lands in ``perf_results.jsonl`` (or the
+file ``WATCHER_PERF_LOG`` points at; the file is a run's output and is
+not committed).  The benchmark's numbers do not: ``benchmarks/run.py``
+prints its own result line and the driver keeps ``PERF_LEDGER.jsonl``.
 
 - :func:`perf_log_path` — the one copy of the ``WATCHER_PERF_LOG``-or-
-  repo-root resolution previously duplicated across six scripts;
+  repo-root resolution;
 - :class:`EventLog` — a thread-safe, atomic-append jsonl sink stamping
   every record with the versioned envelope (``schema_version``,
   ``run_id``, wall clock ``ts``, monotonic clock ``mono``, ``event``);
@@ -14,13 +14,11 @@ append; this module is the single replacement:
   validator the report layer uses to tolerate legacy (pre-schema) lines.
 
 Compatibility: the envelope keeps a ``stage`` field mirroring ``event``
-(unless the caller sets its own) because the perf-suite resume markers
-and the watcher journal key on ``stage`` — old readers keep working on
-new lines, and the report reader accepts old lines.
+(unless the caller sets its own) because older journals key on ``stage``
+— old readers keep working on new lines, and the report reader accepts
+old lines.
 
-This module is deliberately stdlib-only: the watcher/suite supervisors
-must be able to load it WITHOUT importing the ``lightgbm_tpu`` package
-(whose ``__init__`` pulls in jax — see ``bench.load_obs``).
+This module is deliberately stdlib-only.
 """
 from __future__ import annotations
 
@@ -38,7 +36,7 @@ SCHEMA_VERSION = 1
 #: envelope fields every schema event carries
 REQUIRED_FIELDS = ("schema_version", "run_id", "event", "ts", "mono")
 
-#: the event kind marking a bench script's final one-JSON-line summary
+#: the event kind of :meth:`EventLog.summary`, a run's closing record
 SUMMARY_EVENT = "bench_summary"
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(
@@ -46,8 +44,8 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(
 
 
 def perf_log_path(env: Optional[Dict[str, str]] = None) -> str:
-    """The results file: ``WATCHER_PERF_LOG`` when the watcher points every
-    stage at one journal, else the repo-root ``perf_results.jsonl``."""
+    """The results file: ``WATCHER_PERF_LOG`` when the caller points every
+    process at one journal, else the repo-root ``perf_results.jsonl``."""
     env = os.environ if env is None else env
     return env.get("WATCHER_PERF_LOG") or os.path.join(
         _REPO_ROOT, "perf_results.jsonl")
@@ -67,8 +65,8 @@ def make_event(event: str, run_id: str, **fields: Any) -> Dict[str, Any]:
     rec["event"] = str(event)
     rec["ts"] = time.time()
     rec["mono"] = time.monotonic()
-    # legacy-reader compat: suite resume markers / watcher records key on
-    # "stage"; mirror the kind unless the caller carries its own stage
+    # legacy-reader compat: older journals key on "stage"; mirror the kind
+    # unless the caller carries its own stage
     rec.setdefault("stage", rec["event"])
     return rec
 
@@ -118,11 +116,10 @@ class EventLog:
 
     Each record is serialized to one line and written with a single
     ``write`` call on a file opened in append mode, so concurrent writers
-    (serve worker threads, the watcher's stage subprocesses sharing
-    ``WATCHER_PERF_LOG``) interleave whole lines, never fragments.
+    (serve worker threads, processes sharing ``WATCHER_PERF_LOG``)
+    interleave whole lines, never fragments.
 
-    ``echo=True`` also prints each line to stdout (the bench scripts'
-    historical behavior — the suite/watcher scrape stdout for progress).
+    ``echo=True`` also prints each line to stdout.
     """
 
     _defaults: Dict[str, "EventLog"] = {}
@@ -169,8 +166,7 @@ class EventLog:
     def default(cls, *, echo: bool = False) -> "EventLog":
         """Process-wide log for the resolved :func:`perf_log_path` (one
         ``run_id`` per process per path).  ``echo=True`` upgrades an
-        existing silent default — bench mains want echo, library callers
-        don't care."""
+        existing silent default."""
         path = perf_log_path()
         with cls._defaults_lock:
             log = cls._defaults.get(path)
@@ -188,22 +184,14 @@ class EventLog:
         self._write(rec)
         return rec
 
-    def emit_raw(self, rec: Dict[str, Any]) -> Dict[str, Any]:
-        """Append a caller-built record verbatim (no envelope) — for
-        relaying already-stamped records (e.g. the watcher forwarding a
-        stage's summary)."""
-        self._write(rec)
-        return rec
-
     def summary(self, **fields: Any) -> Dict[str, Any]:
-        """Emit a bench script's final summary: appended to the log AND
-        printed as the last stdout line (the one-JSON-line contract,
-        ``supervise.extract_json_line``).  Validates before writing so a
-        malformed summary fails the bench loudly, not the reader later.
+        """Emit a run's final summary: appended to the log AND printed as
+        the last stdout line.  Validates before writing so a malformed
+        summary fails the writer loudly, not the reader later.
 
         Surfaces the tracer's silent data loss: when the process tracer has
         dropped spans (ring overflow) the summary carries a
-        ``tracer_dropped`` count so no bench can claim complete span
+        ``tracer_dropped`` count so no run can claim complete span
         coverage it doesn't have."""
         if "tracer_dropped" not in fields:
             try:  # lazy: keep module import order free of cycles
@@ -216,7 +204,7 @@ class EventLog:
         rec = make_event(SUMMARY_EVENT, self.run_id, **fields)
         errs = validate_event(rec)
         if errs:
-            raise ValueError(f"invalid bench summary: {'; '.join(errs)}")
+            raise ValueError(f"invalid summary: {'; '.join(errs)}")
         line = json.dumps(rec)
         with self._lock:
             self._append_line(line)

@@ -1,8 +1,8 @@
 """Flight recorder: crash-proof forensics for live runs.
 
 Post-hoc telemetry (``events.py`` journals) answers "what happened" only
-when the process got to write it.  A stage that is SIGKILLed by the
-watcher's hang reaper, segfaults inside jaxlib, or dies to an unhandled
+when the process got to write it.  A process that is SIGKILLed by
+whatever supervises it, segfaults inside jaxlib, or dies to an unhandled
 exception leaves an exit code and a truncated journal.  This module
 keeps a bounded in-memory ring of the last N schema events (tapped off
 :class:`~lightgbm_tpu.obs.events.EventLog` via its observer hook) plus
@@ -23,13 +23,11 @@ completed-span tail and every thread's still-open spans (``open: true``
 with the span's age).
 
 Destination precedence: the ``LGBM_FLIGHT_DIR`` environment variable
-(how ``supervise.run_stage`` redirects a child's dump into a collectible
+(how a parent process redirects a child's dump into a collectible
 location) beats the ``dir`` argument beats the directory of
 :func:`~lightgbm_tpu.obs.events.perf_log_path`.
 
-Deliberately stdlib-only and importable via the jax-free
-``bench.load_obs()`` path — the watcher's fake stages exercise it
-without numpy in the interpreter.
+Deliberately stdlib-only.
 """
 from __future__ import annotations
 
@@ -48,7 +46,7 @@ from .events import EventLog, make_event, new_run_id, perf_log_path
 __all__ = ["FlightRecorder", "install", "get_recorder", "uninstall",
            "dump", "FATAL_SIGNALS"]
 
-#: prefix of every dump file (``supervise.run_stage`` globs on it)
+#: prefix of every dump file
 FLIGHT_PREFIX = "flight_"
 
 #: termination/fatal signals the recorder dumps on.  SIGINT is left
@@ -240,8 +238,8 @@ class FlightRecorder:
         except Exception:
             pass
         # restore the previous disposition and re-raise: the process dies
-        # with the status the signal implies (watcher reaping semantics,
-        # shell wait status) instead of a handler swallowing it
+        # with the status the signal implies (the shell's wait status)
+        # instead of a handler swallowing it
         prev = self._prev_handlers.get(signum)
         try:
             signal.signal(signum, prev if prev is not None

@@ -9,8 +9,8 @@ running:
   rendered from the process :class:`~lightgbm_tpu.obs.metrics
   .MetricsRegistry`) and ``GET /healthz`` (the JSON of
   :func:`health_snapshot`).  Enabled by the ``obs_health_port`` config
-  knob (or the ``LGBM_OBS_HEALTH_PORT`` env var the watcher exports to
-  its stages); auto-started by the boosting loops and
+  knob (or the ``LGBM_OBS_HEALTH_PORT`` env var a parent process exports
+  to its children); auto-started by the boosting loops and
   ``serve.Predictor``.  ``port=0`` binds an ephemeral port (tests).
 - :func:`set_status` — a tiny process-wide status board (run_id, stage,
   iteration, last numeric check …) the training loops update per
@@ -26,9 +26,8 @@ running:
   when gradients/hessians/leaf values go NaN/Inf, carrying the stats and
   the flight-dump path.
 
-Deliberately stdlib-only (loadable via the jax-free ``bench.load_obs()``
-path) — the device-side reductions live in the model layer; this module
-only judges their host-side scalars.
+Deliberately stdlib-only — the device-side reductions live in the model
+layer; this module only judges their host-side scalars.
 """
 from __future__ import annotations
 
@@ -470,8 +469,8 @@ def start_health_server(port: int) -> Optional[HealthServer]:
 
 def maybe_start(port: Optional[int] = None) -> Optional[HealthServer]:
     """Start the server when enabled: explicit ``port`` (config knob)
-    wins, else the ``LGBM_OBS_HEALTH_PORT`` env var (how the watcher
-    arms its stage subprocesses).  ``None``/unset → no server."""
+    wins, else the ``LGBM_OBS_HEALTH_PORT`` env var (how a parent process
+    arms its children).  ``None``/unset → no server."""
     if port is None or int(port) <= 0:
         env = os.environ.get("LGBM_OBS_HEALTH_PORT", "")
         try:

@@ -1,10 +1,9 @@
 """Perf-trajectory report: render the results journal + a metrics snapshot.
 
-``python -m lightgbm_tpu obs-report`` (and the watcher, after each TPU
-window) reads ``perf_results.jsonl`` — schema events and legacy
-pre-schema lines alike — and renders a markdown or JSON report: record
-counts by kind, the headline bench summaries over time, watcher windows,
-and the process's live metrics snapshot when one exists.
+``python -m lightgbm_tpu obs-report`` reads ``perf_results.jsonl`` —
+schema events and legacy pre-schema lines alike — and renders a markdown
+or JSON report: record counts by kind, the summary records over time, and
+the process's live metrics snapshot when one exists.
 
 Legacy tolerance is the point: the journal predates the schema by many
 sessions, so the loader classifies every line via ``events.classify_record``
@@ -18,12 +17,10 @@ import sys
 from typing import Any, Dict, List, Optional
 
 from . import costs as _costs
-from . import regress as _regress
 from .events import classify_record, perf_log_path
 
 __all__ = ["load_perf_log", "summarize", "render_markdown", "render_json",
-           "roofline_rows", "render_roofline", "render_regressions",
-           "render_health", "main"]
+           "roofline_rows", "render_roofline", "render_health", "main"]
 
 
 def load_perf_log(path: Optional[str] = None) -> Dict[str, Any]:
@@ -78,8 +75,6 @@ def summarize(loaded: Dict[str, Any],
             ts_min = ts if ts_min is None else min(ts_min, ts)
             ts_max = ts if ts_max is None else max(ts_max, ts)
     summaries = [r for r in records if _is_summary(r)]
-    windows = [r for r in records
-               if _stage_of(r).startswith("watcher_window")]
     run_ids = sorted({r["run_id"] for r in loaded["events"]})
     return {
         "path": loaded["path"],
@@ -92,7 +87,6 @@ def summarize(loaded: Dict[str, Any],
         "by_stage": dict(sorted(by_stage.items(),
                                 key=lambda kv: (-kv[1], kv[0]))),
         "recent_summaries": summaries[-last_n:],
-        "windows": windows[-last_n:],
         "metrics": metrics_snapshot or {},
         "tracer": tracer_info or {},
     }
@@ -138,16 +132,6 @@ def render_markdown(summary: Dict[str, Any]) -> str:
                   "| metric | value | unit | backend |", "|---|---|---|---|"]
         for rec in summary["recent_summaries"]:
             lines.append(_fmt_summary_row(rec))
-    if summary["windows"]:
-        lines += ["", "## Watcher windows", ""]
-        for rec in summary["windows"]:
-            wid = rec.get("window_id", "?")
-            lines.append(f"- window `{wid}`: "
-                         + ", ".join(f"{k}={v}" for k, v in rec.items()
-                                     if k not in ("stage", "event", "ts",
-                                                  "mono", "run_id",
-                                                  "schema_version",
-                                                  "window_id")))
     if summary["metrics"]:
         lines += ["", "## Telemetry snapshot", "",
                   "| metric | value |", "|---|---|"]
@@ -206,49 +190,10 @@ def render_roofline(rows: List[Dict[str, Any]]) -> str:
                              _num(r.get("seconds_per_call"), 1e3),
                              _num(r.get("achieved_flops_per_sec"), 1e-9),
                              _num(r.get("mfu"), fmt="{:.4f}"),
-                             _num(r.get("model_mfu"),
-                                  fmt="{:.4f}") or
-                             _num(r.get("predicted_mfu"), fmt="{:.4f}"),
+                             _num(r.get("model_mfu"), fmt="{:.4f}"),
                              _num(r.get("achieved_bytes_per_sec"), 1e-9),
                              _num(r.get("intensity")),
                              r.get("bound", "")))
-    lines.append("")
-    return "\n".join(lines)
-
-
-# --------------------------------------------------------------------------
-# --regressions: sentinel verdicts over journal + BENCH_r* history
-# --------------------------------------------------------------------------
-
-def render_regressions(result: Dict[str, Any], gate: bool = False) -> str:
-    counts = result["counts"]
-    lines = ["## Perf-regression sentinel", "",
-             "- verdicts: " + (", ".join(f"{k}: **{v}**" for k, v in
-                                         sorted(counts.items())) or "none"),
-             f"- gate: {'**REGRESSED**' if result['regressed'] else 'clean'}"
-             + (" (exit nonzero)" if gate and result["regressed"] else ""),
-             ""]
-    shown = [v for v in result["verdicts"] if v["verdict"] != "no-baseline"]
-    hidden = len(result["verdicts"]) - len(shown)
-    if shown:
-        lines += ["| metric | field | backend | shape | verdict | "
-                  "baseline | latest | Δ% | n |",
-                  "|---|---|---|---|---|---|---|---|---|"]
-        for v in shown:
-            verdict = v["verdict"] + (f" ({v['severity']})"
-                                      if v.get("severity") else "")
-            lines.append("| {} | {} | {} | {} | {} | {} | {} | {} | {} |"
-                         .format(v["metric"], v["field"], v["backend"],
-                                 v["shape"] or "-", verdict,
-                                 _num(v.get("baseline_median")),
-                                 _num(v.get("latest")),
-                                 _num(v.get("rel_change"), 100.0,
-                                      "{:+.1f}"),
-                                 v["n_baseline"]))
-    if hidden:
-        lines.append(f"\n_{hidden} series below the "
-                     f"{_regress.MIN_BASELINE}-sample baseline floor "
-                     "(no-baseline)._")
     lines.append("")
     return "\n".join(lines)
 
@@ -347,39 +292,23 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="omit the in-process metrics snapshot")
     ap.add_argument("--roofline", action="store_true",
                     help="render only the cost-ledger roofline/MFU rows")
-    ap.add_argument("--regressions", action="store_true",
-                    help="render only the perf-regression sentinel verdicts")
     ap.add_argument("--health", action="store_true",
                     help="render only the runtime-health section (status "
                          "board, sentinels, SLO burn rates, flight state)")
     ap.add_argument("--health-url", default=None, metavar="HOST:PORT",
                     help="with --health: fetch /healthz from a live "
                          "process instead of this process's snapshot")
-    ap.add_argument("--gate", action="store_true",
-                    help="with --regressions: exit nonzero on any "
-                         "regressed verdict")
-    ap.add_argument("--bench-glob", default=None,
-                    help="history round files for the sentinel "
-                         "(default: BENCH_r*.json beside the journal)")
     args = ap.parse_args(argv)
 
-    rc = 0
     loaded = load_perf_log(args.path)
-    if args.roofline or args.regressions or args.health:
-        # focused sections (CLI/gate mode): no base report around them
+    if args.roofline or args.health:
+        # focused sections: no base report around them
         parts = []
         payload: Dict[str, Any] = {}
         if args.roofline:
             rows = roofline_rows(loaded, ledger=_costs.get_ledger())
             parts.append(render_roofline(rows))
             payload["roofline"] = rows
-        if args.regressions:
-            res = _regress.scan(journal_path=loaded["path"],
-                                bench_glob=args.bench_glob)
-            parts.append(render_regressions(res, gate=args.gate))
-            payload["regressions"] = res
-            if args.gate and res["regressed"]:
-                rc = 1
         if args.health:
             try:
                 hdata = _health_data(args.health_url)
@@ -415,7 +344,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             f.write(text)
     else:
         sys.stdout.write(text)
-    return rc
+    return 0
 
 
 if __name__ == "__main__":

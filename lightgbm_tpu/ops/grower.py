@@ -307,9 +307,9 @@ def _frontier_eligible(cfg: "GrowerConfig", n_cols: int, interaction_sets,
         # the batched-leaf kernel's bins block spans all features at once
         # (single feature block); very wide feature sets exceed its lane
         # budget
-        from .histogram import _PALLAS_ROWMAJOR_MAX_LANES
+        from .histogram import _PALLAS_LEAVES_MAX_LANES
         bb = cfg.bundle_bins or cfg.max_bin
-        ok = n_cols * (-(-bb // 128) * 128) <= _PALLAS_ROWMAJOR_MAX_LANES
+        ok = n_cols * (-(-bb // 128) * 128) <= _PALLAS_LEAVES_MAX_LANES
     if not ok and cfg.grower_mode == "frontier":
         from ..utils.log import Log
         Log.warning("tree_grower=frontier is not compatible with the "

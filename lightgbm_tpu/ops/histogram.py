@@ -38,15 +38,14 @@ from .onehot_variants import accumulate_block, two_sum
 # scatter-add (or the true-f32 XLA one-hot): the kernels accumulate a bf16
 # (hi, lo) split-precision pair — or the int8 variant's multi-level
 # quantized pair — whose lo-residual rounding is ~2^-18 per row; summed over ~N/B rows
-# per bin this measures 1.2e-4 at 200k rows on v5e
-# (scripts/debug_bf16_fence2.py).  5e-4 gives shape headroom while still
+# per bin this measures 1.2e-4 at 200k rows on v5e.  5e-4 gives shape
+# headroom while still
 # rejecting bare-bf16 accumulation by >200x (the lo-collapse bug class
 # measures ~1e-1 against a true-f32 reference).  The reference side MUST be
 # true f32: _hist_onehot pins precision=HIGHEST internally — at DEFAULT TPU
 # matmul precision it is itself bf16-grade (relerr 0.13 vs the exact
 # scatter-add), which once masked that very bug.  Import this constant
-# everywhere a kernel parity check lives (scripts/bench_dual.py,
-# scripts/bench_onehot_variants.py, tests/test_dual.py,
+# everywhere a kernel parity check lives (chip_smoke.py, tests/test_dual.py,
 # tests/test_onehot_variants.py) — a tolerance re-derived in one place and
 # drifted in another is how the round-4 incident stayed hidden.
 HIST_PARITY_TOL = 5e-4
@@ -281,8 +280,8 @@ def _split_bf16_pair(gh: jax.Array) -> jax.Array:
     XLA's excess-precision simplification rewrites ``f32(bf16(x))`` back to
     ``x`` (allowed by ``xla_allow_excess_precision``, default on), which
     collapses ``lo`` to exactly zero and silently degrades every histogram
-    to bare-bf16 accuracy (relerr ~1e-2 — caught on v5e hardware by
-    ``scripts/bench_dual.py``'s batched-leaf parity gate, round 4; the
+    to bare-bf16 accuracy (relerr ~1e-2 — caught on v5e hardware by the
+    batched-leaf parity check ``chip_smoke.py`` now runs, round 4; the
     repro is ``lo == 0`` in-jit but not eagerly)."""
     hi = jax.lax.optimization_barrier(gh.astype(jnp.bfloat16))
     lo = (gh - hi.astype(jnp.float32)).astype(jnp.bfloat16)
@@ -325,7 +324,7 @@ def build_histogram_leaves(comb: jax.Array, grad: jax.Array, hess: jax.Array,
     n, nc = comb.shape
     f = min(f_limit, nc) if f_limit is not None else nc
     _lanes = f * (-(-max_bin // 128) * 128)
-    if method == "pallas" and _lanes <= _PALLAS_ROWMAJOR_MAX_LANES \
+    if method == "pallas" and _lanes <= _PALLAS_LEAVES_MAX_LANES \
             and num_slots * 6 * _lanes * 4 <= _PALLAS_LEAFACC_BYTES:
         return _hist_leaves_pallas(comb, grad, hess, mask, block_leaf,
                                    num_slots, max_bin, block_rows, f,
@@ -377,11 +376,9 @@ def _hist_leaves_pallas(comb, grad, hess, mask, block_leaf, num_slots,
     f_pad, lanes = feat_geometry(spec, f, B, Bp)   # lane-pack group align
 
     rows = spec.prep(grad, hess, mask)                        # [R, C]
-    # transpose ONCE in XLA (a fixed ~0.7ms u8 relayout), NOT per block in
-    # the kernel: an in-kernel [BR, f].T benched ~35x slower over a full
-    # pass on v5e — Mosaic lowers the small-tile transpose to lane/sublane
-    # shuffles that dominate the whole kernel (measured 128ms vs 3.7ms at
-    # 1M x 28 x 255, scripts/tpu_perf_suite.py round 4)
+    # transpose ONCE in XLA (one u8 relayout), NOT per block in the kernel:
+    # Mosaic lowers an in-kernel small-tile [BR, f].T to lane/sublane
+    # shuffles that dominate the whole kernel
     comb_t = comb[:, :f].T                                        # [f, C] u8
     if f_pad > f:
         # padded features histogram real rows at bin 0 of their own lane
@@ -399,8 +396,7 @@ def _hist_leaves_pallas(comb, grad, hess, mask, block_leaf, num_slots,
     # whose 6-sublane slot slabs are not (8,128)-tile aligned, each dropped
     # the lo-half bf16-residual contributions for some block_leaf patterns:
     # relerr ~1.8e-2 vs the ~3e-5 this split-precision design gives —
-    # caught twice by scripts/bench_dual.py's hardware parity gate, round
-    # 4).  A block's slot is copied to a scratch and back under scalar
+    # caught twice by the hardware parity check, round 4).  A block's slot is copied to a scratch and back under scalar
     # predicates, one statically indexed copy a slot, and the compensated
     # add is written once: sixteen copies of it made the Mosaic program (and
     # the host's time to load it) several times the size.  A block touches
@@ -481,11 +477,10 @@ _PALLAS_BLOCK_LANES = 2048
 _PALLAS_ONEHOT_BYTES = 8 * 1024 * 1024
 
 
-# cap on single-feature-block kernels (the opt-in rowmajor layout and the
-# batched-leaf kernel, whose bins block spans all f at once) so that the
-# 128-row BR floor never busts _PALLAS_ONEHOT_BYTES:
-# f*Bp*128 bf16 <= 8MiB  =>  f*Bp <= 32768
-_PALLAS_ROWMAJOR_MAX_LANES = 32768
+# cap on the batched-leaf kernel, whose bins block spans all f at once (a
+# single feature block), so that the 128-row BR floor never busts
+# _PALLAS_ONEHOT_BYTES: f*Bp*128 bf16 <= 8MiB  =>  f*Bp <= 32768
+_PALLAS_LEAVES_MAX_LANES = 32768
 
 # the batched-leaf kernel keeps its whole [num_slots, 6, f*Bp] f32
 # accumulator VMEM-resident for the full grid.  This cap was written against
@@ -496,8 +491,7 @@ _PALLAS_LEAFACC_BYTES = 48 * 1024 * 1024
 
 
 def _hist_pallas(bins, grad, hess, mask, max_bin, block_rows=None,
-                 f_limit=None, layout="featmajor", variant="base",
-                 interpret=None):
+                 f_limit=None, variant="base", interpret=None):
     """Fused histogram: Pallas TPU kernel, bf16 split-precision one-hot matmul.
 
     TPUs have no fast scatter atomics, so the scatter-add is a one-hot matmul
@@ -508,23 +502,16 @@ def _hist_pallas(bins, grad, hess, mask, max_bin, block_rows=None,
       lo = bf16(x - hi), giving ~16 mantissa bits across the pair.  The six
       rows (g_hi, h_hi, m_hi, g_lo, h_lo, m_lo) ride the SAME matmul (M <= 8
       sublanes is free) with f32 accumulation, so the whole histogram runs at
-      the MXU's bf16 rate — ~4x the f32 rate — with ~1e-5 relative error.
-    The default layout is **feature-major blocked**: bins are transposed
-    ONCE in XLA to ``[f_pad, Npad]`` (a fixed ~0.7 ms u8 relayout at the
-    bench shape) and the block is ``(FC, BR)`` — FC on sublanes
-    (8-aligned), BR on lanes (128-aligned) — with grid (feature_blocks,
-    row_blocks), rows minor, so each [6, FC*Bp] output block accumulates
-    in VMEM while the one-hot only ever exists as a [FC*Bp, BR] tile.
-
-    A **row-major** variant (``layout='rowmajor'``, needs ``f*Bp <= 32k``
-    lanes) feeds the dataset layout straight in as ``(BR, f)`` blocks and
-    transposes each tile INSIDE the kernel.  It exists to amortize the
-    fixed external-transpose latency over small per-leaf segments, but on
-    real v5e the in-kernel small-tile transpose lowers to lane/sublane
-    shuffles that cost ~35x the whole feature-major pass at the bench
-    shape (128 ms vs 3.7 ms at 1M x 28 x 255, round-4
-    ``scripts/tpu_perf_suite.py``), so it is opt-in for benchmarking
-    only, never picked automatically.
+      the MXU's bf16 rate with ~1e-5 relative error.
+    The layout is **feature-major blocked**: bins are transposed ONCE in
+    XLA to ``[f_pad, Npad]`` (one u8 relayout) and the block is
+    ``(FC, BR)`` — FC on sublanes (8-aligned), BR on lanes (128-aligned) —
+    with grid (feature_blocks, row_blocks), rows minor, so each [6, FC*Bp]
+    output block accumulates in VMEM while the one-hot only ever exists as
+    a [FC*Bp, BR] tile.  (Feeding ``(BR, f)`` row-major blocks and
+    transposing each tile inside the kernel lowers to lane/sublane shuffles
+    that dominate the kernel on v5e; what the ledger holds of the kernels'
+    speed is in ``PERF.md`` section 5.)
 
     The one-hot build + dot bodies live in the variant registry
     (``ops/onehot_variants.py``) — ``variant`` selects the build strategy
@@ -553,100 +540,45 @@ def _hist_pallas(bins, grad, hess, mask, max_bin, block_rows=None,
     if interpret is None:
         interpret = _pallas_interpret_default()
 
-    if layout not in ("featmajor", "rowmajor"):
-        raise ValueError(f"unknown histogram layout {layout!r}")
-    if layout == "rowmajor" and f * Bp > _PALLAS_ROWMAJOR_MAX_LANES:
-        raise ValueError(
-            f"layout='rowmajor' needs f*Bp <= {_PALLAS_ROWMAJOR_MAX_LANES} "
-            f"lanes (got {f * Bp}); the benchmark comparison would silently "
-            "run the featmajor kernel instead")
     rows = spec.prep(grad, hess, mask)           # [R, N]: bf16 pair or f32
 
-    if layout == "rowmajor":
-        # ---- row-major path: one feature block spans all features ----------
-        if f % gf:
-            raise ValueError(
-                f"layout='rowmajor' with variant {variant!r} needs the "
-                f"feature count to be a multiple of {gf} (got {f})")
-        f_pad = f
-        lanes = f_pad * lpf
-        # BR is the bins block's sublane dim AND the gh block's lane dim, so
-        # it must be a 128-multiple
-        br_cap = max(128, (_PALLAS_ONEHOT_BYTES // (2 * f_pad * lpf)) // 128 * 128)
-        BR = max(128, min(block_rows or _PALLAS_BLOCK_ROWS, br_cap,
-                          -(-n // 128) * 128))
-        pad = (-n) % BR
-        if pad:
-            bins = jnp.pad(bins, ((0, pad), (0, 0)))
-            rows = jnp.pad(rows, ((0, 0), (0, pad)))
-            # padded rows carry zero weight in every channel
-        n_rb = (n + pad) // BR
+    if f < f_cols:
+        bins = bins[:, :f]                       # drop packed-gradient cols
+    # features per block: 8-sublane floor, lane-pack group multiple
+    align = max(8, gf)
+    FC = max(align, (_PALLAS_BLOCK_LANES // lpf) // align * align)
+    n_fb = -(-f // FC)
+    f_pad = n_fb * FC
+    lanes = FC * lpf                             # output lanes per block
+    # bound the VMEM-resident one-hot tile: FC*lpf*BR (2-byte worst
+    # case; the int8 variant's tile is half that) <= budget
+    br_cap = max(128, (_PALLAS_ONEHOT_BYTES // (2 * FC * lpf)) // 128 * 128)
+    BR = max(128, min(block_rows or _PALLAS_BLOCK_ROWS, br_cap,
+                      -(-n // 128) * 128))
+    pad = (-n) % BR
+    if pad:
+        rows = jnp.pad(rows, ((0, 0), (0, pad)))
+    bins_t = jnp.pad(bins.T, ((0, f_pad - f), (0, pad)))      # [f_pad, Npad]
+    n_rb = (n + pad) // BR
 
-        def kernel_rm(bins_ref, gh_ref, out_ref):
-            @pl.when(pl.program_id(0) == 0)
-            def _init():
-                out_ref[:] = jnp.zeros_like(out_ref)
+    def kernel_fm(bins_ref, gh_ref, out_ref):
+        @pl.when(pl.program_id(1) == 0)
+        def _init():
+            out_ref[:] = jnp.zeros_like(out_ref)
 
-            # transpose the small [BR, f_cols] tile in VMEM so the one-hot
-            # can be built as [f, Bp, BR] and reshaped [f*Bp, BR] by merging
-            # LEADING dims (layout-free).  A [BR, f, Bp] -> [BR, f*Bp]
-            # reshape would merge a non-lane-aligned dim into lanes — a
-            # per-step relayout that benched ~10x slower.  Trailing f_limit
-            # columns (packed gradient bytes) are dropped by the sublane
-            # slice after the transpose.
-            b = bins_ref[:].T[:f_pad]                         # [f_pad, BR]
-            out_ref[:] = accumulate_block(
-                out_ref[:], spec.contrib(b, gh_ref[:],
-                                         fc=f_pad, B=B, Bp=Bp, BR=BR))
+        out_ref[:] = accumulate_block(
+            out_ref[:], spec.contrib(bins_ref[:], gh_ref[:],
+                                     fc=FC, B=B, Bp=Bp, BR=BR))
 
-        out = pl.pallas_call(
-            kernel_rm,
-            out_shape=jax.ShapeDtypeStruct((6, lanes), jnp.float32),
-            grid=(n_rb,),
-            in_specs=[pl.BlockSpec((BR, bins.shape[1]), lambda i: (i, 0)),
-                      pl.BlockSpec((rows.shape[0], BR), lambda i: (0, i))],
-            out_specs=pl.BlockSpec((6, lanes), lambda i: (0, 0)),
-            interpret=interpret,
-        )(bins, rows)
-    else:
-        # ---- feature-major blocked path (wide features) --------------------
-        if f < f_cols:
-            bins = bins[:, :f]                   # drop packed-gradient cols
-        # features per block: 8-sublane floor, lane-pack group multiple
-        align = max(8, gf)
-        FC = max(align, (_PALLAS_BLOCK_LANES // lpf) // align * align)
-        n_fb = -(-f // FC)
-        f_pad = n_fb * FC
-        lanes = FC * lpf                         # output lanes per block
-        # bound the VMEM-resident one-hot tile: FC*lpf*BR (2-byte worst
-        # case; the int8 variant's tile is half that) <= budget
-        br_cap = max(128, (_PALLAS_ONEHOT_BYTES // (2 * FC * lpf)) // 128 * 128)
-        BR = max(128, min(block_rows or _PALLAS_BLOCK_ROWS, br_cap,
-                          -(-n // 128) * 128))
-        pad = (-n) % BR
-        if pad:
-            rows = jnp.pad(rows, ((0, 0), (0, pad)))
-        bins_t = jnp.pad(bins.T, ((0, f_pad - f), (0, pad)))  # [f_pad, Npad]
-        n_rb = (n + pad) // BR
-
-        def kernel_fm(bins_ref, gh_ref, out_ref):
-            @pl.when(pl.program_id(1) == 0)
-            def _init():
-                out_ref[:] = jnp.zeros_like(out_ref)
-
-            out_ref[:] = accumulate_block(
-                out_ref[:], spec.contrib(bins_ref[:], gh_ref[:],
-                                         fc=FC, B=B, Bp=Bp, BR=BR))
-
-        out = pl.pallas_call(
-            kernel_fm,
-            out_shape=jax.ShapeDtypeStruct((6, n_fb * lanes), jnp.float32),
-            grid=(n_fb, n_rb),
-            in_specs=[pl.BlockSpec((FC, BR), lambda fb, i: (fb, i)),
-                      pl.BlockSpec((rows.shape[0], BR), lambda fb, i: (0, i))],
-            out_specs=pl.BlockSpec((6, lanes), lambda fb, i: (0, fb)),
-            interpret=interpret,
-        )(bins_t, rows)
+    out = pl.pallas_call(
+        kernel_fm,
+        out_shape=jax.ShapeDtypeStruct((6, n_fb * lanes), jnp.float32),
+        grid=(n_fb, n_rb),
+        in_specs=[pl.BlockSpec((FC, BR), lambda fb, i: (fb, i)),
+                  pl.BlockSpec((rows.shape[0], BR), lambda fb, i: (0, i))],
+        out_specs=pl.BlockSpec((6, lanes), lambda fb, i: (0, fb)),
+        interpret=interpret,
+    )(bins_t, rows)
 
     return finish_hist(out, f, B, Bp, spec)
 

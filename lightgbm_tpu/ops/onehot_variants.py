@@ -3,12 +3,11 @@
 The histogram build is a one-hot matmul on the MXU (ops/histogram.py), and
 the one-hot construction is the kernel's bound: the production build is an
 iota-compare-select over ``f*Bp*BR`` elements per block on the VPU, ~6 MXU
-MACs of useful work per VPU-built element, which caps the kernel at ~12% MFU
-(docs/PERF.md "ceiling attack").  Each registry entry changes how the
-one-hot tile is built — or what rides the dot — so the production kernels,
-the timing shootout (scripts/bench_onehot_variants.py) and the perf suite
-(scripts/tpu_perf_suite.py) all draw from ONE set of kernel bodies that
-cannot drift apart.  This registry plus ``pick_variant`` replaces the
+MACs of useful work per VPU-built element (docs/COMPONENTS.md, "Histogram
+kernel layout").  Each registry entry changes how the one-hot tile is
+built — or what rides the dot — so both production kernels and the
+election draw from ONE set of kernel bodies that cannot drift apart.
+This registry plus ``pick_variant`` replaces the
 reference's col-wise/row-wise histogram auto-tuner (``train_share_states.h``)
 with a TPU-native equivalent: the candidate axes are one-hot build
 strategies, and the timed election runs once on device at first fit.
@@ -37,15 +36,11 @@ Variant families (``VARIANTS``):
 Every variant is interchangeable at the ``build_histogram`` call site and
 parity-checks against the exact scatter-add in Pallas interpret mode on CPU
 (tests/test_onehot_variants.py), so no variant can land or drift without
-tier-1 coverage; hardware pricing comes from the shootout under the watcher.
+tier-1 coverage; on the chip ``chip_smoke.py`` runs the same check.
 
-jax is imported inside the kernel-body/prep functions (the idiom the
-shootout always used): registry METADATA — names, geometry, the VPU-work
-model — is plain-int machinery, and nothing heavier loads until a kernel
-is actually built.  (Importing THIS MODULE still runs the package
-``__init__``, which imports jax — callers that must stay jax-free, like
-the watcher's supervisor, load ``bench``/``supervise`` package-init-free
-instead and never touch the registry.)
+jax is imported inside the kernel-body/prep functions: registry METADATA —
+names, geometry, the VPU-work model — is plain-int machinery, and nothing
+heavier loads until a kernel is actually built.
 """
 from __future__ import annotations
 
@@ -75,9 +70,8 @@ def pack_k(max_bin: int) -> int:
 class VariantSpec(NamedTuple):
     """One one-hot build strategy, pluggable into every histogram kernel.
 
-    The kernel shells (grid/BlockSpec plumbing in ops/histogram.py and the
-    shootout's single-block bench kernel) stay generic; everything
-    variant-specific lives here:
+    The kernel shells (grid/BlockSpec plumbing in ops/histogram.py) stay
+    generic; everything variant-specific lives here:
 
       prep(grad, hess, mask) -> [R, N] rows for the dot's LHS (R and dtype
           set the MXU rate: 6 bf16 rows for the split-precision pair, 3 f32
@@ -92,8 +86,8 @@ class VariantSpec(NamedTuple):
           kernel's slot-select).  Rows are the (hi, lo) triple pairs that
           ``finish_hist`` sums.
       supports(B): static eligibility for a kernel bin width.
-      vpu_compares(f, B, BR): per-row-block VPU compare count — the work
-          model behind the predicted MFU bounds in docs/PERF.md.
+      vpu_compares(f, B, BR): per-row-block VPU compare count, the work
+          model of docs/COMPONENTS.md "Histogram kernel layout".
     """
     name: str
     description: str
@@ -180,24 +174,6 @@ def total_lanes(name: str, f: int, max_bin: int) -> int:
     the structural size the lane-packing variant shrinks."""
     spec = VARIANTS[name]
     return feat_geometry(spec, f, max_bin, padded_bins(max_bin))[1]
-
-
-#: VPU:MXU throughput ratio at the bf16 rate (8x128 VPU lanes vs the
-#: 128x128 MXU) — the normalization of the docs/PERF.md VPU-work model
-VPU_MXU_RATIO = 42.0
-
-
-def predicted_mfu(name: str, f: int, max_bin: int) -> float:
-    """Analytical MFU bound from the VPU-work model (docs/PERF.md
-    "ceiling attack"): per row the kernel does ``6 * lanes`` useful MXU
-    MACs against ``vpu_compares`` one-hot VPU ops at a ~1:42 throughput
-    disadvantage, so the bound is ``MACs / (MACs + 42 * compares)`` —
-    fewer compares per useful MAC raises the roof.  The perf suite and
-    shootout report this next to the achieved MFU so the next window
-    prices each variant's headroom automatically."""
-    macs = 6.0 * total_lanes(name, f, max_bin)
-    compares = float(VARIANTS[name].vpu_compares(f, max_bin, 1))
-    return macs / (macs + VPU_MXU_RATIO * compares)
 
 
 # --------------------------------------------------------------------------
@@ -434,57 +410,6 @@ def finish_hist(out, f, B, Bp, spec: VariantSpec):
     hist = hist[..., :f, :]
     # [..., C, f, B] -> [..., f, B, C]
     return jnp.moveaxis(hist, -3, -1)
-
-
-# --------------------------------------------------------------------------
-# single-feature-block bench kernel (the shootout's shell)
-# --------------------------------------------------------------------------
-
-def make_bench_kernel(variant: str, f: int, max_bin: int, BR: int, *,
-                      interpret: bool = False):
-    """(prep, run) for the timing shootout: ``rows = jit(prep)(g, h, m)``
-    once outside the timed loop, then ``run(bins_t [f, N] u8, rows)`` is the
-    timed kernel — feature-major single-block, bins pre-transposed OUTSIDE
-    (the production layout; the in-kernel transpose benched 35x slower).
-    Returns finished ``[f, B, 6]`` pair histograms so parity checks read off
-    the same surface the production kernels expose."""
-    import jax
-    from jax.experimental import pallas as pl
-
-    spec = VARIANTS[variant]
-    B = max_bin
-    Bp = padded_bins(B)
-    fc, lanes = feat_geometry(spec, f, B, Bp)
-
-    def kernel(bins_ref, gh_ref, out_ref):
-        import jax.numpy as jnp
-
-        @pl.when(pl.program_id(0) == 0)
-        def _init():
-            out_ref[:] = jnp.zeros_like(out_ref)
-
-        out_ref[:] = accumulate_block(
-            out_ref[:], spec.contrib(bins_ref[:], gh_ref[:],
-                                     fc=fc, B=B, Bp=Bp, BR=BR))
-
-    def run(bins_t, rows):
-        import jax.numpy as jnp
-        n = bins_t.shape[1]
-        assert n % BR == 0
-        if fc > f:
-            bins_t = jnp.pad(bins_t, ((0, fc - f), (0, 0)))
-        out = pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((6, lanes), jnp.float32),
-            grid=(n // BR,),
-            in_specs=[pl.BlockSpec((fc, BR), lambda i: (0, i)),
-                      pl.BlockSpec((rows.shape[0], BR), lambda i: (0, i))],
-            out_specs=pl.BlockSpec((6, lanes), lambda i: (0, 0)),
-            interpret=interpret,
-        )(bins_t, rows)
-        return finish_hist(out, f, B, Bp, spec)
-
-    return spec.prep, run
 
 
 # --------------------------------------------------------------------------

@@ -24,8 +24,6 @@ onto collectives exactly (ops/grower.py ``reduce_hist`` /
 
 Paths that need a full-width histogram on every shard (EFB bundle
 expansion, forced splits, CEGB-lazy) fall back to the full ``psum``.
-``scripts/bench_dp_scaling.py`` measures the 1..8-shard curve on the
-virtual CPU mesh.
 """
 from __future__ import annotations
 

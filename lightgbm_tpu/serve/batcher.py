@@ -15,10 +15,9 @@ bucket-shaped call:
   already pending, ``submit`` refuses immediately with
   :class:`QueueSaturatedError` instead of letting latency collapse.
 
-Supervision idioms follow ``utils/supervise.py``: the optional
-``heartbeat`` is any ``(event, **fields)`` callable (e.g.
-``supervise.Heartbeat``) and a worker-thread crash marks the batcher
-broken and fails pending futures instead of hanging their callers.
+The optional ``heartbeat`` is any ``(event, **fields)`` callable, and a
+worker-thread crash marks the batcher broken and fails pending futures
+instead of hanging their callers.
 """
 from __future__ import annotations
 

@@ -68,7 +68,7 @@ class Predictor:
         artifact's config: ``serve_batch_deadline_ms`` /
         ``serve_queue_depth``).
       heartbeat: ``(event, **fields)`` observability callable shared with
-        the batchers (``utils/supervise.Heartbeat`` shape).
+        the batchers.
     """
 
     def __init__(self, artifact: Optional[PredictorArtifact] = None, *,

@@ -8,9 +8,7 @@ prefetches), so the block size is chosen as
 
 rounded down to a 128-multiple (row blocks tile the TPU sublane grid).
 ``STREAM_FAKE_HBM_BYTES`` overrides the configured budget so CPU tier-1
-tests exercise real eviction/prefetch behavior without hardware — the same
-fake-backend seam pattern that made the TPU-window watcher testable
-(docs/WATCHER.md).
+tests exercise real eviction/prefetch behavior without hardware.
 """
 from __future__ import annotations
 

@@ -11,7 +11,7 @@ Every block is padded to the uniform ``block_rows`` shape (pad rows ride
 row-weight 0, so they vanish from every histogram and sum) — one compiled
 program shape serves all blocks.  Device-byte accounting
 (``PipelineStats``) is the measurement surface for the synthetic-HBM-cap
-tests and ``scripts/bench_stream.py``.
+tests.
 """
 from __future__ import annotations
 

@@ -4,7 +4,7 @@ A cold grow program compiles for 40 s on one v5e chip and 108 s under a
 four-device ``shard_map`` (chip_smoke.py observations, PR 22) and every
 process recompiles it, so the package points JAX at a persistent cache once,
 at import (``lightgbm_tpu/__init__.py``): ``lgb.train``, ``python -m
-lightgbm_tpu``, ``bench.py``, ``chip_smoke.py`` and every script share it.
+lightgbm_tpu``, ``chip_smoke.py`` and ``benchmarks/run.py`` share it.
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself and this
   module sets no directory in code, so whoever runs the program places the

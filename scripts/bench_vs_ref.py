@@ -1,20 +1,17 @@
 """Head-to-head vs the compiled reference binary, same data, same machine.
 
 Trains ``/tmp/lgbm_src/lightgbm`` (reference CLI, ``docs/Experiments.rst:
-110-135`` methodology) on the exact dataset ``bench.py`` uses
-(``make_higgs_like``) with the exact bench params, times it from the
-reference's own per-iteration log lines (``src/boosting/gbdt.cpp:275``
-prints cumulative elapsed per iteration), and scores held-out AUC on a
-fresh 200k-row split via ``task=predict``.
+110-135`` methodology) on the exact dataset ``chip_smoke.py`` trains on
+(``make_higgs_like``) with its parameters, times it from the reference's
+own per-iteration log lines (``src/boosting/gbdt.cpp:275`` prints
+cumulative elapsed per iteration), and scores held-out AUC on a fresh
+200k-row split via ``task=predict``.
 
-Results land in ``docs/ref_headtohead.json`` keyed by row count —
-``bench.py`` reads that file to derive its held-out-AUC floor and to emit
-``ref_auc`` / ``ref_sec_per_tree_local`` / ``auc_delta`` in the bench
-detail — and are appended to ``perf_results.jsonl``.
+Results land in ``docs/ref_headtohead.json`` keyed by row count, the only
+whole-model comparison with the compiled reference; the entry is also the
+last line of stdout.
 
 Run: ``python scripts/bench_vs_ref.py [--rows 1000000] [--iters 22]``
-(iters defaults to bench.py's warmup+timed = 22 so the AUC comparison is
-between same-size ensembles).
 """
 from __future__ import annotations
 
@@ -31,21 +28,16 @@ import numpy as np
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-from bench import make_higgs_like  # noqa: E402
-
-from bench import load_obs  # noqa: E402
+from chip_smoke import make_higgs_like  # noqa: E402
 
 REF_BIN = os.environ.get("REF_LGBM_BIN", "/tmp/lgbm_src/lightgbm")
 OUT_JSON = os.path.join(REPO, "docs", "ref_headtohead.json")
-# the single perf-journal writer (obs.events): honors WATCHER_PERF_LOG,
-# which the bare perf_results.jsonl path here previously ignored
-LOG = load_obs().EventLog.default(echo=True)
 
 # one row per line, label first (the reference default: label=column 0).
 # %.9g round-trips float32 bit-exactly (9 significant digits uniquely
 # identify any binary32; %.7g did NOT, so the reference trained on data
 # that differed from ours in the last ulps — weakening the "identical
-# data" head-to-head claim).  tests/test_bench.py locks the round trip.
+# data" head-to-head claim).  tests/test_chip_smoke.py locks the round trip.
 def _write_csv(path: str, X: np.ndarray, y: np.ndarray | None) -> None:
     cols = X if y is None else np.column_stack([y, X])
     np.savetxt(path, cols, delimiter=",", fmt="%.9g")
@@ -83,9 +75,9 @@ def main() -> None:
     ap.add_argument("--iters", type=int, default=22)
     ap.add_argument("--valid-rows", type=int, default=200_000)
     ap.add_argument("--warmup", type=int, default=2,
-                    help="iterations excluded from sec/tree (compile/cache"
-                         " warmup analog; the reference has none, but this"
-                         " matches how bench.py times ours)")
+                    help="iterations excluded from sec/tree (the reference"
+                         " compiles nothing; ours are timed after a"
+                         " warm-up)")
     args = ap.parse_args()
 
     if not os.path.exists(REF_BIN):
@@ -147,8 +139,6 @@ def main() -> None:
         "threads": nthreads,
         "ref_version": "LightGBM v3.1.1.99 (compiled on this VM)",
     }
-    print(json.dumps(entry))
-
     table = {}
     if os.path.exists(OUT_JSON):
         with open(OUT_JSON) as f:
@@ -158,9 +148,7 @@ def main() -> None:
     with open(OUT_JSON, "w") as f:
         json.dump(table, f, indent=1)
     print(f"recorded -> {OUT_JSON}")
-    # one-JSON-line contract: summary() appends to the journal AND prints
-    # the schema-stamped record as the LAST stdout line
-    LOG.summary(bench="ref_headtohead", **entry)
+    print(json.dumps({"bench": "ref_headtohead", **entry}), flush=True)
 
 
 if __name__ == "__main__":

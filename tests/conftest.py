@@ -40,10 +40,9 @@ def multiclass_data():
 
 # --- quick tier -------------------------------------------------------------
 # `pytest -m quick` runs a <3-minute cross-section (kernel unit tests, native
-# parser, param docs, plus one smoke test per major surface) so hardware
-# windows aren't spent on the full ~1h suite.  Whole fast modules + named
-# smoke tests; anything unlisted is excluded.
-_QUICK_MODULES = {"test_ops", "test_native", "test_param_docs", "test_bench"}
+# parser, param docs, plus one smoke test per major surface).  Whole fast
+# modules + named smoke tests; anything unlisted is excluded.
+_QUICK_MODULES = {"test_ops", "test_native", "test_param_docs"}
 _QUICK_TESTS = {
     ("test_engine", "test_binary"),
     ("test_engine", "test_early_stopping"),

@@ -10,12 +10,18 @@
   every variant the first-fit election may pick.  That proves they compile;
   whether they run and give the right numbers only the chip can say
   (``chip_smoke.py`` phase 1).
+- ``chip_smoke.auc_of`` and the jax-free ``scripts/bench_vs_ref.py`` (which
+  drives the compiled reference binary on ``chip_smoke.make_higgs_like``'s
+  data) agree on AUC, and the CSV the reference reads is our float32 matrix
+  bit for bit.
 """
+import importlib.util
 import json
 import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -66,6 +72,56 @@ def test_without_the_flag_a_cpu_backend_is_a_failure(tmp_path):
     # and the sizes cannot be cut outside a dry run
     p = _run([SMOKE, "--rows", "1000"], {"JAX_PLATFORMS": "cpu"})
     assert p.returncode != 0 and "--dry-run" in p.stderr
+
+
+# --------------------------------------------------------------------------
+# the head-to-head with the compiled reference (scripts/bench_vs_ref.py)
+# --------------------------------------------------------------------------
+
+def _load(name, *path):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(REPO, *path))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_csv_roundtrips_float32_bit_exact(tmp_path):
+    """The head-to-head's "identical data" claim requires the CSV handed to
+    the reference binary to reproduce our float32 matrix BIT-exactly:
+    %.9g guarantees that (9 significant digits uniquely identify any
+    binary32); the old %.7g did not."""
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(200, 4)).astype(np.float32)
+    # adversarial values: last-ulp neighbors, huge/tiny exponents, denormal
+    X[0, :] = [np.float32(1/3), np.nextafter(np.float32(1/3), np.float32(1)),
+               np.float32(3.4e38), np.float32(1.2e-38)]
+    X[1, :] = [np.float32(1e-45), np.float32(-0.0), np.float32(2**-24),
+               np.nextafter(np.float32(1.0), np.float32(2.0))]
+    y = (rng.random(200) > 0.5).astype(np.float32)
+    path = str(tmp_path / "t.csv")
+    _load("bench_vs_ref", "scripts", "bench_vs_ref.py")._write_csv(path, X, y)
+    back = np.loadtxt(path, delimiter=",")
+    cols = np.column_stack([y, X])
+    np.testing.assert_array_equal(
+        back.astype(np.float32).view(np.uint32),
+        cols.view(np.uint32),
+        err_msg="CSV write/read must round-trip float32 bit-exactly")
+
+
+def test_script_auc_matches_package_metric():
+    """The script's standalone AUC agrees with ``chip_smoke.auc_of``, the
+    package's AUCMetric that ``chip_smoke.py`` holds its floor against."""
+    script_auc = _load("bench_vs_ref", "scripts", "bench_vs_ref.py")._auc
+    auc_of = _load("chip_smoke", "chip_smoke.py").auc_of
+    rng = np.random.default_rng(0)
+    for n, tie in [(500, False), (500, True), (50, True)]:
+        y = (rng.random(n) > 0.4).astype(np.float64)
+        s = rng.normal(size=n)
+        if tie:                      # heavy ties exercise the midrank path
+            s = np.round(s, 1)
+        np.testing.assert_allclose(script_auc(y, s), auc_of(s, y),
+                                   atol=1e-12)
 
 
 # --------------------------------------------------------------------------

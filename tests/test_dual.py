@@ -5,11 +5,10 @@ The analog of the reference's ``tests/python_package_test/test_dual.py:20-35``
 kernels — the XLA one-hot/scatter fallbacks vs the Pallas TPU kernel — and
 the backends (CPU vs TPU).
 
-On the CPU CI backend the Pallas kernel cannot run, so the TPU half is
-skipped; the driver's bench environment (ambient TPU) runs it for real via
-``scripts/bench_dual.py`` or by setting ``LGBM_TPU_DUAL=1`` with a TPU
-visible.  What always runs: scatter-vs-onehot kernel parity and
-grower-level equivalence between histogram methods.
+On the CPU backend the Pallas kernel is not compiled, so the TPU half is
+skipped; on the chip ``chip_smoke.py``'s first phase holds both Pallas
+kernels to the exact scatter-add.  What always runs: scatter-vs-onehot
+kernel parity and grower-level equivalence between histogram methods.
 """
 import numpy as np
 import pytest
@@ -41,8 +40,8 @@ def test_scatter_vs_onehot_parity():
 
 def test_hist_methods_train_same_model():
     """The full training path must produce the same tree structure whatever
-    histogram method the backend picked (scatter vs onehot here; the TPU
-    bench covers pallas via the AUC pin)."""
+    histogram method the backend picked (scatter vs onehot here;
+    ``chip_smoke.py`` covers pallas on the chip)."""
     from sklearn.datasets import make_classification
     import lightgbm_tpu as lgb
 
@@ -56,7 +55,7 @@ def test_hist_methods_train_same_model():
         # except the root pass, and make_classification's redundant columns
         # produce exactly-tied gains whose resolution flips with summation
         # order — kernel parity for it is covered by test_frontier and
-        # scripts/bench_dual.py.)
+        # chip_smoke.run_kernel_checks.)
         bst = lgb.Booster(params={"objective": "binary", "num_leaves": 31,
                                   "verbose": -1, "tree_grower": "serial"},
                           train_set=train)
@@ -94,8 +93,8 @@ def test_split_bf16_pair_keeps_residual_under_jit():
     optimization barrier in the lowered program (the barrier is
     backend-erasable post-optimization where the rewrite doesn't fire, so
     only the pre-optimization lowering is assertable on CPU CI; the
-    hardware-truth gate is scripts/bench_dual.py's batched-leaf parity), (2) the in-jit lo equals
-    the eager lo bit-for-bit on this backend."""
+    hardware-truth gate is chip_smoke.py's batched-leaf parity), (2) the
+    in-jit lo equals the eager lo bit-for-bit on this backend."""
     from lightgbm_tpu.ops.histogram import _split_bf16_pair
 
     rng = np.random.default_rng(0)

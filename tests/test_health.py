@@ -3,12 +3,9 @@ docs/OBSERVABILITY.md "Live health & forensics").
 
 CPU-only.  Covers ISSUE 20's acceptance criteria: a live training run
 with ``obs_health_port`` set answers ``/metrics`` and ``/healthz`` from
-another process; a SIGKILLed (or hung-and-reaped) supervised stage
-leaves a schema-valid ``flight_*.jsonl`` that ``run_stage`` collects
-beside its journal; and a NaN-gradient objective raises
+another process; a SIGKILLed, signalled or raising child leaves a
+schema-valid ``flight_*.jsonl``; and a NaN-gradient objective raises
 :class:`DivergenceError` within ``obs_health_check_iters`` rounds.
-Crash-path children are stdlib-only (obs loads via ``bench.load_obs``)
-so each subprocess costs milliseconds, not a jax import.
 """
 import json
 import os
@@ -25,8 +22,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
-
 from lightgbm_tpu.obs import flight as obs_flight  # noqa: E402
 from lightgbm_tpu.obs import health as obs_health  # noqa: E402
 from lightgbm_tpu.obs import metrics as obs_metrics  # noqa: E402
@@ -35,8 +30,6 @@ from lightgbm_tpu.obs.events import EventLog, classify_record  # noqa: E402
 from lightgbm_tpu.obs.flight import FlightRecorder  # noqa: E402
 from lightgbm_tpu.obs.health import DivergenceError, SLOMonitor  # noqa: E402
 from lightgbm_tpu.obs.tracer import get_tracer  # noqa: E402
-
-sup = bench._load_supervise()
 
 pytestmark = pytest.mark.health
 
@@ -359,8 +352,7 @@ def test_flight_span_tail_in_dump(tmp_path):
 _CRASH_CHILD = """
 import os, signal, sys
 sys.path.insert(0, {repo!r})
-import bench
-obs = bench.load_obs()
+from lightgbm_tpu import obs
 rec = obs.flight.install(dir={dir!r}, run_id="victim", flush_every=1)
 rec.note("about_to_die", mode={mode!r})
 mode = {mode!r}
@@ -407,98 +399,6 @@ def test_flight_dump_on_unhandled_exception(tmp_path):
     exc = [e for e in evs if e["event"] == "unhandled_exception"]
     assert exc and exc[0]["type"] == "ValueError"
     assert "boom" in exc[0]["message"]
-
-
-# ---------------------------------------------------------------------------
-# run_stage / watcher: crash forensics collected beside the journal
-# ---------------------------------------------------------------------------
-
-_STAGE_CHILD = """
-import os, signal, sys, time
-sys.path.insert(0, {repo!r})
-import bench
-obs = bench.load_obs()
-rec = obs.flight.install(flush_every=1)      # LGBM_FLIGHT_DIR from run_stage
-rec.note("stage_payload", mode={mode!r})
-mode = {mode!r}
-if mode == "sigkill":
-    os.kill(os.getpid(), signal.SIGKILL)
-elif mode == "hang":
-    time.sleep(600)
-"""
-
-
-def _stage_argv(tmp_path, mode):
-    script = tmp_path / f"stage_{mode}.py"
-    script.write_text(_STAGE_CHILD.format(repo=REPO, mode=mode))
-    return [sys.executable, str(script)]
-
-
-def test_run_stage_collects_flight_dump_on_sigkill(tmp_path):
-    res = sup.run_stage("victim-kill", _stage_argv(tmp_path, "sigkill"),
-                        timeout=60, retries=0, flight_dir=str(tmp_path))
-    assert res.status == "crash"
-    assert len(res.flight_dumps) == 1
-    evs = _assert_schema_lines(res.flight_dumps[0])
-    assert any(e["event"] == "stage_payload" for e in evs)
-    assert res.to_record()["flight_dumps"] == res.flight_dumps
-    # the collectible name carries stage + attempt; scratch dirs are gone
-    base = os.path.basename(res.flight_dumps[0])
-    assert base.startswith("flight_victim-kill_a0_")
-    assert not [d for d in os.listdir(tmp_path) if d.startswith(".flight_")]
-
-
-def test_run_stage_collects_flight_dump_on_hang_kill(tmp_path):
-    res = sup.run_stage("victim-hang", _stage_argv(tmp_path, "hang"),
-                        timeout=2, retries=0, flight_dir=str(tmp_path))
-    assert res.status == "timeout"
-    assert len(res.flight_dumps) == 1
-    evs = _assert_schema_lines(res.flight_dumps[0])
-    assert any(e["event"] == "stage_payload" and e["mode"] == "hang"
-               for e in evs)
-
-
-def test_run_stage_ok_keeps_no_dump(tmp_path):
-    script = tmp_path / "ok.py"
-    script.write_text(_STAGE_CHILD.format(repo=REPO, mode="ok"))
-    res = sup.run_stage("fine", [sys.executable, str(script)],
-                        timeout=60, retries=0, flight_dir=str(tmp_path))
-    assert res.status == "ok"
-    assert res.flight_dumps == []
-    assert not list(tmp_path.glob("flight_*.jsonl"))    # healthy = no noise
-
-
-@pytest.mark.watcher
-def test_watcher_collects_flight_dumps_beside_journal(tmp_path):
-    plan = tmp_path / "plan.json"
-    plan.write_text(json.dumps({"perf_suite": ["crash"],
-                                "onehot_shootout": ["hang"]}))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               WATCHER_FAKE_BACKEND="ok",
-               WATCHER_FAKE_STAGE_PLAN=str(plan),
-               WATCHER_PERF_LOG=str(tmp_path / "perf.jsonl"))
-    p = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts",
-                                      "tpu_window_watcher.py"),
-         "--state-dir", str(tmp_path), "--poll-interval", "0.01",
-         "--poll-cap", "0.05", "--probe-timeout", "5",
-         "--stage-timeout", "2"],
-        capture_output=True, text=True, timeout=120, env=env)
-    assert p.returncode == 0, p.stderr
-    dumps = sorted(tmp_path.glob("flight_*.jsonl"))
-    names = [d.name for d in dumps]
-    assert len(dumps) == 2, names
-    assert names[0].startswith("flight_onehot_shootout_a0_")
-    assert names[1].startswith("flight_perf_suite_a0_")
-    for d in dumps:
-        evs = _assert_schema_lines(d)
-        assert evs[0]["event"] == "flight_dump"
-        assert any(e["event"] == "fake_stage_behavior" for e in evs)
-    # the stage's perf record carries the collected dump paths
-    recs = [json.loads(l) for l in
-            (tmp_path / "perf.jsonl").read_text().splitlines()]
-    crashed = [r for r in recs if r.get("stage") == "watcher_perf_suite"]
-    assert crashed and crashed[0]["flight_dumps"]
 
 
 # ---------------------------------------------------------------------------

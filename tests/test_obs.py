@@ -3,10 +3,9 @@
 CPU-only and fast.  Covers ISSUE 16's acceptance criteria: the structured
 event schema round-trips and is thread-safe; the report layer tolerates
 the legacy (pre-schema) journal lines the six old writers produced; the
-serve-path metrics are correct under concurrent load; a CPU training run
-emits one schema-valid event per boosting iteration and leaves nested spans
-in the process tracer; and every ``scripts/bench_*.py`` is statically
-held to the one-JSON-line summary contract.
+serve-path metrics are correct under concurrent load; and a CPU training
+run emits one schema-valid event per boosting iteration and leaves nested
+spans in the process tracer.
 """
 import glob
 import json
@@ -738,60 +737,10 @@ def test_telemetry_off_keeps_journal_untouched(train_telemetry_env):
     assert obs_metrics.snapshot().get("train.iterations") is None
 
 
-# ---------------------------------------------------------------------------
-# bench-contract static check: every bench script uses the shared writer
-# and ends with the schema summary (satellite of ISSUE 16 — keeps future
-# bench scripts from regressing to bare json.dumps prints)
-def test_every_bench_script_honors_summary_contract():
-    scripts = sorted(glob.glob(os.path.join(REPO, "scripts", "bench_*.py")))
-    assert scripts, "no bench scripts found — wrong repo layout?"
-    offenders = []
-    for path in scripts:
-        src = open(path).read()
-        if "load_obs" not in src or ".summary(" not in src:
-            offenders.append(os.path.basename(path))
-    assert not offenders, (
-        f"bench scripts bypassing the EventLog summary contract: {offenders} "
-        "— route records through bench.load_obs().EventLog and emit the "
-        "final one-JSON-line summary via LOG.summary(...) "
-        "(see docs/OBSERVABILITY.md)")
-
-
-def test_supervisor_loader_is_jax_free():
-    """bench.load_obs + events + report must import WITHOUT jax — the
-    watcher/suite supervisors run while a stage owns the TPU."""
-    import subprocess
-    import sys as _sys
-    code = (
-        "import builtins, sys\n"
-        "real = builtins.__import__\n"
-        "def guard(name, *a, **k):\n"
-        "    if name == 'jax' or name.startswith('jax.'):\n"
-        "        raise AssertionError('supervisor path imported jax')\n"
-        "    return real(name, *a, **k)\n"
-        "builtins.__import__ = guard\n"
-        "sys.path.insert(0, %r)\n"
-        "import bench\n"
-        "obs = bench.load_obs()\n"
-        "log = obs.EventLog(sys.argv[1])\n"
-        "log.emit('probe', ok=True)\n"
-        "loaded = obs.report.load_perf_log(sys.argv[1])\n"
-        "assert loaded['total'] == 1\n"
-        "print('JAXFREE_OK')\n" % REPO)
-    import tempfile
-    with tempfile.TemporaryDirectory() as d:
-        r = subprocess.run(
-            [_sys.executable, "-c", code, os.path.join(d, "e.jsonl")],
-            capture_output=True, text=True, timeout=60)
-    assert r.returncode == 0, r.stderr
-    assert "JAXFREE_OK" in r.stdout
-
-
 def test_no_peak_rate_constants_outside_costs():
-    """ONE peak table (ISSUE 18): every MFU / peak-rate figure must price
-    against lightgbm_tpu/obs/costs.py:PEAK_RATES.  Before the cost ledger,
-    bench.py, scripts/tpu_perf_suite.py and scripts/bench_onehot_variants.py
-    each carried a private table and disagreed about what "12% MFU" meant."""
+    """ONE peak table in Python (ISSUE 18): every MFU / peak-rate figure the
+    package prints prices against lightgbm_tpu/obs/costs.py:PEAK_RATES (the
+    benchmark's are data, ``benchmarks/peaks.json``)."""
     import re
     # multi-digit (or fractional) mantissas with e9..e19 exponents — the
     # shape of every published peak rate (275e12, 819e9, 3.3e12, ...) but

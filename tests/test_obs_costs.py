@@ -1,32 +1,24 @@
 """Device-truth performance attribution (ISSUE 18): cost ledger, roofline
-math, watermark gauges, and the perf-regression sentinel.
+math and watermark gauges.
 
 CPU-only and fast.  Covers the acceptance criteria: the ledger records
 XLA cost/memory analysis for a jitted histogram call on CPU and
 ``obs-report --roofline`` renders its MFU row; watermark gauges populate
 during a short boosting run (via the injectable stats provider — CPU
-publishes no ``memory_stats``); the sentinel issues regressed / improved /
-no-baseline verdicts on synthetic histories AND stays clean on the repo's
-real committed ``BENCH_r0*.json`` rounds; and the ``--gate`` CLI exits
-nonzero on a journal copy with an injected 2x ``sec_per_tree`` slowdown
-but zero on the unmodified journal.
+publishes no ``memory_stats``).
 """
 import json
-import os
-import shutil
 
 import numpy as np
 import pytest
 
-from lightgbm_tpu.obs import costs, regress
+from lightgbm_tpu.obs import costs
 from lightgbm_tpu.obs import metrics as obs_metrics
 from lightgbm_tpu.obs import report as obs_report
 from lightgbm_tpu.obs.events import EventLog, classify_record
 from lightgbm_tpu.obs.tracer import get_tracer
 
 pytestmark = pytest.mark.obs
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -228,112 +220,3 @@ def test_record_watermarks_empty_when_backend_has_no_stats():
     finally:
         costs.set_stats_provider(None)
     assert "nowhere.device_bytes_in_use" not in obs_metrics.snapshot()
-
-
-# ---------------------------------------------------------------------------
-# regression sentinel: synthetic histories
-def test_classify_synthetic_verdicts():
-    base = [1.0, 1.02, 0.98, 1.01]
-    v = regress.classify(base, 2.0, "lower")          # 2x slowdown
-    assert v["verdict"] == "regressed"
-    assert v["severity"] in ("major", "critical")
-    assert regress.classify(base, 0.5, "lower")["verdict"] == "improved"
-    assert regress.classify(base, 1.03, "lower")["verdict"] == "ok"
-    # fewer than MIN_BASELINE prior samples can never false-positive
-    v = regress.classify([1.0, 1.0], 99.0, "lower")
-    assert v["verdict"] == "no-baseline" and v["n_baseline"] == 2
-    # direction flips for higher-is-better metrics
-    assert regress.classify(base, 0.5, "higher")["verdict"] == "regressed"
-    assert regress.classify(base, 2.0, "higher")["verdict"] == "improved"
-    # one wedged outlier must not poison the median baseline
-    v = regress.classify([0.81, 2.0, 0.82, 0.80], 0.83, "lower")
-    assert v["verdict"] == "ok"
-
-
-def _sample(value, seq, metric="synthetic_bench", field="sec_per_tree"):
-    return {"key": (metric, "cpu", "rows=1000", field), "metric": metric,
-            "backend": "cpu", "shape": "rows=1000", "field": field,
-            "value": float(value), "direction": "lower", "seq": seq}
-
-
-def test_scan_flags_injected_slowdown_and_improvement():
-    slow = [_sample(v, i) for i, v in enumerate([1.0, 1.01, 0.99, 2.2])]
-    res = regress.scan(samples=slow)
-    assert res["regressed"] and res["counts"]["regressed"] == 1
-    worst = res["verdicts"][0]
-    assert worst["verdict"] == "regressed" and worst["field"] == "sec_per_tree"
-
-    fast = [_sample(v, i) for i, v in enumerate([1.0, 1.01, 0.99, 0.4])]
-    res = regress.scan(samples=fast)
-    assert not res["regressed"] and res["counts"] == {"improved": 1}
-
-    fresh = [_sample(1.0, 0), _sample(1.0, 1)]
-    res = regress.scan(samples=fresh)
-    assert not res["regressed"] and res["counts"] == {"no-baseline": 1}
-
-
-def test_canonical_metric_merges_renamed_series():
-    # the honest-labeling rename must continue the mislabeled series:
-    # backend + rows live in the series KEY, not the metric name
-    assert (regress.canonical_metric("higgs_1m_train_throughput")
-            == regress.canonical_metric("higgs_200k_cpu_fallback_train_throughput")
-            == regress.canonical_metric("higgs_10p5m_train_throughput")
-            == "higgs_train_throughput")
-
-
-def test_extract_samples_skips_failed_records():
-    assert regress.extract_samples({"stage": "grow_64", "error": "boom",
-                                    "ms": 5.0}) == []
-    assert regress.extract_samples({"stage": "grow_64", "ok": False,
-                                    "ms": 5.0}) == []
-    got = regress.extract_samples({"stage": "grow_64", "backend": "cpu",
-                                   "ms": 5.0})
-    assert [s["field"] for s in got] == ["ms"]
-    # non-perf stages are not judged
-    assert regress.extract_samples({"stage": "compile_probe",
-                                    "ms": 5.0}) == []
-
-
-# ---------------------------------------------------------------------------
-# regression sentinel: the repo's real committed history
-def test_sentinel_on_real_bench_rounds(tmp_path):
-    bench_glob = os.path.join(REPO, "BENCH_r*.json")
-    samples = regress.load_history(
-        journal_path=str(tmp_path / "no_journal.jsonl"),
-        bench_glob=bench_glob)
-    assert samples, "committed BENCH_r0*.json rounds produced no samples"
-    metrics = {s["metric"] for s in samples}
-    assert "higgs_train_throughput" in metrics     # canonicalized name
-    backends = {s["backend"] for s in samples}
-    assert "cpu" in backends
-    res = regress.scan(samples=samples)
-    # the committed rounds are the baseline: they must judge clean
-    assert not res["regressed"], res["verdicts"][:3]
-
-
-def test_gate_exit_codes_on_journal_copy(tmp_path):
-    """Acceptance: ``obs-report --regressions --gate`` exits 0 on the
-    unmodified journal and nonzero after an injected 2x ``sec_per_tree``
-    slowdown."""
-    journal = str(tmp_path / "perf_results.jsonl")
-    shutil.copy(os.path.join(REPO, "perf_results.jsonl"), journal)
-    bench_glob = os.path.join(REPO, "BENCH_r*.json")
-    out = str(tmp_path / "report.md")
-
-    rc = obs_report.main(["--path", journal, "--regressions", "--gate",
-                          "--bench-glob", bench_glob, "--out", out])
-    assert rc == 0, open(out).read()
-
-    # inject: the latest bench summary, twice as slow per tree
-    rec = json.load(open(os.path.join(REPO, "BENCH_r05.json")))["parsed"]
-    rec["detail"]["sec_per_tree"] *= 2.0
-    with open(journal, "a") as f:
-        f.write(json.dumps(rec) + "\n")
-    rc = obs_report.main(["--path", journal, "--regressions", "--gate",
-                          "--bench-glob", bench_glob, "--out", out])
-    assert rc == 1
-    text = open(out).read()
-    assert "regressed" in text and "sec_per_tree" in text
-    # without --gate the same scan reports but exits zero
-    rc = obs_report.main(["--path", journal, "--regressions", "--out", out])
-    assert rc == 0

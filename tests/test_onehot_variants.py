@@ -3,13 +3,10 @@
 Every registry variant (ops/onehot_variants.py) must parity-check against
 the exact scatter-add — masked rows AND fractional GOSS-style weights — in
 Pallas interpret mode on CPU, at BOTH a lane-packing width (max_bin=64) and
-the bench width (max_bin=255).  No variant can land or drift without this
-gate; hardware pricing is the shootout's job (scripts/bench_onehot_variants
-.py under the watcher).
-
-The interpret-mode checks run in clean subprocesses (the pattern of
-tests/test_frontier.py).  Pallas imports in-process as well; Mosaic's view
-of the same kernels is tests/test_chip_smoke.py (AOT compile for v5e).
+the 256-wide kernel histogram that max_bin=255 trains with.  No variant can
+land or drift without this gate.  The check is ``chip_smoke.py``'s own
+routine, the one the chip runs as its first phase; Mosaic's view of the
+same kernels is tests/test_chip_smoke.py (AOT compile for v5e).
 
 Registry STRUCTURE (geometry, work model, tuner caching) is asserted
 in-process — that metadata is deliberately importable without jax kernels.
@@ -147,95 +144,38 @@ def test_election_has_no_floor():
 
 
 # --------------------------------------------------------------------------
-# interpret-mode parity (clean subprocesses)
+# interpret-mode parity: the routine the chip runs (chip_smoke.py phase 1)
 # --------------------------------------------------------------------------
 
-_PARITY_CHECK = r"""
-import numpy as np, jax, jax.numpy as jnp
-import lightgbm_tpu.ops.histogram as H
-from lightgbm_tpu.ops import onehot_variants as ov
-
-rng = np.random.default_rng(3)
-for B in (64, 255):
-    n, f = 2560, 9
-    bins = jnp.asarray(rng.integers(0, B, size=(n, f), dtype=np.uint8))
-    g = jnp.asarray(rng.normal(size=n).astype(np.float32))
-    h = jnp.asarray(rng.uniform(0.1, 1.0, size=n).astype(np.float32))
-    # masked rows AND fractional GOSS-style weights in one mask vector
-    m = jnp.asarray(np.where(rng.uniform(size=n) < 0.8,
-                             rng.uniform(0.1, 2.5, size=n),
-                             0.0).astype(np.float32))
-    ref = H.fold_hist(H._hist_scatter(bins, g, h, m, B))
-    for name, spec in ov.VARIANTS.items():
-        if not spec.supports(B):
-            assert name == "packed" and B == 255
-            continue
-        got = jax.jit(lambda *x, v=name: H.fold_hist(
-            H._hist_pallas(*x, B, variant=v)))(bins, g, h, m)
-        err = float(jnp.max(jnp.abs(got - ref) / (jnp.abs(ref) + 1.0)))
-        assert err < H.HIST_PARITY_TOL, (name, B, err)
-        print("PROD_OK", name, B, err)
-    # the shootout's single-block shell must match too (registry shell #2)
-    bins_t = jnp.asarray(np.ascontiguousarray(np.asarray(bins).T))
-    for name in ("base", "packed", "staged", "int8"):
-        spec = ov.VARIANTS[name]
-        if not spec.supports(B):
-            continue
-        prep, run = ov.make_bench_kernel(name, f, B, 128, interpret=True)
-        got = H.fold_hist(jax.jit(run)(bins_t, jax.jit(prep)(g, h, m)))
-        err = float(jnp.max(jnp.abs(got - ref) / (jnp.abs(ref) + 1.0)))
-        assert err < H.HIST_PARITY_TOL, ("bench", name, B, err)
-        print("BENCH_OK", name, B, err)
-print("PARITY_DONE")
-"""
-
-
-def test_every_variant_interpret_parity_vs_scatter():
-    out = _run_clean(_PARITY_CHECK)
-    assert "PARITY_DONE" in out
-    # every registry family must have been exercised on the production shell
+def _parity_cases():
     from lightgbm_tpu.ops import onehot_variants as ov
-    for name in ov.VARIANT_NAMES:
-        assert f"PROD_OK {name}" in out, out
+    return [(v, b) for b in (64, 256) for v in ov.VARIANT_NAMES
+            if ov.VARIANTS[v].supports(b)]
 
 
-_LEAVES_CHECK = r"""
-import numpy as np, jax, jax.numpy as jnp
-import lightgbm_tpu.ops.histogram as H
-from lightgbm_tpu.ops import onehot_variants as ov
-
-rng = np.random.default_rng(0)
-BR, NB, NC, k = 128, 6, 10, 4
-C = BR * NB
-for B, names in ((64, ("base", "packed", "staged", "int8")),
-                 (255, ("base", "int8"))):
-    comb = jnp.asarray(rng.integers(0, B, size=(C, NC)).astype(np.uint8))
-    g = jnp.asarray(rng.normal(size=C).astype(np.float32))
-    h = jnp.asarray(rng.random(C).astype(np.float32))
-    m = jnp.asarray(np.where(rng.random(C) > 0.2,
-                             rng.uniform(0.5, 1.5, size=C), 0.0)
-                    .astype(np.float32))
-    # slot k-2 deliberately empty: must come back zeros, not stale memory
-    bl = np.sort(rng.integers(0, k, size=NB)).astype(np.int32)
-    bl = jnp.asarray(np.where(bl == k - 2, k - 1, bl))
-    ref = H.fold_hist(H.build_histogram_leaves(
-        comb, g, h, m, bl, k, B, method="scatter", block_rows=BR, f_limit=7))
-    assert ref.shape[1] == 7       # fallback slices BEFORE scattering now
-    for name in names:
-        got = jax.jit(lambda *x, v=name: H.fold_hist(H._hist_leaves_pallas(
-            *x, k, B, BR, 7, variant=v)))(comb, g, h, m, bl)
-        err = float(jnp.max(jnp.abs(got - ref) / (jnp.abs(ref) + 1.0)))
-        assert err < H.HIST_PARITY_TOL, (name, B, err)
-        assert float(jnp.abs(got[k - 2]).max()) == 0.0
-        print("LEAVES_OK", name, B, err)
-print("LEAVES_DONE")
-"""
+@pytest.mark.parametrize("variant,max_bin", _parity_cases())
+def test_kernel_parity_vs_scatter(variant, max_bin):
+    """Both production kernels, every variant, at the lane-packing width and
+    at the 256-wide kernel histogram ``max_bin=255`` trains with: a row
+    count that is no block multiple, one empty leaf slot, masked and
+    fractionally weighted rows (``chip_smoke.run_kernel_checks`` makes all
+    three)."""
+    import chip_smoke
+    from lightgbm_tpu.ops.histogram import HIST_PARITY_TOL
+    errs = chip_smoke.run_kernel_checks(
+        (variant,), max_bin=max_bin, n_feat=9, slots=4, block_rows=128,
+        rows=2500)
+    assert set(errs) == {f"hist_pallas/{variant}",
+                         f"hist_leaves_pallas/{variant}"}
+    bad = {k: e for k, e in errs.items() if not e < HIST_PARITY_TOL}
+    assert not bad, bad
 
 
-def test_leaves_kernel_variants_interpret_parity():
-    out = _run_clean(_LEAVES_CHECK)
-    assert "LEAVES_DONE" in out
-    assert "LEAVES_OK packed 64" in out
+def test_parity_cases_cover_the_registry():
+    from lightgbm_tpu.ops import onehot_variants as ov
+    cases = _parity_cases()
+    assert {v for v, _ in cases} == set(ov.VARIANT_NAMES)
+    assert ("packed", 64) in cases and ("packed", 256) not in cases
 
 
 _E2E_CHECK = r"""
