@@ -40,6 +40,10 @@ SCOPES = (
     "lgbm/root",
     "lgbm/frontier_round",
     "lgbm/frontier_round/select",
+    # the round's [N]-pass, in row order: decide = slot comparisons, bin
+    # read, go-left; rank = the slot update; scatter = the one sort that
+    # groups and counts the smaller children's rows (no scatter is left
+    # under it: the name is the benchmark's)
     "lgbm/frontier_round/partition",
     "lgbm/frontier_round/partition/decide",
     "lgbm/frontier_round/partition/rank",

@@ -22,18 +22,20 @@ down any path).  Therefore:
   numbering (left child keeps the parent's leaf id, right child takes the
   next fresh id) — with no sequential priority queue anywhere.
 
-Splits applied beyond the budget ("overshoot") revert for free: a dropped
-split's two child segments are contiguous inside the parent's recorded row
-range, so the parent simply remains a leaf over that range.
+Splits applied beyond the budget ("overshoot") revert for free: every row
+carries the leaf slot it ended in, a slot knows the split record that made
+it, and a dropped record's rows belong to the nearest selected record above
+it (``_leaf_of_slots``), so the parent simply remains a leaf.
 
-Per round the heavy work is batched: ONE element-gather reads every row's
-bin in its leaf's split column, ONE segmented cumsum stable-partitions all k
-segments of the row permutation, ONE leaf-grouped row gather feeds the
-batched Pallas histogram kernel (``build_histogram_leaves``), and the 2k
-child split searches ride a single vmapped ``find_best_split``.  This
-amortizes the sequential tail (per-split small-op overhead, ~33% of round-3
-tree time) and halves gather traffic (only smaller-sibling rows are ever
-row-gathered; partition decisions ride a byte-sized element gather).
+Per round the heavy work is batched and stays in row order: every row
+compares its slot with the k selected ones and reads its bin at its own
+index in that slot's split column (k contiguous column reads, no per-row
+gather), ONE sort groups the rows of the k smaller children and its keys
+give the children's counts, ONE leaf-grouped row gather feeds the batched Pallas
+histogram kernel (``build_histogram_leaves``), and the 2k child split
+searches ride a single vmapped ``find_best_split``.  This amortizes the
+sequential tail (per-split small-op overhead, ~33% of round-3 tree time)
+and halves gather traffic (only smaller-sibling rows are ever row-gathered).
 
 Scope: serial, data-, feature- and voting-parallel modes without
 cross-leaf-COUPLED features.  Monotone constraints, CEGB, interaction
@@ -46,7 +48,6 @@ stream of the same structure as the sequential grower's step-keyed one.
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
@@ -78,14 +79,16 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     rounds of 2**31 rows).  Each round passes every one of the ``n`` rows
     whatever it splits, so the two say what share of that work was useful.
 
-    The invariant the rounds rest on: a leaf is one contiguous range of the
-    row permutation ``perm``, ``[leaf_begin, leaf_begin + leaf_nrows)``, and a
-    split stable-partitions that range in place.  So a position's leaf is
-    derived from the ranges wherever it is needed and is never stored per
-    row: a round compares each position against the at most ``frontier_k``
-    ranges it splits (``_spread_by_range``), and the final node assignment
-    is a running sum of the leaf id's steps at the leaves' begins
-    (``grower.node_assign_from_ranges``).
+    The invariant the rounds rest on: a row keeps its leaf, not its place.
+    The loop carries ``row_slot``, every row's leaf slot, and nothing else of
+    size ``n``; rows are never moved.  A round compares each row's slot with
+    the at most ``frontier_k`` slots it splits (``_spread_by_slot``), reads
+    the row's bin at its own index in the split column (``_bin_of_rows``) and
+    writes the right child's slot with a select.  Only the histograms need
+    rows grouped by leaf, and only the smaller children's: one sort a round
+    (``_group_smaller_children``) puts their ids in front, ascending inside a
+    child as a stable partition would leave them, and counts them.  The final node assignment
+    is a table from slot to leaf (``_leaf_of_slots``) read by selects.
 
     Sums: the histogram store holds pairs (``histogram.py``: a float32 sum
     and what it rounds away), siblings are subtracted in pairs, a leaf's
@@ -98,7 +101,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     The device phases carry ``jax.named_scope`` names (``obs/scopes.py``
     lists them): compile-time metadata, nothing at run time.
     """
-    from .grower import TreeArrays, _BestSplits, node_assign_from_ranges
+    from .grower import TreeArrays, _BestSplits
 
     n, n_cols = bins.shape
     if efb is not None:
@@ -165,8 +168,9 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
         jnp.stack([grad, hess, row_weight], axis=1), bins.dtype
     ).reshape(n, _gh_cols)
     comb = jnp.concatenate([bins, _gh_packed], axis=1)    # [N, NC + gh_cols]
-    ncc = comb.shape[1]
-    comb_flat = comb.reshape(-1)
+    # [NC, N]: a split column is one contiguous row (the chip keeps ``bins``
+    # feature-major, so the transposition is a bitcast there)
+    bins_t = bins.T
 
     def _unpack_gh(combb):
         cap = combb.shape[0]
@@ -348,9 +352,8 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                            jnp.array([True]))
 
         state = dict(
-            perm=jnp.arange(n, dtype=jnp.int32),
-            leaf_begin=jnp.zeros(LS, jnp.int32),
-            leaf_nrows=jnp.zeros(LS, jnp.int32).at[0].set(n),
+            row_slot=jnp.zeros(n, jnp.int32),     # every row's leaf slot
+            leaf_nrows=jnp.zeros(LS, jnp.int32).at[0].set(n),   # raw, local
             leaf_depth=jnp.zeros(LS, jnp.int32),
             leaf_sum_g=jnp.zeros(LS, jnp.float32).at[0].set(tot[0]),
             leaf_weight=jnp.zeros(LS, jnp.float32).at[0].set(tot[1]),
@@ -381,9 +384,6 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             sp_rcount=jnp.zeros(S, jnp.float32),
             sp_value=jnp.zeros(S, jnp.float32),   # split-leaf output
             sp_count=jnp.zeros(S, jnp.float32),   # split-leaf weighted count
-            sp_begin=jnp.zeros(S, jnp.int32),     # split-leaf row range (local)
-            sp_nrows=jnp.zeros(S, jnp.int32),
-            sp_nleft=jnp.zeros(S, jnp.int32),     # raw left row count (local)
             n_applied=jnp.int32(0),
             # counters (with_stats): rounds taken, rows of the leaves split
             n_rounds=jnp.int32(0),
@@ -428,7 +428,6 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             sel_iscat = is_categorical[sel_feat]
             sel_nanbin = nan_bins[sel_feat]
             sel_col, sel_decode = col_tables(sel_feat)
-            sel_beg = st["leaf_begin"][sel]
             sel_rows = st["leaf_nrows"][sel]
             sel_gain = b.gain[sel]
             sp_ghat_i = jnp.minimum(sel_gain, st["leaf_cghat"][sel])
@@ -438,60 +437,53 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             # so all shards histogram the same side (grower.py apply_split)
             left_smaller = b.lc[sel] <= b.rc[sel]
 
-        # ---- [N]-pass: decide + segmented stable partition ----------------
-        # A leaf is one contiguous range of ``perm``, so everything a position
-        # needs of its leaf's split is piecewise constant over the at most k
-        # selected ranges: _spread_by_range has it by comparisons.  The one
-        # per-row gather is the bin byte, the one scatter is ``perm``.
+        # ---- [N]-pass, in row order: decide, count, group ------------------
+        # A row carries its leaf slot, so everything it needs of its leaf's
+        # split is spread by comparing that slot with the at most k selected
+        # ones (_spread_by_slot), and its bin is at its own index in the
+        # split column, a contiguous row of ``bins_t``.  Nothing is gathered
+        # or scattered per row; the one data movement is the sort that groups
+        # the smaller children's rows for the histograms.
         with jax.named_scope("partition"):
-            pos = jnp.arange(n, dtype=jnp.int32)
-            spread = functools.partial(_spread_by_range, pos, sel_beg,
-                                       sel_rows, valid)
+            row_slot = st["row_slot"]
             with jax.named_scope("decide"):
-                act, (col_p, nb_p, thr_p, dleft_p, iscat_p,
-                      *decode_p) = spread((sel_col, sel_nanbin, sel_thr,
-                                           sel_dleft, sel_iscat, *sel_decode))
-                rowid = st["perm"]
+                in_slot = [(row_slot == sel[i]) & valid[i] for i in range(k)]
+                act, (nb_p, thr_p, dleft_p, iscat_p, right_p,
+                      *decode_p) = _spread_by_slot(
+                    in_slot, (sel_nanbin, sel_thr, sel_dleft, sel_iscat,
+                              right_slot, *sel_decode))
                 if mode == "feature":
-                    # columns are sharded (col_p is the split feature's global
-                    # index): the owner shard selects its local column
-                    # and ONE [N] psum broadcasts it (rows are replicated, so every
-                    # shard's perm/selection state is identical; grower.py
-                    # partition_and_hist does the same per split — here it is once
-                    # per ROUND)
-                    local_ix = jnp.clip(col_p - f_start, 0, f - 1)
-                    owns = (col_p >= f_start) & (col_p < f_start + f)
-                    colv_loc = jnp.take(comb_flat,
-                                        rowid * ncc + local_ix).astype(jnp.int32)
-                    colv = jax.lax.psum(jnp.where(owns & act, colv_loc, 0), axis)
+                    # columns are sharded (sel_col is the split feature's
+                    # global index): the owner shard reads its local column
+                    # and ONE [N] psum broadcasts it (rows are replicated, so
+                    # every shard's row_slot is identical; grower.py
+                    # partition_and_hist does the same per split — here it is
+                    # once per ROUND)
+                    owns = (sel_col >= f_start) & (sel_col < f_start + f)
+                    colv = jax.lax.psum(_bin_of_rows(
+                        bins_t, jnp.clip(sel_col - f_start, 0, f - 1),
+                        [m & owns[i] for i, m in enumerate(in_slot)]), axis)
                 else:
-                    colv = jnp.take(comb_flat,
-                                    rowid * ncc + col_p).astype(jnp.int32)
-                    colv = decode_col(colv, *decode_p)
+                    colv = decode_col(_bin_of_rows(bins_t, sel_col, in_slot),
+                                      *decode_p)
                 is_miss = (colv == nb_p) & (nb_p >= 0)
                 # word min(colv >> 5, cw - 1) of the leaf's categorical bit set
-                _, words_p = spread([sel_cbits[:, w] for w in range(cw)])
+                _, words_p = _spread_by_slot(
+                    in_slot, [sel_cbits[:, w] for w in range(cw)])
                 wsel = words_p[0]
                 for w in range(1, cw):
                     wsel = jnp.where(colv >> 5 >= w, words_p[w], wsel)
                 gl_cat = ((wsel >> (colv & 31)) & 1) > 0
                 gl = jnp.where(iscat_p, gl_cat,
                                jnp.where(is_miss, dleft_p, colv <= thr_p))
-                gl_a = gl & act
-            with jax.named_scope("rank"):
-                cumL = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                        jnp.cumsum(gl_a.astype(jnp.int32))])
-                baseL_i = jnp.take(cumL, sel_beg)
-                nl_i = jnp.take(cumL, sel_beg + sel_rows) - baseL_i   # [k] raw left
-                _, (beg_p, baseL_p, nl_p) = spread((sel_beg, baseL_i, nl_i))
-                rankL = cumL[1:] - gl_a.astype(jnp.int32) - baseL_p   # exclusive
-                rankA = pos - beg_p        # every row of a selected range is active
-                rankR = rankA - rankL
             with jax.named_scope("scatter"):
-                new_pos = jnp.where(act,
-                                    beg_p + jnp.where(gl, rankL, nl_p + rankR),
-                                    pos)
-                perm_new = jnp.zeros(n, jnp.int32).at[new_pos].set(rowid)
+                rows_small, small_n = _group_smaller_children(
+                    in_slot, gl, left_smaller)
+            with jax.named_scope("rank"):
+                # [k] raw left, from the smaller child's count: no sum over
+                # the rows, which would decide every row again
+                nl_i = jnp.where(left_smaller, small_n, sel_rows - small_n)
+                row_slot_new = jnp.where(act & ~gl, right_p, row_slot)
 
         # ---- leaf bookkeeping --------------------------------------------
         with jax.named_scope("bookkeeping"):
@@ -500,7 +492,6 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             nr_i = sel_rows - nl_i
             rows_sel = jnp.sum(jnp.where(valid, sel_rows, 0))   # <= n
             depth_c = st["leaf_depth"][sel] + 1
-            leaf_begin = upd(st["leaf_begin"], right_slot, sel_beg + nl_i, valid)
             leaf_nrows = upd(upd(st["leaf_nrows"], sel, nl_i, valid),
                              right_slot, nr_i, valid)
             leaf_depth = upd(upd(st["leaf_depth"], sel, depth_c, valid),
@@ -550,15 +541,11 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                 sp_rout=rec(st["sp_rout"], b.rout[sel]),
                 sp_value=rec(st["sp_value"], sp_value_i),
                 sp_count=rec(st["sp_count"], st["leaf_count"][sel]),
-                sp_begin=rec(st["sp_begin"], sel_beg),
-                sp_nrows=rec(st["sp_nrows"], sel_rows),
-                sp_nleft=rec(st["sp_nleft"], nl_i),
             )
 
         # ---- batched smaller-child histograms -----------------------------
         with jax.named_scope("hist_gather"):
-            small_n = jnp.where(valid, jnp.where(left_smaller, nl_i, nr_i), 0)
-            small_beg = jnp.where(left_smaller, sel_beg, sel_beg + nl_i)
+            small_beg = jnp.cumsum(small_n) - small_n    # in rows_small
             nblocks = jnp.maximum(-(-small_n // BR), 1)   # >=1: every slot inits
             blk_start = jnp.concatenate([jnp.zeros(1, jnp.int32),
                                          jnp.cumsum(nblocks)])[:-1]
@@ -567,7 +554,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
         def mk_branch(C2):
             NB = C2 // BR
 
-            def br(perm_arg):
+            def br(rows_arg):
                 with jax.named_scope("hist_gather"):
                     blk = jnp.arange(NB, dtype=jnp.int32)
                     i_of_blk = jnp.clip(
@@ -578,7 +565,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                     local = (qb - blk_start[i_of_q]) * BR + (q % BR)
                     okrow = (local < small_n[i_of_q]) & (qb < nb_tot)
                     row_pos = jnp.clip(small_beg[i_of_q] + local, 0, n - 1)
-                    rid = jnp.take(perm_arg, row_pos)
+                    rid = jnp.take(rows_arg, row_pos)
                     combb = jnp.take(comb, jnp.where(okrow, rid, 0), axis=0)
                     ghb = _unpack_gh(combb)
                     m = jnp.where(okrow, ghb[:, 2], 0.0)
@@ -594,7 +581,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             idx = jnp.searchsorted(jnp.asarray(caps2, jnp.int32),
                                    nb_tot * BR)
         hist_small = jax.lax.switch(idx, [mk_branch(c) for c in caps2],
-                                    perm_new)
+                                    rows_small)
         with jax.named_scope("hist"):
             hist_small = reduce_hist(hist_small)              # [k, NC, Bb, 6]
 
@@ -665,8 +652,7 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                 sp_rcount=rec(st["sp_rcount"], rc))
 
         return dict(
-            perm=perm_new,
-            leaf_begin=leaf_begin, leaf_nrows=leaf_nrows,
+            row_slot=row_slot_new, leaf_nrows=leaf_nrows,
             leaf_depth=leaf_depth, leaf_sum_g=leaf_sum_g,
             leaf_weight=leaf_weight, leaf_count=leaf_count,
             leaf_cghat=leaf_cghat, leaf_cs=leaf_cs, leaf_il=leaf_il,
@@ -807,14 +793,12 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
             num_leaves=(nsel + 1).astype(jnp.int32),
         )
 
-        # ---- node assignment from final leaf row ranges ------------------------
-        lbeg = state["sp_begin"][src]
-        lnl = state["sp_nleft"][src]
-        leaf_beg = leafset(jnp.zeros(L, jnp.int32), lbeg, lbeg + lnl)
-        leaf_nr = leafset(jnp.zeros(L, jnp.int32), lnl,
-                          state["sp_nrows"][src] - lnl)
-        leaf_nr = leaf_nr.at[0].set(jnp.where(no_split, n, leaf_nr[0]))
-        node_assign = node_assign_from_ranges(state["perm"], leaf_beg, leaf_nr)
+        # ---- node assignment: a row's leaf from its last slot --------------
+        node_assign = _leaf_of_rows(
+            state["row_slot"],
+            _leaf_of_slots(state["leaf_cs"], state["leaf_il"],
+                           state["sp_parent"], state["sp_is_left"],
+                           pos_of_rec, leaf_id_of_node))
     if not with_stats:
         return tree, node_assign
     stats = jnp.stack([state["n_rounds"], state["rows_sel_hi"],
@@ -825,25 +809,97 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     return tree, node_assign, stats
 
 
-def _spread_by_range(pos, beg, rows, valid, tables):
-    """Per-position values of per-slot tables, by range comparison.
+def _spread_by_slot(in_slot, tables):
+    """Per-row values of per-slot tables, by the rows' slot masks.
 
-    Slot ``i`` owns the positions ``[beg[i], beg[i] + rows[i])`` if
-    ``valid[i]``; the valid slots' ranges do not overlap, and an empty or
-    invalid slot owns nothing.  Returns ``(act, values)``: ``act[p]`` says
-    that some slot owns position ``p``, and ``values[j][p]`` is
-    ``tables[j][i]`` of that slot (zero where there is none).  The loop over
-    the ``k`` slots is unrolled into selects that XLA fuses into elementwise
-    work over ``pos``: no per-row gather, no ``[N, k]`` intermediate.
+    ``in_slot[i]`` is a bool ``[n]``: the rows that sit in the ``i``-th
+    selected leaf slot (all false for a slot that is not valid); a row is in
+    at most one.  Returns ``(act, values)``: ``act[r]`` says that some slot
+    holds row ``r``, and ``values[j][r]`` is ``tables[j][i]`` of that slot
+    (zero where there is none).  The loop over the ``k`` slots is unrolled
+    into selects that XLA fuses into elementwise work over the rows: no
+    per-row gather, no ``[N, k]`` intermediate.
     """
-    end = beg + jnp.where(valid, rows, 0)
-    act = jnp.zeros(pos.shape, bool)
-    values = [jnp.zeros(pos.shape, t.dtype) for t in tables]
-    for i in range(beg.shape[0]):
-        in_i = (pos >= beg[i]) & (pos < end[i])
+    shape = in_slot[0].shape
+    act = jnp.zeros(shape, bool)
+    values = [jnp.zeros(shape, t.dtype) for t in tables]
+    for i, in_i in enumerate(in_slot):
         act = act | in_i
         values = [jnp.where(in_i, t[i], v) for t, v in zip(tables, values)]
     return act, values
+
+
+def _bin_of_rows(bins_t, cols, in_slot):
+    """int32 ``[n]``: row ``r``'s bin in column ``cols[i]`` of the slot ``i``
+    whose mask holds it, 0 for a row in no slot.  ``bins_t`` is
+    ``[n_cols, n]``, so a slot's column is one contiguous row of it, read at
+    the rows' own indices."""
+    colv = jnp.zeros(bins_t.shape[1:], bins_t.dtype)
+    for i, in_i in enumerate(in_slot):
+        col_i = jax.lax.dynamic_index_in_dim(bins_t, cols[i], 0, keepdims=False)
+        colv = jnp.where(in_i, col_i, colv)
+    return colv.astype(jnp.int32)
+
+
+def _group_smaller_children(in_slot, go_left, left_smaller):
+    """Row ids with the rows of the round's smaller children in front.
+
+    The rows of slot 0's smaller child come first, then slot 1's, and so on,
+    ascending inside each child: the order a stable partition of ascending
+    ids leaves them in.  Behind them, every other row.  One sort on
+    ``(child, row id)``: the pair is unique, so the result is determined.
+    Returns the ids and ``int32[k]``, the rows of each smaller child, read
+    off the sorted keys (slot ``i``'s start in the ids is the sum before it).
+    """
+    k = len(in_slot)
+    n = go_left.shape[0]
+    child = jnp.full(n, k, jnp.int32)
+    for i, in_i in enumerate(in_slot):
+        child = jnp.where(in_i & (go_left == left_smaller[i]), i, child)
+    child, rows = jax.lax.sort((child, jnp.arange(n, dtype=jnp.int32)),
+                               num_keys=2)
+    # counted on the sorted keys, which no fusion can compute again
+    counts = jnp.sum(child[None, :] == jnp.arange(k, dtype=jnp.int32)[:, None],
+                     axis=1, dtype=jnp.int32)
+    return rows, counts
+
+
+def _leaf_of_slots(leaf_cs, leaf_il, sp_parent, sp_is_left, pos_of_rec,
+                   leaf_id_of_node):
+    """int32 ``[LS]``: the final tree's leaf of the rows in each leaf slot.
+
+    A slot was made by split record ``leaf_cs`` as its left (``leaf_il``) or
+    right child.  From there, up ``sp_parent``/``sp_is_left`` to the first
+    record the replay selected as a node (``pos_of_rec >= 0``): records
+    below it were applied beyond the budget, and their rows stay in that
+    node's child.  The left child keeps the leaf id the node split
+    (``leaf_id_of_node``), the right child of node ``j`` is leaf ``j + 1``;
+    a slot under no selected record is leaf 0.
+    """
+    def dropped(cs):
+        return (cs >= 0) & (pos_of_rec[jnp.clip(cs, 0)] < 0)
+
+    def up(c):
+        cs, il = c
+        r = jnp.clip(cs, 0)
+        d = dropped(cs)
+        return jnp.where(d, sp_parent[r], cs), jnp.where(d, sp_is_left[r], il)
+
+    cs, il = jax.lax.while_loop(lambda c: jnp.any(dropped(c[0])), up,
+                                (leaf_cs, leaf_il))
+    node = pos_of_rec[jnp.clip(cs, 0)]
+    return jnp.where(cs >= 0,
+                     jnp.where(il, leaf_id_of_node[jnp.clip(node, 0)], node + 1),
+                     0)
+
+
+def _leaf_of_rows(row_slot, leaf_of_slot):
+    """``leaf_of_slot[row_slot]`` as selects over the slots (a table of a few
+    hundred entries is never gathered per row)."""
+    out = jnp.zeros(row_slot.shape, jnp.int32)
+    for s in range(leaf_of_slot.shape[0]):
+        out = jnp.where(row_slot == s, leaf_of_slot[s], out)
+    return out
 
 
 def _as_batch(s: SplitResult, m: int) -> SplitResult:

@@ -369,57 +369,98 @@ def test_bynode_extra_trees_parallel_frontier(clf_data, learner):
 
 
 # ---------------------------------------------------------------------------
-# the [N]-pass rests on one invariant: a leaf is one contiguous range of
-# ``perm``, so what a position needs of its leaf comes from range comparisons
+# the [N]-pass rests on one invariant: a row carries its leaf slot, so what it
+# needs of its leaf's split comes from comparing that slot with the selected
 @pytest.mark.parametrize("layout", ["random", "whole"])
 @pytest.mark.parametrize("k", [1, 3, 16])
-def test_spread_by_range_matches_gathers(k, layout):
-    """_spread_by_range against the per-row gathers it replaced
-    (``slot_of_leaf[pos_leaf]``, then ``table[slot]``)."""
+def test_spread_by_slot_matches_gathers(k, layout):
+    """_spread_by_slot and _bin_of_rows against the per-row gathers they stand
+    for (``slot_of_leaf[row_leaf]``, then ``table[slot]`` and
+    ``bins[row, col[slot]]``)."""
     import jax.numpy as jnp
-    from lightgbm_tpu.ops.frontier import _spread_by_range
-    n, LS = 997, 40
+    from lightgbm_tpu.ops.frontier import _bin_of_rows, _spread_by_slot
+    n, LS, f = 997, 40, 7
     for seed in range(5):
         rng = np.random.default_rng(100 * k + seed)
-        if layout == "whole":           # one leaf is the whole array
-            nrows = np.zeros(LS, np.int64)
-            nrows[rng.integers(LS)] = n
+        if layout == "whole":           # one leaf holds every row
+            row_leaf = np.full(n, rng.integers(LS))
         else:                           # about half of the leaves are empty
-            live = rng.random(LS) < 0.5
-            live[rng.integers(LS)] = True
-            cuts = np.sort(rng.integers(0, n + 1, live.sum() - 1))
-            nrows = np.zeros(LS, np.int64)
-            nrows[live] = np.diff(np.concatenate([[0], cuts, [n]]))
-        order = rng.permutation(LS)     # leaf slots in no order of position
-        begin = np.zeros(LS, np.int64)
-        begin[order] = np.cumsum(nrows[order]) - nrows[order]
-        pos_leaf = np.zeros(n, np.int64)
-        for leaf in range(LS):
-            pos_leaf[begin[leaf]:begin[leaf] + nrows[leaf]] = leaf
+            live = np.flatnonzero(rng.random(LS) < 0.5)
+            live = live if len(live) else np.array([rng.integers(LS)])
+            row_leaf = rng.choice(live, n)
         sel = rng.permutation(LS)
         valid = rng.random(k) < 0.7
         if layout == "whole":           # ... and a valid slot selects it
-            whole = np.argmax(nrows)
+            whole = row_leaf[0]
             sel = np.concatenate([[whole], sel[sel != whole]])
             valid[0] = True
         sel = sel[:k]
         tables = (rng.integers(-5, 300, k).astype(np.int32),
                   rng.random(k) < 0.5,
                   rng.integers(0, 2 ** 31 - 1, k).astype(np.int32))
+        bins = rng.integers(0, 256, (n, f)).astype(np.uint8)
+        cols = rng.integers(0, f, k).astype(np.int32)
 
         slot_of_leaf = np.full(LS, -1)
         slot_of_leaf[sel[valid]] = np.arange(k)[valid]
-        si = slot_of_leaf[pos_leaf]
+        si = slot_of_leaf[row_leaf]
         want_act = si >= 0
-        act, got = _spread_by_range(
-            jnp.arange(n, dtype=jnp.int32), jnp.asarray(begin[sel], jnp.int32),
-            jnp.asarray(nrows[sel], jnp.int32), jnp.asarray(valid),
-            tuple(jnp.asarray(t) for t in tables))
+        row_slot = jnp.asarray(row_leaf, jnp.int32)
+        in_slot = [(row_slot == int(sel[i])) & bool(valid[i]) for i in range(k)]
+        act, got = _spread_by_slot(in_slot, tuple(jnp.asarray(t) for t in tables))
         np.testing.assert_array_equal(np.asarray(act), want_act)
         for t, g in zip(tables, got):
             assert g.dtype == t.dtype
             np.testing.assert_array_equal(
                 np.asarray(g), np.where(want_act, t[np.maximum(si, 0)], 0))
+        colv = _bin_of_rows(jnp.asarray(bins).T, jnp.asarray(cols), in_slot)
+        assert colv.dtype == jnp.int32
+        np.testing.assert_array_equal(
+            np.asarray(colv),
+            np.where(want_act, bins[np.arange(n), cols[np.maximum(si, 0)]], 0))
+
+
+@pytest.mark.parametrize("k", [1, 3, 16])
+def test_grouped_rows_are_the_stable_partitions_ranges(k):
+    """_group_smaller_children against a NumPy stable partition: with the rows
+    kept grouped by leaf as a stable partition from ``arange(n)`` keeps them,
+    split every selected leaf's range in place; the smaller child's range is,
+    child by child and in order, what the one sort puts in front, and its
+    length the count the sort's keys give."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.frontier import _group_smaller_children
+    n, LS = 1203, 24
+    for seed in range(4):
+        rng = np.random.default_rng(10 * k + seed)
+        row_leaf = rng.integers(0, LS, n)
+        row_leaf[row_leaf == 5] = 6                 # an empty leaf, selected below
+        sel = np.concatenate([[5], rng.permutation(LS)])[:k] if k > 1 \
+            else rng.permutation(LS)[:1]
+        valid = rng.random(k) < 0.8
+        go_left = rng.random(n) < rng.random(LS)[row_leaf]
+        left_smaller = rng.random(k) < 0.5          # not the count's: any side
+
+        row_slot = jnp.asarray(row_leaf, jnp.int32)
+        in_slot = [(row_slot == int(sel[i])) & bool(valid[i]) for i in range(k)]
+        got, counts = (np.asarray(a) for a in _group_smaller_children(
+            in_slot, jnp.asarray(go_left), jnp.asarray(left_smaller)))
+        assert sorted(got) == list(range(n))
+
+        perm = np.argsort(row_leaf, kind="stable")  # leaves as ranges of perm
+        at = 0
+        for i in range(k):
+            if not valid[i]:
+                assert counts[i] == 0
+                continue
+            rows = perm[row_leaf[perm] == sel[i]]   # the leaf's range
+            parted = np.concatenate([rows[go_left[rows]], rows[~go_left[rows]]])
+            nl = int(go_left[rows].sum())
+            small = parted[:nl] if left_smaller[i] else parted[nl:]
+            assert counts[i] == len(small)
+            np.testing.assert_array_equal(got[at:at + len(small)], small)
+            at += len(small)
+        # behind the children, every other row
+        assert not np.isin(got[at:], got[:at]).any()
 
 
 def _walk_eqns(jaxpr, scope=""):
@@ -459,10 +500,10 @@ def _small_grow_case(n=1000, f=5, categorical=True, **cfg_kw):
     return args, GrowerConfig(**kw)
 
 
-def test_partition_gathers_one_byte_and_scatters_perm_only():
-    """Under the ``partition`` scope the only [n]-sized gather is the bin
-    look-up and the only [n]-sized scatter is ``perm``; ``perm`` is the only
-    [n]-sized array the round loop carries (no per-position leaf id)."""
+def test_partition_sorts_once_and_moves_no_row():
+    """Under the ``partition`` scope nothing is gathered into an [n]-sized
+    result and nothing is scattered to [n] places; the one data movement is
+    one sort; the round loop carries one [n]-sized array, the rows' slots."""
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.ops.frontier import grow_tree_frontier
@@ -471,23 +512,15 @@ def test_partition_gathers_one_byte_and_scatters_perm_only():
     jaxpr = jax.make_jaxpr(
         lambda *a: grow_tree_frontier(*a, cfg, with_stats=True))(*args)
 
-    gathers, scatters, loops = [], [], []
-    for eqn, scope in _walk_eqns(jaxpr.jaxpr):
-        name = eqn.primitive.name
-        if name == "while" and any(
-                "partition" in s for _, s in _walk_eqns(
-                    eqn.params["body_jaxpr"].jaxpr)):
-            loops.append(eqn)
-        if "partition" not in scope:
-            continue
-        if eqn.outvars and eqn.outvars[0].aval.shape == (n,):
-            if name == "gather":
-                gathers.append(eqn.invars[0].aval)
-            elif name.startswith("scatter"):
-                scatters.append(eqn.outvars[0].aval)
-    ncc = f + 12                               # bins + packed (g, h, w) bytes
-    assert [(a.shape, a.dtype) for a in gathers] == [((n * ncc,), jnp.uint8)]
-    assert [(a.shape, a.dtype) for a in scatters] == [((n,), jnp.int32)]
+    loops = [eqn for eqn, _ in _walk_eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == "while" and any(
+                 "partition" in s for _, s in _walk_eqns(
+                     eqn.params["body_jaxpr"].jaxpr))]
+    gathers, scatters = _per_row_ops(jaxpr.jaxpr, n, "partition")
+    assert gathers == [] and scatters == []
+    sorts = [eqn for eqn, scope in _walk_eqns(jaxpr.jaxpr)
+             if eqn.primitive.name == "sort" and "partition" in scope]
+    assert [[v.aval.shape for v in e.invars] for e in sorts] == [[(n,), (n,)]]
     assert len(loops) == 1
     n_carried = len(loops[0].params["body_jaxpr"].out_avals)
     carried = [v.aval for v in loops[0].invars[-n_carried:]]
@@ -509,9 +542,9 @@ def _per_row_ops(jaxpr, n, under):
     return gathers, scatters
 
 
-def test_finalize_looks_nothing_up_per_position():
-    """Under ``lgbm/finalize`` no gather has an [n]-sized result, and the one
-    scatter to [n] places takes the positions' leaves to row order."""
+def test_finalize_moves_no_row():
+    """Under ``lgbm/finalize`` a row's leaf comes from its slot by selects:
+    no gather has an [n]-sized result and nothing is scattered to [n] places."""
     import jax
     from lightgbm_tpu.ops.frontier import grow_tree_frontier
     n = 1000
@@ -519,7 +552,39 @@ def test_finalize_looks_nothing_up_per_position():
     jaxpr = jax.make_jaxpr(lambda *a: grow_tree_frontier(*a, cfg))(*args)
     gathers, scatters = _per_row_ops(jaxpr.jaxpr, n, "lgbm/finalize")
     assert gathers == []
-    assert scatters == [(n,)]
+    assert scatters == []
+
+
+@pytest.mark.parametrize("categorical", [False, True], ids=["plain", "categorical"])
+def test_leaf_of_slots_when_splits_are_dropped(categorical, monkeypatch):
+    """Sixteen leaves a round under a budget of eight: the rounds apply more
+    splits than the tree keeps, and the rows of a dropped split's children
+    stay in the leaf it split.  The rows' leaves, from their last slots, are
+    the leaves the tree's own traversal finds."""
+    import jax
+    from lightgbm_tpu.ops.frontier import grow_tree_frontier
+    from lightgbm_tpu.ops.predict import predict_leaf_binned
+    args, cfg = _small_grow_case(3000, categorical=categorical, num_leaves=8,
+                                 frontier_k=16, sorted_cat=categorical)
+    if categorical:     # gradients that follow the first column's categories
+        col = np.asarray(args[0][:, 0]).astype(int)
+        args = (args[0], args[1] + 3.0 * (col * 7 % 5 < 2), *args[2:])
+    applied = []
+    while_loop = jax.lax.while_loop
+
+    def spy(cond, body, init):          # the round loop's final state, eagerly
+        out = while_loop(cond, body, init)
+        if isinstance(out, dict) and "n_applied" in out:
+            applied.append(int(out["n_applied"]))
+        return out
+
+    monkeypatch.setattr(jax.lax, "while_loop", spy)
+    tree, node_assign = grow_tree_frontier(*args, cfg)
+    assert len(applied) == 1 and applied[0] > cfg.num_leaves - 1
+    assert int(tree.num_leaves) == 8
+    assert bool(np.asarray(tree.is_cat_split).any()) == categorical
+    want = predict_leaf_binned(tree, args[0], args[7])
+    np.testing.assert_array_equal(np.asarray(node_assign), np.asarray(want))
 
 
 def test_valid_traverse_looks_nothing_up_per_row():
@@ -552,8 +617,9 @@ def test_valid_traverse_looks_nothing_up_per_row():
 @pytest.mark.parametrize("categorical", [False, True], ids=["plain", "categorical"])
 @pytest.mark.parametrize("grower", ["frontier", "serial"])
 def test_node_assign_is_the_trees_own_traversal(grower, categorical):
-    """The rows' leaves as the grower returns them, from the ranges of its
-    partition, are the leaves the binned traversal finds for the same rows."""
+    """The rows' leaves as the grower returns them (the frontier grower from
+    the rows' last slots, the serial one from the ranges of its partition) are
+    the leaves the binned traversal finds for the same rows."""
     import jax
     from lightgbm_tpu.ops.frontier import grow_tree_frontier
     from lightgbm_tpu.ops.grower import grow_tree
