@@ -13,14 +13,19 @@ correlated the way features built from one impression are.  Kinds, each over
 - ``normal``   ``v`` itself
 
 The label is Bernoulli of a nonlinear logistic model over ``label.features``
-of the columns' normals; its intercept is solved on the first block so that
-the positive rate is ``label.rate``.
+of the columns' normals; its intercept is solved so that the positive rate is
+``label.rate``.
 
-Everything comes from ``--seed``: the label model's weights and every row.
-``stream`` draws a disjoint set of rows under the same label model (0 the
-training rows, 1 the validation rows).  So two seeds are two data sets of one
-kind, and the trees grown on them differ in shape (PERF.md section 2 says what
-that does to the spread of ``s_per_tree``).
+The population is the configuration's and the sample is the seed's.  The
+label model (which columns carry the label, their weights, the pairwise terms)
+is drawn from ``label.model_seed``, a key of the block, and its intercept is
+solved on block ``[model_seed, 0, 0]``: one truth behind the labels for every
+``--seed``.  ``--seed`` seeds every block of rows (``[seed, stream, i]``);
+``stream`` draws a disjoint set of rows (0 the training rows, 1 the validation
+rows).  So two seeds are two samples of one population, as two days of one
+click log are: the trees grown on them have one shape, where two label models
+grow trees whose cost differs by a tenth (PERF.md section 2).  A block without
+``model_seed`` raises: there is no fall-back to the seed.
 
 The matrix is written block by block straight into one C-contiguous float64
 array (what ``lgb.Dataset`` takes without another copy); values are float32,
@@ -63,7 +68,7 @@ def _value(kind, v):
     raise ValueError(f"unknown column kind {kind!r}")
 
 
-def _label_model(spec, n_cols, seed):
+def _label_weights(spec, n_cols, seed):
     """Weights of the label's logistic model: linear terms on the columns'
     normals, a few pairwise products and one threshold term."""
     rng = np.random.default_rng([int(seed), 0x1ABE1])
@@ -75,7 +80,7 @@ def _label_model(spec, n_cols, seed):
     return feats, w, pairs, wp
 
 
-def _block(spec, groups, f, model, key, rows):
+def _block(spec, groups, f, weights, key, rows):
     """(values float32 [F, rows], the label's logit without its intercept, the
     uniforms the label is drawn with) of one block.  Column-major, so a column
     is contiguous; plain ufuncs, which run in parallel across the threads that
@@ -89,7 +94,7 @@ def _block(spec, groups, f, model, key, rows):
     x32 = np.empty((f, rows), np.float32)
     for kind, a, b, mu, sigma in groups:
         x32[a:b] = _value(kind, mu + sigma * z[a:b])
-    feats, w, pairs, wp = model
+    feats, w, pairs, wp = weights
     zs = z[feats]
     logit = w @ zs
     for (a, b), wab in zip(pairs, wp):
@@ -99,18 +104,19 @@ def _block(spec, groups, f, model, key, rows):
     return x32, logit, rng.random(rows, dtype=np.float32)
 
 
-def make(spec: dict, rows: int, seed: int, stream: int = 0,
-         threads: int | None = None):
-    """(X float64 [rows, F] C-contiguous, y float32 [rows])."""
-    seed = int(seed)
+def label_model(spec: dict):
+    """The population's label model, from ``label.model_seed`` alone:
+    (columns, weights, pairs, pair weights, intercept).  The intercept is
+    solved on the first block of rows that the model's own seed draws
+    (bisection on the mean of the sigmoid), so both streams of every seed
+    share it."""
+    if "model_seed" not in spec["label"]:
+        raise KeyError("the data block's label has no model_seed: the label model "
+                       "belongs to the configuration, not to --seed")
+    model_seed = int(spec["label"]["model_seed"])
     groups, f = _groups(spec)
-    model = _label_model(spec, f, seed)
-    n_full, rest = divmod(rows, BLOCK_ROWS)
-    sizes = [BLOCK_ROWS] * n_full + ([rest] if rest else [])
-
-    # the label's intercept, from the training rows' first block (bisection on
-    # the mean of the sigmoid), so that both streams share it
-    _, head, _ = _block(spec, groups, f, model, [seed, 0, 0], BLOCK_ROWS)
+    weights = _label_weights(spec, f, model_seed)
+    _, head, _ = _block(spec, groups, f, weights, [model_seed, 0, 0], BLOCK_ROWS)
     head = head.astype(np.float64)
     lo, hi = -30.0, 30.0
     for _ in range(60):
@@ -119,14 +125,25 @@ def make(spec: dict, rows: int, seed: int, stream: int = 0,
             lo = mid
         else:
             hi = mid
-    intercept = np.float32(0.5 * (lo + hi))
+    return (*weights, np.float32(0.5 * (lo + hi)))
+
+
+def make(spec: dict, rows: int, seed: int, stream: int = 0,
+         threads: int | None = None):
+    """(X float64 [rows, F] C-contiguous, y float32 [rows]): ``rows`` rows of
+    the configuration's population, drawn by ``seed``."""
+    seed = int(seed)
+    groups, f = _groups(spec)
+    *weights, intercept = label_model(spec)
+    n_full, rest = divmod(rows, BLOCK_ROWS)
+    sizes = [BLOCK_ROWS] * n_full + ([rest] if rest else [])
 
     x = np.empty((rows, f), np.float64)
     y = np.empty(rows, np.float32)
 
     def fill(i):
         at = i * BLOCK_ROWS
-        x32, logit, u = _block(spec, groups, f, model, [seed, stream, i], sizes[i])
+        x32, logit, u = _block(spec, groups, f, weights, [seed, stream, i], sizes[i])
         x[at:at + sizes[i]] = x32.T
         y[at:at + sizes[i]] = u < 1.0 / (1.0 + np.exp(-(logit + intercept)))
 

@@ -2,16 +2,18 @@
 
     JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q
 
-They rehearse the command at 20k rows (a result that says it is a rehearsal),
-check that every name in BENCHMARK.json resolves to a file, check the trace
-reduction on a small recorded trace and the work functions on a hand case,
-and drive the job with the timed path broken underneath to see ``correct``
-come out false: once for the control (the reference in bfloat16) and once for
-each fault a training cell can have.
+They rehearse every cell's command at 20k rows (a result that says it is a
+rehearsal), check that every name in BENCHMARK.json resolves to a file, hold
+the generator to one population a configuration (the label model is the
+configuration's, the rows are the seed's), check the trace reduction on a
+small recorded trace and the work functions on a hand case, and drive the job
+under every configuration's own limits with the timed path broken underneath
+to see ``correct`` come out false: once for the control (the reference in
+bfloat16) and once for each fault a training cell can have.
 """
 from __future__ import annotations
 
-import copy
+import hashlib
 import json
 import os
 import re
@@ -23,17 +25,27 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmarks import reference, run as bench_run, trace_reduce, work  # noqa: E402
+from benchmarks import datagen, reference, run as bench_run, trace_reduce, work  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+CONFIGS = {c["name"]: c["file"] for c in BENCH["configs"]}
+
+
+def _config(name):
+    with open(os.path.join(ROOT, CONFIGS[name])) as f:
+        return json.load(f)
+
+
 @pytest.fixture(scope="module")
 def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+    return BENCH
 
 
 def _last_json(text):
@@ -42,8 +54,8 @@ def _last_json(text):
 
 # ------------------------------------------------------------ the command
 @pytest.mark.parametrize("trace", [0, 1])
-def test_command_rehearsal(bench, capfd, trace):
-    cell = bench["workloads"][0]["name"]
+@pytest.mark.parametrize("cell", CELLS)
+def test_command_rehearsal(bench, capfd, cell, trace):
     rc = bench_run.main(["--workload", cell, "--seed", "3000000011", "--seconds", "1",
                          "--trace", str(trace), "--rehearse", "20000"])
     out, err = capfd.readouterr()
@@ -89,6 +101,8 @@ def test_names_resolve_and_are_well_formed(bench):
         with open(os.path.join(ROOT, c["file"])) as f:
             conf = json.load(f)
         assert conf["reduced"] == c["reduced"] and "limits" in conf
+        # the population is the configuration's: one label model for every --seed
+        assert isinstance(conf["data"]["label"]["model_seed"], int)
     cells = set()
     for w in bench["workloads"]:
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
@@ -108,9 +122,79 @@ def test_names_resolve_and_are_well_formed(bench):
         assert 0.01 <= m["bound"] <= 0.1 and m["source"] in ("host_clock", "device_trace")
     for m in bench["per_layer"]:
         assert m["moves"] in e2e and "bound" not in m
+        # a metric without a list binds every cell a later PR adds
+        assert m.get("workloads"), m["name"]
         assert os.path.exists(os.path.join(ROOT, "benchmarks", "layer_metrics",
                                            m["name"] + ".py"))
     assert len(json.dumps(bench)) < 64 * 1024
+
+
+# ------------------------------------------------------------ the generator
+# sha256 of make(data, rows, model_seed, stream) as the parent of PR 33 made it
+# from --seed alone (x, y): the configuration's seed still gives those bytes
+PARENT_BYTES = {
+    ("criteo67", 300_000, 0):
+        ("cd3deb1e7ccc948942e217b6a1fe62af809f29c9808e991ccfa3f822c1beece2",
+         "760cf8dba295cc5f1682c98d27e6aec7a43e0d28e24d90761d37ac33a1e0cbf7"),
+    ("criteo67", 60_000, 1):
+        ("b2c1679d8298ff87990dc3f70e362ebfa330c21c19578795c0bfa504f3ebe4dc",
+         "fcf545dea43469dce5b85d5af47a6b51edbe087dd31fca50dfade6461e864140"),
+    ("criteo67-msh100", 300_000, 0):
+        ("5b644559a319c43e8e6451aeea0f3a03a712e76b735f9bf176a9631bcc5725c0",
+         "08b38b0b15a341952f73a44b509a1f13118b6565857f32d5b00181805e369f14"),
+    ("criteo67-msh100", 60_000, 1):
+        ("5a59f49f38918f776fe0389245f3d49d092b23efdf89efb3c3aa3c7fe15a8da5",
+         "b404f38581933ba048f0711f2bcfd3096b55d730e84c4a16d1a61f2cae29a5a4"),
+}
+
+
+def _sha(a):
+    return hashlib.sha256(a.tobytes()).hexdigest()
+
+
+@pytest.mark.parametrize("config_name,rows,stream", list(PARENT_BYTES))
+def test_model_seed_gives_the_bytes_the_seed_gave(config_name, rows, stream):
+    """300,000 rows span two blocks, so the intercept's block and a later one."""
+    spec = _config(config_name)["data"]
+    x, y = datagen.make(spec, rows, spec["label"]["model_seed"], stream=stream)
+    assert (_sha(x), _sha(y)) == PARENT_BYTES[config_name, rows, stream]
+
+
+@pytest.mark.parametrize("stream", [0, 1])
+@pytest.mark.parametrize("config_name", list(CONFIGS))
+def test_two_seeds_are_two_samples_of_one_population(config_name, stream, monkeypatch):
+    spec = _config(config_name)["data"]
+    models, real = [], datagen.label_model
+    monkeypatch.setattr(datagen, "label_model",
+                        lambda spec: models.append(real(spec)) or models[-1])
+    rate = float(spec["label"]["rate"])
+    drawn = {}
+    for seed in (spec["label"]["model_seed"], 3000003301, 7):
+        drawn[seed] = x, y = datagen.make(spec, 40_000, seed, stream=stream)
+        # the intercept was solved on the model's own block: it holds on any seed's rows
+        assert abs(float(y.mean()) - rate) < 0.1 * rate
+    want = real(spec)       # columns, weights, pairs, pair weights, intercept
+    assert len(models) == 3
+    for got in models:
+        assert all(np.array_equal(a, b) for a, b in zip(got, want))
+    (xa, ya), (xb, yb), (xc, _) = drawn.values()
+    assert not np.array_equal(xa, xb) and not np.array_equal(ya, yb)
+    assert not np.array_equal(xb, xc)
+    # the other stream of one seed is other rows too
+    assert not np.array_equal(xa, datagen.make(spec, 40_000, spec["label"]["model_seed"],
+                                               stream=1 - stream)[0])
+
+
+def test_two_model_seeds_are_two_populations_and_none_raises():
+    name = next(iter(CONFIGS))
+    spec, other = _config(name)["data"], _config(name)["data"]
+    other["label"]["model_seed"] += 1
+    a, b = datagen.label_model(spec), datagen.label_model(other)
+    assert not np.array_equal(a[1], b[1])
+    # one path: no fall-back to --seed
+    del other["label"]["model_seed"]
+    with pytest.raises(KeyError, match="model_seed"):
+        datagen.make(other, 1000, 3000000128)
 
 
 # ------------------------------------------------------------ trace reduction
@@ -172,10 +256,8 @@ def test_median_gap_ignores_a_few_small_leaves_and_sees_every_leaf_shifted():
 
 
 # ------------------------------------------------------------ control and faults
-def _ctx(bench, rows=6000, seconds=0.5):
-    with open(os.path.join(ROOT, bench["configs"][0]["file"])) as f:
-        config = json.load(f)
-    config = copy.deepcopy(config)
+def _ctx(config_name, rows=6000, seconds=0.5):
+    config = _config(config_name)     # its own limits, untouched
     config["params"].update(num_leaves=15, min_sum_hessian_in_leaf=1e-3,
                             min_data_in_leaf=20)     # a size a test run can hold
     with open(os.path.join(ROOT, "benchmarks", "traffic", "train-valid.json")) as f:
@@ -189,12 +271,13 @@ def _ctx(bench, rows=6000, seconds=0.5):
             "control": ["bfloat16", "half", "frozen"]}, lines
 
 
-def test_sound_run_is_correct_and_controls_are_not(bench):
-    """The program as it is passes at test size under the cell's own limits; the
-    reference put in its place in bfloat16, on half of the rows, or with its
-    state frozen fails at least one of them."""
+@pytest.mark.parametrize("config_name", list(CONFIGS))
+def test_sound_run_is_correct_and_controls_are_not(config_name):
+    """The program as it is passes at test size under the configuration's own
+    limits; the reference put in its place in bfloat16, on half of the rows,
+    or with its state frozen fails at least one of them."""
     from benchmarks.jobs import train
-    ctx, lines = _ctx(bench)
+    ctx, lines = _ctx(config_name)
     out = train.run(ctx)
     assert out["correct"], out["compared"]
     limits = ctx["config"]["limits"]
@@ -258,10 +341,11 @@ def _altered_valid_metric(monkeypatch):
 
 @pytest.mark.parametrize("fault", [_frozen_state, _half_batch, _altered_answer,
                                    _altered_valid_metric])
-def test_broken_timed_path_is_not_correct(bench, monkeypatch, fault):
+@pytest.mark.parametrize("config_name", list(CONFIGS))
+def test_broken_timed_path_is_not_correct(config_name, monkeypatch, fault):
     from benchmarks.jobs import train
     fault(monkeypatch)
-    ctx, _ = _ctx(bench)
+    ctx, _ = _ctx(config_name)
     ctx["control"] = []
     out = train.run(ctx)
     assert out["attempted"] >= 1
