@@ -4,6 +4,10 @@ Analog of the reference ``Metric`` (``include/LightGBM/metric.h``;
 implementations ``src/metric/{regression,binary,multiclass}_metric.hpp``).
 ``eval(score, objective)`` receives RAW scores and uses the objective's
 output transform, exactly like the reference.
+
+Where the scores live on the device, ``has_device_form`` says whether
+``device_eval`` computes the same metric there (``metric/device.py``); the
+caller (``GBDT.eval_scores``) then fetches nothing but its result.
 """
 from __future__ import annotations
 
@@ -12,11 +16,16 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from ..config import Config
+from ..objective.binary import BinaryLogloss
+from ..obs import costs as obs_costs
+from . import device
 
 
 class Metric:
     name: str = "base"
     higher_better: bool = False
+    _label_dev = None       # the labels on the device, from the first device_eval
+    _recorded = False
 
     def __init__(self, config: Config):
         self.config = config
@@ -31,6 +40,31 @@ class Metric:
 
     def eval(self, score: np.ndarray, objective=None) -> List[Tuple[str, float, bool]]:
         raise NotImplementedError
+
+    # -- the device form ---------------------------------------------------
+    def has_device_form(self, objective=None) -> bool:
+        """Whether :meth:`device_eval` computes this metric, with this
+        objective, over device scores of one tree an iteration."""
+        return False
+
+    def device_eval(self, score, objective=None) -> List[Tuple[str, float, bool]]:
+        """:meth:`eval` over the device array ``score`` ([1, N] raw scores),
+        computed where it lives."""
+        raise NotImplementedError
+
+    def _device_run(self, program, score, *args):
+        """``program(score, labels, *args)`` with the labels staged on the
+        device once (``device.stage_labels``); after the first call the
+        program's cost and scopes go into the obs tables, as the booster's
+        programs' do."""
+        if self._label_dev is None:
+            self._label_dev = device.stage_labels(self.label)
+        out = program(score, self._label_dev, *args)
+        if not self._recorded:
+            self._recorded = True
+            obs_costs.record_program(f"eval.{self.name}.{self.num_data}",
+                                     program, score, self._label_dev, *args)
+        return out
 
     # -- helpers --------------------------------------------------------
     def _transform(self, score: np.ndarray, objective) -> np.ndarray:
@@ -157,10 +191,29 @@ class BinaryLoglossMetric(Metric):
     name = "binary_logloss"
 
     def eval(self, score, objective=None):
-        prob = np.clip(self._transform(score, objective), 1e-15, 1 - 1e-15)
+        if isinstance(objective, BinaryLogloss):
+            # in float64, as the reference's sigmoid: the objective's own
+            # transform runs in jax's float32, where a score past 16.6 gives
+            # a probability of 1.0, and the clip below cannot keep its log
+            # finite in float32
+            with np.errstate(over="ignore"):
+                prob = 1.0 / (1.0 + np.exp(
+                    -objective.sigmoid * np.asarray(score, np.float64)))
+        else:
+            prob = self._transform(score, objective)
+        prob = np.clip(prob, 1e-15, 1 - 1e-15)
         y = (self.label > 0).astype(np.float64)
         loss = -(y * np.log(prob) + (1 - y) * np.log(1 - prob))
         return [(self.name, self._avg(loss), False)]
+
+    def has_device_form(self, objective=None):
+        return self.weight is None and isinstance(objective, BinaryLogloss)
+
+    def device_eval(self, score, objective=None):
+        partials = self._device_run(device.logloss_partials, score,
+                                    np.float32(objective.sigmoid))
+        return [(self.name, device.logloss_value(partials, self.num_data),
+                 False)]
 
 
 class BinaryErrorMetric(Metric):
@@ -201,6 +254,14 @@ class AUCMetric(Metric):
         neg_below = np.concatenate([[0.0], np.cumsum(wn)[:-1]])
         auc = np.sum(wp * (neg_below + wn / 2.0)) / (pos_w * neg_w)
         return [(self.name, float(auc), True)]
+
+    def has_device_form(self, objective=None):
+        return self.weight is None and self.num_data <= device.MAX_ROWS
+
+    def device_eval(self, score, objective=None):
+        partials, positives = self._device_run(device.auc_partials, score)
+        return [(self.name,
+                 device.auc_value(partials, positives, self.num_data), True)]
 
 
 class AveragePrecisionMetric(Metric):

@@ -249,11 +249,7 @@ class GBDT:
         if name in self._recorded_programs:
             return
         self._recorded_programs.add(name)
-        try:
-            with get_tracer().span("lgbm/scope_table", program=name):
-                obs_costs.analyze_jitted(name, fn, *args, **meta)
-        except Exception as e:
-            Log.debug("program %s not recorded: %s", name, e)
+        obs_costs.record_program(name, fn, *args, **meta)
 
     # ------------------------------------------------------------------
     def init_train(self, train_data: Dataset) -> None:
@@ -1296,25 +1292,36 @@ class GBDT:
         return out
 
     def eval_scores(self, data_name: str, score_dev, metrics) -> List[tuple]:
-        """One data set's metrics over its device scores, as ``lgbm/eval``
-        with three kinds of children: ``wait`` (the device finishing the
-        scores' last update, a whole tree where the host ran ahead), ``fetch``
-        (the device-to-host copy and the float64 cast) and one ``metric``
-        each."""
+        """One data set's metrics over its scores, as ``lgbm/eval`` with three
+        kinds of children: ``wait`` (the device finishing the scores' last
+        update, a whole tree where the host ran ahead), ``fetch`` (the
+        device-to-host copy and the float64 cast, where a metric runs on the
+        host) and one ``metric`` each, whose ``on_device`` says where it ran:
+        a metric with a device form computes where device scores of one tree
+        an iteration live, and only its result is fetched."""
         out = []
         tracer = get_tracer()
+        scores_on_device = (isinstance(score_dev, jax.Array)
+                            and self.num_tree_per_iteration == 1)
+        on_device = [scores_on_device and m.has_device_form(self.objective)
+                     for m in metrics]
         with tracer.span("lgbm/eval", iteration=self.iter_ - 1,
                          data=data_name):
             with tracer.span("lgbm/eval/wait"):
                 jax.block_until_ready(score_dev)
-            with tracer.span("lgbm/eval/fetch"):
-                score = np.asarray(score_dev, np.float64)
-            s = score[0] if self.num_tree_per_iteration == 1 else score
-            for m in metrics:
-                with tracer.span("lgbm/eval/metric",
-                                 metric=type(m).__name__):
-                    for name, val, hib in m.eval(s, self.objective):
-                        out.append((data_name, name, val, hib))
+            if not all(on_device):
+                with tracer.span("lgbm/eval/fetch"):
+                    score = np.asarray(score_dev, np.float64)
+                s = score[0] if self.num_tree_per_iteration == 1 else score
+            for m, dev in zip(metrics, on_device):
+                with tracer.span("lgbm/eval/metric", metric=type(m).__name__,
+                                 on_device=int(dev)):
+                    rows = (m.device_eval(score_dev, self.objective) if dev
+                            else m.eval(s, self.objective))
+                obs_metrics.counter("eval.metrics_on_device" if dev
+                                    else "eval.metrics_on_host").inc()
+                out.extend((data_name, name, val, hib)
+                           for name, val, hib in rows)
         return out
 
     # ------------------------------------------------------------------

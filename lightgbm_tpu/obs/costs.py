@@ -35,6 +35,7 @@ __all__ = ["PEAK_RATES", "normalize_chip", "peak_flops",
            "peak_bandwidth", "mfu", "arithmetic_intensity",
            "ridge_intensity", "classify_bound", "roofline", "CostLedger",
            "get_ledger", "reset_ledger", "current_chip", "analyze_jitted",
+           "record_program",
            "record_watermarks", "set_stats_provider", "COST_EVENT"]
 
 #: event name the ledger emits per program (rendered by --roofline)
@@ -368,3 +369,16 @@ def analyze_jitted(name: str, fn: Callable, *args: Any,
     compiled = jitted.lower(*args).compile()
     scopes.record_compiled(compiled)
     return (ledger or get_ledger()).record(name, compiled, **record_kw)
+
+
+def record_program(name: str, fn: Callable, *args: Any, **meta: Any) -> None:
+    """:func:`analyze_jitted` right after ``fn``'s first call, timed by an
+    ``lgbm/scope_table`` span; never fatal (a program that cannot be read is
+    left out of the tables and logged)."""
+    from .tracer import get_tracer
+    try:
+        with get_tracer().span("lgbm/scope_table", program=name):
+            analyze_jitted(name, fn, *args, **meta)
+    except Exception as e:
+        from ..utils.log import Log
+        Log.debug("program %s not recorded: %s", name, e)

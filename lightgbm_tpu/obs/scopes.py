@@ -60,6 +60,9 @@ SCOPES = (
     "lgbm/finalize",
     "lgbm/score_update",
     "lgbm/valid_traverse",
+    # the built-in binary metrics over device scores (metric/device.py): the
+    # AUC's sort and scans, the log-loss's softplus, their block sums
+    "lgbm/valid_metric",
     # the serial grower (ops/grower.py)
     "lgbm/partition",
     "lgbm/hist",

@@ -539,15 +539,16 @@ def test_compile_span_is_parented_to_the_step_that_compiled(
     bst.update()
     assert len([s for s in get_tracer().spans()
                 if s.name == "lgbm/compile"]) == len(compiles)
-    # the metrics and their wait for the device are apart
+    # the metrics and their wait for the device are apart; both metrics
+    # run where the scores are, so nothing copies the scores to the host
     names = [s.name for s in get_tracer().spans()]
-    for want in ("lgbm/eval", "lgbm/eval/wait", "lgbm/eval/fetch",
-                 "lgbm/eval/metric", "lgbm/booster/init",
-                 "lgbm/dataset/construct/reference_bin"):
+    for want in ("lgbm/eval", "lgbm/eval/wait", "lgbm/eval/metric",
+                 "lgbm/booster/init", "lgbm/dataset/construct/reference_bin"):
         assert want in names, want
-    metrics = [s.args["metric"] for s in get_tracer().spans()
-               if s.name == "lgbm/eval/metric"]
-    assert metrics[:2] == ["BinaryLoglossMetric", "AUCMetric"]
+    assert "lgbm/eval/fetch" not in names
+    metrics = [(s.args["metric"], s.args["on_device"])
+               for s in get_tracer().spans() if s.name == "lgbm/eval/metric"]
+    assert metrics[:2] == [("BinaryLoglossMetric", 1), ("AUCMetric", 1)]
 
 
 def test_device_scopes_hold_every_phase_and_outlive_the_booster():

@@ -340,3 +340,49 @@ def test_hist_store_share_reader(fixture, monkeypatch):
     monkeypatch.setattr(scopes, "SCOPES", tuple(s for s in scopes.SCOPES if s != scope))
     assert read(run) is None
     assert read({"trace": None, "window_s": 1.0}) is None
+
+
+# ---- the metrics computed where the scores live ----
+def test_valid_metric_share_reader(fixture, monkeypatch):
+    from lightgbm_tpu.obs import scopes
+    scope = "lgbm/valid_metric"
+    assert scope in scopes.SCOPES
+    assert set(_listed("valid_metric_share.train")["workloads"]) == _train_cells()
+    read = _reader("valid_metric_share.train")
+    run = {"trace": fixture["trace"], "window_s": WINDOW_S,
+           "_phase_table": _reduce(fixture)}
+    assert read(run) == 0.0
+    fixture["scopes"]["copy.7 s32[4096]"] = scope
+    run["_phase_table"] = table = _reduce(fixture)
+    assert read(run) == pytest.approx(100 * table["scope_seconds"][scope] / WINDOW_S)
+    # the parent names no such scope: its metrics run on the host
+    monkeypatch.setattr(scopes, "SCOPES", tuple(s for s in scopes.SCOPES if s != scope))
+    assert read(run) is None
+    assert read({"trace": None, "window_s": 1.0}) is None
+
+
+def test_device_metric_share_reader(fixture, monkeypatch):
+    """Of the window's three ``lgbm/eval/metric`` spans, those whose metric
+    ran on the device; the warm-up's, outside the window, are not counted."""
+    assert set(_listed("device_metric_share.train")["workloads"]) == _train_cells()
+    read = _reader("device_metric_share.train")
+    run = {"trace": fixture["trace"], "window_s": WINDOW_S}
+    # a program whose metric spans carry no on_device (the parent's): no value
+    _program_with(monkeypatch, fixture["spans"])
+    assert read(run) is None
+    metric_spans = [s for s in fixture["spans"] if s["name"] == "lgbm/eval/metric"]
+    assert len(metric_spans) == 3
+    for s, where in zip(metric_spans, (1, 1, 0)):
+        s["args"]["on_device"] = where
+    # one before the window, on the host: left out
+    fixture["spans"].append({"id": 13, "parent": 10, "name": "lgbm/eval/metric",
+                             "start": -24000000000, "end": -23500000000,
+                             "tid": 1, "depth": 1, "iteration": 0,
+                             "args": {"metric": "AUCMetric", "on_device": 0}})
+    _program_with(monkeypatch, fixture["spans"])
+    assert read(run) == pytest.approx(100.0 * 2 / 3)
+    for s in metric_spans:
+        s["args"]["on_device"] = 1
+    _program_with(monkeypatch, fixture["spans"])
+    assert read(run) == 100.0
+    assert read({"trace": None, "window_s": 1.0}) is None
