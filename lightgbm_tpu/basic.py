@@ -121,9 +121,10 @@ class Dataset:
             # resolve categorical feature names -> indices
             if cats is not None and feature_names is not None:
                 cats = [feature_names.index(c) if isinstance(c, str) else c for c in cats]
-            from .io.dataset import _is_sparse
+            from .io.dataset import _is_sparse, is_shards
             self._inner = _InnerDataset.from_data(
-                data if (hasattr(data, "values") or _is_sparse(data))
+                data if (hasattr(data, "values") or _is_sparse(data)
+                         or is_shards(data))
                 else np.asarray(data, dtype=np.float64),
                 cfg, label=self.label, weight=self.weight, group=self.group,
                 init_score=self.init_score, categorical_feature=cats,
@@ -633,7 +634,7 @@ class Booster:
             score = np.asarray(self._gbdt._train_score, np.float64)
             dataset = self.train_set
         else:
-            score = np.asarray(self._gbdt._valid_scores[idx], np.float64)
+            score = self._gbdt.valid_score_host(idx)
             dataset = (self.valid_sets_py[idx]
                        if getattr(self, "valid_sets_py", None) else None)
         s = score[0] if self._gbdt.num_tree_per_iteration == 1 else score

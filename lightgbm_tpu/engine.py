@@ -264,8 +264,7 @@ def cv(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100,
                 # the PYTHON-level Datasets (get_label/get_weight), not the
                 # inner binned ones; feval runs on every eval set like the
                 # reference (training included when eval_train_metric)
-                evals = [("valid",
-                          np.asarray(bst._gbdt._valid_scores[0], np.float64),
+                evals = [("valid", bst._gbdt.valid_score_host(0),
                           bst.valid_sets_py[0]
                           if getattr(bst, "valid_sets_py", None) else None)]
                 if eval_train_metric:

@@ -15,7 +15,7 @@ layouts feeding the MXU), and histogram dispatch is a JAX op in
 from __future__ import annotations
 
 import sys
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -109,6 +109,7 @@ class Dataset:
         self.feature_names: List[str] = []
         self.reference: Optional["Dataset"] = None
         self._device: Optional[DeviceData] = None
+        self._device_rows: Dict[Any, DeviceData] = {}   # by row sharding
         # raw feature values, kept only for linear trees (the reference keeps
         # Dataset::raw_data_ when linear_tree=true, dataset.h:717)
         self.raw_data: Optional[np.ndarray] = None
@@ -142,7 +143,13 @@ class Dataset:
         blocks (see ``_bin_data_sparse``) — the TPU-design answer to the
         reference's per-feature sparse bin containers
         (``src/io/sparse_bin.hpp:73``): the DEVICE matrix is the bundled
-        dense one, whose width EFB has already collapsed."""
+        dense one, whose width EFB has already collapsed.
+
+        A list of 2-D arrays (the ``LGBM_DatasetCreateFromMats`` path) is
+        the rows of one table in shards, laid end to end: the bin mappers
+        come from the row sample of the whole table, so mappers and bins are
+        the ones the concatenated matrix gives, and each shard is binned
+        alone (span ``.../shard``), never joined into one float matrix."""
         with get_tracer().span("lgbm/dataset/construct",
                                reference=reference is not None):
             return cls._from_data(data, config, label, weight, group,
@@ -162,8 +169,9 @@ class Dataset:
                   "linear_tree with sparse input is not supported")
         else:
             with span("lgbm/dataset/construct/to_2d_float"):
-                data = _to_2d_float(data)
-        self.num_data, self.num_total_features = data.shape
+                data = ([_to_2d_float(part) for part in data] if is_shards(data)
+                        else _to_2d_float(data))
+        self.num_data, self.num_total_features = _shape_of(data)
         self.feature_names = _sanitize_feature_names(
             list(feature_names)) if feature_names else [
             f"Column_{i}" for i in range(self.num_total_features)]
@@ -196,7 +204,8 @@ class Dataset:
                     self._apply_bundling()
         if config.linear_tree or (reference is not None
                                   and reference.raw_data is not None):
-            self.raw_data = np.asarray(data, np.float32)
+            self.raw_data = (np.concatenate([np.asarray(p, np.float32) for p in data])
+                             if is_shards(data) else np.asarray(data, np.float32))
         md = Metadata(self.num_data)
         self.metadata = md
         if label is not None:
@@ -229,7 +238,7 @@ class Dataset:
                 col = lambda f: np.asarray(  # noqa: E731
                     sample_csc[:, [f]].toarray(), np.float64).ravel()
             else:
-                sample = data[sample_idx]
+                sample = _take_rows(data, sample_idx)
                 col = lambda f: sample[:, f]  # noqa: E731
 
         with span("lgbm/dataset/construct/find_bins",
@@ -288,20 +297,36 @@ class Dataset:
             Log.warning("There are no meaningful features, as all feature values are constant.")
         self.real_to_inner = {f: i for i, f in enumerate(self.used_features)}
 
-    def _bin_data(self, data: np.ndarray) -> None:
-        n_used = len(self.used_features)
+    def _bin_data(self, data) -> None:
+        """``data`` (a matrix, or its rows in shards) into ``self.bins``.  A
+        shard is binned in a span of its own that names the device of the
+        data-parallel mesh its first row goes to (0 off the mesh)."""
         max_nb = max((self.bin_mappers[f].num_bin for f in self.used_features), default=1)
         dtype = np.uint8 if max_nb <= 256 else np.uint16
+        if not is_shards(data):
+            self.bins = self._bin_rows(data, dtype)
+            return
+        from ..parallel.mesh import row_shards
+        per_device = -(-self.num_data // row_shards(self.config))
+        self.bins = np.empty((self.num_data, len(self.used_features)), dtype)
+        lo = 0
+        for i, part in enumerate(data):
+            hi = lo + part.shape[0]
+            with get_tracer().span("lgbm/dataset/construct/shard", shard=i,
+                                   rows=hi - lo, device=lo // max(per_device, 1)):
+                self.bins[lo:hi] = self._bin_rows(part, dtype)
+            lo = hi
+
+    def _bin_rows(self, data: np.ndarray, dtype) -> np.ndarray:
         # native threaded binning (parser.cpp BinValues); numpy fallback
         from ..native import bin_values
         native = bin_values(data, self.bin_mappers, self.used_features)
         if native is not None:
-            self.bins = native.astype(dtype, copy=False)
-            return
-        bins = np.empty((self.num_data, n_used), dtype=dtype)
+            return native.astype(dtype, copy=False)
+        bins = np.empty((data.shape[0], len(self.used_features)), dtype=dtype)
         for i, f in enumerate(self.used_features):
             bins[:, i] = self.bin_mappers[f].value_to_bin(data[:, f]).astype(dtype)
-        self.bins = bins
+        return bins
 
     _SPARSE_BLOCK_ROWS = 65536
     _SPARSE_BLOCK_BYTES = 128 * 1024 * 1024   # dense f64 block budget
@@ -521,9 +546,23 @@ class Dataset:
         return self._device_tensors(monotone_constraints, with_bins=False)
 
     # ------------------------------------------------------------------
-    def device_data(self, monotone_constraints: Optional[Sequence[int]] = None) -> DeviceData:
-        """Materialize device tensors (lazily cached)."""
-        return self._device_tensors(monotone_constraints, with_bins=True)
+    def device_data(self, monotone_constraints: Optional[Sequence[int]] = None,
+                    row_sharding=None) -> DeviceData:
+        """Materialize device tensors (lazily cached).  ``row_sharding`` (a
+        ``NamedSharding`` over the rows of the data-parallel mesh) puts the
+        bins on the mesh instead of on the default device, padded with rows
+        of bin 0 to a multiple of it, each device's rows sent to it from the
+        host (``parallel.mesh.put_rows``); each layout is cached apart."""
+        if row_sharding is None:
+            return self._device_tensors(monotone_constraints, with_bins=True)
+        dd = self._device_rows.get(row_sharding)
+        if dd is None or monotone_constraints is not None:
+            from ..parallel.mesh import put_rows
+            dd = replace(self._device_tensors(monotone_constraints, with_bins=False),
+                         bins=put_rows(self.bins, row_sharding))
+            if monotone_constraints is None:
+                self._device_rows[row_sharding] = dd
+        return dd
 
     def _device_tensors(self, monotone_constraints, with_bins: bool) -> DeviceData:
         if (self._device is not None and monotone_constraints is None
@@ -673,6 +712,34 @@ def _is_sparse(data) -> bool:
     """True for any scipy.sparse matrix/array, without importing scipy
     eagerly (it is an optional dependency of this package)."""
     return hasattr(data, "tocsr") and hasattr(data, "nnz")
+
+
+def is_shards(data) -> bool:
+    """A list of 2-D arrays: one table's rows in shards, laid end to end."""
+    return (isinstance(data, (list, tuple)) and len(data) > 0
+            and all(isinstance(p, np.ndarray) and p.ndim == 2 for p in data))
+
+
+def _shape_of(data) -> tuple:
+    if not is_shards(data):
+        return data.shape
+    cols = {p.shape[1] for p in data}
+    check(len(cols) == 1, "the shards of a Dataset differ in their number of "
+          f"columns: {sorted(cols)}")
+    return sum(p.shape[0] for p in data), cols.pop()
+
+
+def _take_rows(data, idx: np.ndarray) -> np.ndarray:
+    """Rows ``idx`` (sorted) of a matrix or of its shards laid end to end."""
+    if not is_shards(data):
+        return data[idx]
+    out, lo = [], 0
+    for part in data:
+        hi = lo + part.shape[0]
+        a, b = np.searchsorted(idx, [lo, hi])
+        out.append(part[idx[a:b] - lo])
+        lo = hi
+    return np.concatenate(out)
 
 
 def _to_2d_float(data) -> np.ndarray:

@@ -57,8 +57,11 @@ class Metric:
         device once (``device.stage_labels``); after the first call the
         program's cost and scopes go into the obs tables, as the booster's
         programs' do."""
+        on = device.rows_mesh(score)
         if self._label_dev is None:
-            self._label_dev = device.stage_labels(self.label)
+            self._label_dev = device.stage_labels(self.label, score)
+        if on is not None:
+            program = device.on_mesh(program, *on)
         out = program(score, self._label_dev, *args)
         if not self._recorded:
             self._recorded = True

@@ -27,9 +27,17 @@ Both run under the scope ``lgbm/valid_metric`` (``obs/scopes.py``), over
 theirs has the shape of the scores, and none shares its key (name and result
 shape, ``obs.scopes.op_key``) with an operation of the score updates, whose
 time would then read as ambiguous.
+
+Scores whose rows are divided over a mesh (``tree_learner=data``,
+``GBDT._place_valid``) take the same programs under ``shard_map``
+(:func:`on_mesh`): each device pads its own rows, the log-loss's block sums
+stay where they are computed and the host adds all of them, and the AUC's
+order is global, so every device gathers the scores and labels of all
+(scope ``lgbm/collective/metric``) and counts as one device does.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -55,12 +63,34 @@ def padded(n: int) -> int:
     return (n // LOSS_BLOCK + 1) * LOSS_BLOCK
 
 
-def stage_labels(label: np.ndarray):
+def stage_labels(label: np.ndarray, score=None):
     """The int8 device labels the programs take: 1 positive, 0 negative,
-    ``PAD`` past the data."""
-    out = np.full(padded(len(label)), PAD, np.int8)
-    out[:len(label)] = label > 0
-    return jnp.asarray(out)
+    ``PAD`` past the data.  For ``score`` divided over a mesh
+    (:func:`rows_mesh`), each device's block: its rows' labels, then ``PAD``
+    to ``padded`` of its row count."""
+    on = rows_mesh(score)
+    if on is None:
+        out = np.full(padded(len(label)), PAD, np.int8)
+        out[:len(label)] = label > 0
+        return jnp.asarray(out)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh, axis = on
+    shards = mesh.shape[axis]
+    rows = score.shape[-1] // shards
+    out = np.full((shards, padded(rows)), PAD, np.int8)
+    flat = np.full(rows * shards, PAD, np.int8)
+    flat[:len(label)] = label > 0
+    out[:, :rows] = flat.reshape(shards, rows)
+    return jax.device_put(out.reshape(-1), NamedSharding(mesh, P(axis)))
+
+
+def rows_mesh(score):
+    """``(mesh, axis)`` where the rows of ``score`` (``[K, N]``) are divided
+    over a mesh axis, else None."""
+    spec = getattr(getattr(score, "sharding", None), "spec", None)
+    if spec is None or len(spec) < 2 or spec[1] is None:
+        return None
+    return score.sharding.mesh, spec[1]
 
 
 def auc_block(n: int) -> int:
@@ -129,22 +159,27 @@ def auc_partials(score, label):
     the raw scores ``score`` (N, any shape) and the staged labels ``label``
     (``stage_labels``)."""
     with jax.named_scope(SCOPE):
-        # ties are grouped by value, so their order is free: an unstable
-        # sort compiles in half the time of a stable one for the v5e
-        s, lab = lax.sort((_pad_to(score, label, jnp.inf), label), num_keys=1,
-                          is_stable=False)
-        y = (lab == 1).astype(jnp.int32)
-        neg = (lab == 0).astype(jnp.int32)
-        upto = jnp.cumsum(neg)          # negatives at or below each row
-        differs = s[1:] != s[:-1]
-        first = jnp.concatenate([jnp.ones(1, bool), differs])
-        last = jnp.concatenate([differs, jnp.ones(1, bool)])
-        # negatives below the row's tie group, and below it or in it
-        below = lax.cummax(jnp.where(first, upto - neg, 0))
-        through = lax.cummin(jnp.where(last, upto, label.shape[0]),
-                             reverse=True)
-        return (_block_sums(y * (below + through), auc_block(label.shape[0])),
-                jnp.sum(y))
+        return _auc_counts(_pad_to(score, label, jnp.inf), label)
+
+
+def _auc_counts(score, label):
+    """The AUC's counts over padded scores and staged labels, under the
+    caller's scope."""
+    # ties are grouped by value, so their order is free: an unstable
+    # sort compiles in half the time of a stable one for the v5e
+    s, lab = lax.sort((score, label), num_keys=1, is_stable=False)
+    y = (lab == 1).astype(jnp.int32)
+    neg = (lab == 0).astype(jnp.int32)
+    upto = jnp.cumsum(neg)          # negatives at or below each row
+    differs = s[1:] != s[:-1]
+    first = jnp.concatenate([jnp.ones(1, bool), differs])
+    last = jnp.concatenate([differs, jnp.ones(1, bool)])
+    # negatives below the row's tie group, and below it or in it
+    below = lax.cummax(jnp.where(first, upto - neg, 0))
+    through = lax.cummin(jnp.where(last, upto, label.shape[0]),
+                         reverse=True)
+    return (_block_sums(y * (below + through), auc_block(label.shape[0])),
+            jnp.sum(y))
 
 
 def auc_value(partials, positives, n: int) -> float:
@@ -160,6 +195,10 @@ def auc_value(partials, positives, n: int) -> float:
 def logloss_partials(score, label, sigmoid):
     """float32 sums, over blocks of ``LOSS_BLOCK`` rows, of each row's
     log-loss through ``sigmoid * score``."""
+    return _logloss_blocks(score, label, sigmoid)
+
+
+def _logloss_blocks(score, label, sigmoid):
     with jax.named_scope(SCOPE):
         z = sigmoid * _pad_to(score, label, 0.0)
         pos = label == 1
@@ -171,3 +210,35 @@ def logloss_partials(score, label, sigmoid):
 
 def logloss_value(partials, n: int) -> float:
     return float(np.asarray(partials).sum(dtype=np.float64) / n)
+
+
+def on_mesh(program, mesh, axis):
+    """``program`` (:func:`auc_partials` or :func:`logloss_partials`) for
+    scores ``[K, N]`` whose rows are divided over ``axis`` of ``mesh`` and
+    labels staged for them (``stage_labels``): the same results, the AUC's
+    on every device, the log-loss's blocks where they were summed."""
+    return _mesh_programs(mesh, axis)[program]
+
+
+@functools.lru_cache(maxsize=None)
+def _mesh_programs(mesh, axis):
+    from jax.sharding import PartitionSpec as P
+
+    def auc(score, label):
+        with jax.named_scope(SCOPE):
+            score = _pad_to(score, label, jnp.inf)
+        with jax.named_scope("lgbm/collective/metric"):
+            score = lax.all_gather(score, axis, tiled=True)
+            label = lax.all_gather(label, axis, tiled=True)
+        with jax.named_scope(SCOPE):
+            return _auc_counts(score, label)
+
+    rows = P(None, axis)
+    return {
+        auc_partials: jax.jit(jax.shard_map(
+            auc, mesh=mesh, in_specs=(rows, P(axis)), out_specs=(P(), P()),
+            check_vma=False)),
+        logloss_partials: jax.jit(jax.shard_map(
+            _logloss_blocks, mesh=mesh, in_specs=(rows, P(axis), P()),
+            out_specs=P(axis), check_vma=False)),
+    }

@@ -33,7 +33,8 @@ class DART(GBDT):
         ta = self._device_trees[model_idx]
         leaf = predict_leaf_binned(ta, bins, self._dd.nan_bins, efb=self._dd.efb)
         vals = ta.leaf_value * scale
-        return vals[leaf]
+        # the training score has no rows that pad the mesh; the bins may
+        return vals[leaf][:self.train_data.num_data]
 
     def train_one_iter(self, grad=None, hess=None):
         cfg = self.config
@@ -65,9 +66,9 @@ class DART(GBDT):
                 w = self._tree_weights[mi]
                 self._train_score = self._train_score.at[k].add(
                     -self._tree_score_delta(mi, self._dd.bins, w))
-                for vi, vset in enumerate(self.valid_sets):
+                for vi in range(len(self.valid_sets)):
                     ta = self._device_trees[mi]
-                    leaf = predict_leaf_binned(ta, vset.device_data().bins,
+                    leaf = predict_leaf_binned(ta, self._valid_bins(vi),
                                                self._dd.nan_bins, efb=self._dd.efb)
                     self._valid_scores[vi] = self._valid_scores[vi].at[k].add(
                         -(ta.leaf_value * w)[leaf])
@@ -95,8 +96,8 @@ class DART(GBDT):
             ta = self._device_trees[mi]
             self._train_score = self._train_score.at[k].add(
                 self._tree_score_delta(mi, self._dd.bins, adj))
-            for vi, vset in enumerate(self.valid_sets):
-                leaf = predict_leaf_binned(ta, vset.device_data().bins,
+            for vi in range(len(self.valid_sets)):
+                leaf = predict_leaf_binned(ta, self._valid_bins(vi),
                                                self._dd.nan_bins, efb=self._dd.efb)
                 self._valid_scores[vi] = self._valid_scores[vi].at[k].add(
                     (ta.leaf_value * adj)[leaf])
@@ -111,9 +112,9 @@ class DART(GBDT):
                 self._tree_weights[mi] = new_w
                 self._train_score = self._train_score.at[k].add(
                     self._tree_score_delta(mi, self._dd.bins, new_w))
-                for vi, vset in enumerate(self.valid_sets):
+                for vi in range(len(self.valid_sets)):
                     ta = self._device_trees[mi]
-                    leaf = predict_leaf_binned(ta, vset.device_data().bins,
+                    leaf = predict_leaf_binned(ta, self._valid_bins(vi),
                                                self._dd.nan_bins, efb=self._dd.efb)
                     self._valid_scores[vi] = self._valid_scores[vi].at[k].add(
                         (ta.leaf_value * new_w)[leaf])

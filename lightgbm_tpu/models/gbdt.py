@@ -11,7 +11,6 @@ learner is the single-program grower in ``ops/grower.py``.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 import os
 import time
@@ -110,6 +109,11 @@ class GBDT:
         # the frontier grower's histogram store between trees (``_grow``),
         # keyed by shape; ``engine.cv`` gives its folds one pool
         self.hist_pool: Dict[tuple, jax.Array] = {}
+        # tree_learner=data|feature|voting: the device mesh, and the rows'
+        # sharding over it where the learner divides rows (_learner_mesh)
+        self._mesh = None
+        self._row_sharding = None
+        self._rows_on_mesh = None       # the same where the rows divide it
         self._device_trees: List = []        # per-model device TreeArrays
         self._tree_weights: List[float] = []  # current scale of each model
         self.train_data_name = "training"    # Booster.set_train_data_name
@@ -188,12 +192,14 @@ class GBDT:
                 "train.grow_tree",
                 (now - last[1]) / 1e9 / self.num_tree_per_iteration)
 
-    @staticmethod
-    def _count_frontier(stats_dev, rows: int) -> Dict[str, int]:
+    def _count_frontier(self, stats_dev, rows: int) -> Dict[str, int]:
         """The grower's counters of one tree (``rows`` is what each frontier
         round passed) into ``obs.metrics``, totals as counters and per tree
         as histograms; returned for the drain span to carry.  The serial
-        grower counts no rounds, and ``frontier_trees`` 0."""
+        grower counts no rounds, and ``frontier_trees`` 0.  Under
+        ``tree_learner=data`` also the mesh's ``shards`` (gauge
+        ``train.shards``) and ``collective_bytes``, what each device received
+        in the tree's exchange (``frontier.exchange_bytes``)."""
         rounds, hi, lo, frontier, blocks = (int(v) for v in
                                             np.asarray(stats_dev))
         obs_metrics.counter("train.frontier_trees").inc(frontier)
@@ -203,12 +209,22 @@ class GBDT:
                   "rows_passed": rounds * rows,
                   "rows_selected": (hi << 20) + lo,
                   "hist_feature_blocks": blocks}
+        cfg = self._grower_cfg
+        if self._mesh is not None and cfg.parallel_mode == "data":
+            from ..ops.frontier import exchange_bytes
+            counts["shards"] = cfg.num_shards
+            counts["collective_bytes"] = exchange_bytes(
+                cfg, int(self._dd.bins.shape[1]), rounds)
+            obs_metrics.gauge("train.shards").set(cfg.num_shards)
         for name, v in (("train.frontier_rounds", counts["rounds"]),
                         ("train.rows_passed", counts["rows_passed"]),
                         ("train.rows_selected", counts["rows_selected"]),
-                        ("train.hist_feature_blocks", blocks)):
-            obs_metrics.counter(name).inc(v)
-            obs_metrics.histogram(name + "_per_tree").observe(v)
+                        ("train.hist_feature_blocks", blocks),
+                        ("train.collective_bytes",
+                         counts.get("collective_bytes"))):
+            if v is not None:
+                obs_metrics.counter(name).inc(v)
+                obs_metrics.histogram(name + "_per_tree").observe(v)
         return counts
 
     @staticmethod
@@ -267,8 +283,9 @@ class GBDT:
         for m in self.train_metrics:
             m.init(train_data.metadata, train_data.num_data)
         tracer = get_tracer()
+        self._mesh, self._row_sharding = self._learner_mesh()
         with tracer.span("lgbm/booster/init/to_device", what="bins,labels"):
-            self._dd = train_data.device_data()
+            self._dd = train_data.device_data(row_sharding=self._row_sharding)
             self._label_dev = (
                 jnp.asarray(train_data.metadata.label)
                 if train_data.metadata.label is not None else None)
@@ -312,24 +329,26 @@ class GBDT:
                                 self._dd.efb)
         return "frontier" if ok else "serial"
 
-    def _setup_parallel(self) -> None:
-        """Route ``tree_learner=data|feature|voting`` through a device mesh
-        (the analog of the reference's learner×device ``CreateTreeLearner``
-        factory, ``tree_learner.cpp:15-53``).  Falls back to serial with a
-        warning when only one device is available."""
+    def _learner_mesh(self):
+        """``(mesh, row_sharding)`` of ``tree_learner=data|feature|voting``
+        (the analog of the reference's learner x device ``CreateTreeLearner``
+        factory, ``tree_learner.cpp:15-53``): a 1-D mesh over the devices,
+        and for the learners that divide rows the ``NamedSharding`` the bins
+        and scores are placed with; ``(None, None)`` for the serial learner,
+        with a warning where a parallel one finds only one device."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from ..parallel.mesh import DATA_AXIS, FEATURE_AXIS, default_mesh
         cfg = self.config
-        self._mesh = None
         tl = cfg.tree_learner or "serial"
         if tl == "serial":
-            return
+            return None, None
         n_dev = cfg.mesh_shape[0] if cfg.mesh_shape else len(jax.devices())
         if n_dev < 2:
             Log.warning(
                 "tree_learner=%s requested but only one device is available; "
                 "training serially", tl)
-            return
-        if tl in ("feature", "voting") and self._dd.efb is not None:
+            return None, None
+        if tl in ("feature", "voting") and self.train_data.bundles is not None:
             # the Dataset disables bundling when its params request these
             # learners; a dataset constructed for serial/data training and
             # then reused here would silently misalign per-feature metadata
@@ -338,25 +357,37 @@ class GBDT:
                 f"tree_learner={tl} cannot train on an EFB-bundled Dataset; "
                 "construct the Dataset with tree_learner=%s or "
                 "enable_bundle=false in its params" % tl)
-        axis = FEATURE_AXIS if tl == "feature" else DATA_AXIS
-        self._mesh = default_mesh(n_dev, axis_name=axis)
+        if tl == "feature":
+            return default_mesh(n_dev, axis_name=FEATURE_AXIS), None
+        mesh = default_mesh(n_dev, axis_name=DATA_AXIS)
+        return mesh, NamedSharding(mesh, P(DATA_AXIS))
+
+    def _setup_parallel(self) -> None:
+        """The grower's view of the mesh ``_learner_mesh`` chose.  Under the
+        row-dividing learners the bins are on the mesh already, each device
+        holding its rows (padded with zero-weight rows to a multiple of the
+        mesh), and the training score goes there too where the rows divide
+        the mesh: left on one device, every tree's program would move it, and
+        the second tree would recompile (the score comes back from the first
+        step row-sharded)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        if self._mesh is None:
+            return
+        axis = self._mesh.axis_names[0]
+        n_dev = self._mesh.devices.size
         self._grower_cfg = self._grower_cfg._replace(
-            axis_name=axis, parallel_mode=tl, num_shards=n_dev,
-            top_k=cfg.top_k)
-        if axis == DATA_AXIS and self.train_data.num_data % n_dev == 0:
-            # Put the row-sharded operands on the mesh ONCE.  Left where
-            # jnp.asarray made them (device 0), every tree's shard_map call
-            # re-scatters the whole bin matrix, and the second tree
-            # recompiles the grow program: the score comes back from the
-            # first step row-sharded, so the gradients change sharding
-            # between the first call and the rest (chip_smoke.py --devices
-            # prints the placements).  Rows that do not divide the mesh are
-            # padded inside the jitted step and stay as they were.
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            self._dd = dataclasses.replace(self._dd, bins=jax.device_put(
-                self._dd.bins, NamedSharding(self._mesh, P(axis))))
+            axis_name=axis, parallel_mode=self.config.tree_learner,
+            num_shards=n_dev, top_k=self.config.top_k)
+        if self._row_sharding is not None \
+                and self.train_data.num_data % n_dev == 0:
+            # the labels, weights and each tree's row weights with them:
+            # on the first device they would be moved to the mesh every tree
+            self._rows_on_mesh = self._row_sharding
             self._train_score = jax.device_put(
                 self._train_score, NamedSharding(self._mesh, P(None, axis)))
+            self._label_dev, self._weight_dev = (
+                None if a is None else jax.device_put(a, self._row_sharding)
+                for a in (self._label_dev, self._weight_dev))
 
     def _make_grower_cfg(self) -> GrowerConfig:
         cfg = self.config
@@ -639,8 +670,37 @@ class GBDT:
                 init[k] += self.init_scores[k]
         with get_tracer().span("lgbm/booster/init/to_device",
                                what="valid bins,scores"):
-            valid_data.device_data()
-            self._valid_scores.append(jnp.asarray(init))
+            self._valid_scores.append(self._place_valid(valid_data, init))
+
+    def _place_valid(self, valid_data: Dataset, score: np.ndarray):
+        """A validation set's bins and its scores ``[K, n]`` on the device.
+        Where the learner divides rows over a mesh both go there, padded
+        alike to a multiple of it (rows of bin 0, scores of 0), so that each
+        tree's traversal and the metrics run where the rows are; the host
+        reads the first ``n`` columns (``valid_score_host``).  Linear trees
+        keep them on one device: their leaves read the raw rows."""
+        rows = self._valid_rows()
+        valid_data.device_data(row_sharding=rows)
+        if rows is None:
+            return jnp.asarray(score)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from ..parallel.mesh import put_rows
+        return put_rows(score, NamedSharding(rows.mesh, P(None, *rows.spec)),
+                        row_axis=1)
+
+    def _valid_rows(self):
+        return None if self.config.linear_tree else self._row_sharding
+
+    def _valid_bins(self, vi: int):
+        """Validation set ``vi``'s bins as ``_place_valid`` placed them."""
+        return self.valid_sets[vi].device_data(
+            row_sharding=self._valid_rows()).bins
+
+    def valid_score_host(self, vi: int) -> np.ndarray:
+        """Validation set ``vi``'s raw scores ``[K, n]`` on the host, float64,
+        without the rows that pad them to the mesh."""
+        n = self.valid_sets[vi].num_data
+        return np.asarray(self._valid_scores[vi], np.float64)[:, :n]
 
     # ------------------------------------------------------------------
     # bagging (gbdt.cpp:182-262); subclasses (GOSS) override
@@ -782,7 +842,8 @@ class GBDT:
 
         with tracer.span("lgbm/update/sample"):
             bag_mask, g, h = self._bagging_weights(it, g, h)
-        row_weight = bag_mask if bag_mask is not None else jnp.ones(n, jnp.float32)
+        row_weight = (bag_mask if bag_mask is not None
+                      else jnp.ones(n, jnp.float32, device=self._rows_on_mesh))
         fmask = self._feature_mask(it)
         self._prev_scores = (self._train_score, list(self._valid_scores))
 
@@ -871,8 +932,8 @@ class GBDT:
                 else:
                     self._train_score = self._train_score.at[k].add(
                         jnp.where(nl > 1, delta[node_assign], 0.0))
-                for vi, vset in enumerate(self.valid_sets):
-                    vleaf = self._predict_leaf_jit(tree_arrays, vset.device_data().bins)
+                for vi in range(len(self.valid_sets)):
+                    vleaf = self._predict_leaf_jit(tree_arrays, self._valid_bins(vi))
                     if linear_dev is not None:
                         vraw = self._valid_raw_dev(vi)
                         vdelta = linear_leaf_delta(
@@ -955,9 +1016,9 @@ class GBDT:
                 self._train_score = self._score_update_jit(*score_args)
                 self._record_program("train.score_update",
                                      self._score_update_jit, *score_args)
-                for vi, vset in enumerate(self.valid_sets):
+                for vi in range(len(self.valid_sets)):
                     valid_args = (self._valid_scores[vi], tree_arrays, delta,
-                                  vset.device_data().bins, k)
+                                  self._valid_bins(vi), k)
                     self._valid_scores[vi] = self._valid_update_jit(
                         *valid_args)
                     self._record_program(f"train.valid_update.{vi}",
@@ -1050,8 +1111,11 @@ class GBDT:
         ``HistogramPool`` once per learner.  The folds of ``engine.cv`` share
         one pool, so they hold one store between them; ``engine.train`` and
         ``engine.cv`` empty it when training ends (``release_hist_pool``).
-        A store of another shape (``reset_parameter``) is dropped."""
-        if getattr(self, "_mesh", None) is not None \
+        A store of another shape (``reset_parameter``) is dropped.  Under
+        ``tree_learner=data`` every device keeps a store of its own (the
+        histograms are the mesh's sums, the same on every device)."""
+        data_mesh = self._grower_cfg.parallel_mode == "data"
+        if (self._mesh is not None and not data_mesh) \
                 or self._grower_name() != "frontier":
             return self._grow_jit(*grow_args, None)
         from ..ops.frontier import hist_store_shape
@@ -1059,7 +1123,9 @@ class GBDT:
         store = self.hist_pool.pop(shape, None)
         self.hist_pool.clear()
         if store is None:
-            store = jnp.zeros(shape, jnp.float32)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            store = jnp.zeros(shape, jnp.float32, device=(
+                NamedSharding(self._mesh, P()) if data_mesh else None))
         *out, self.hist_pool[shape] = self._grow_jit(*grow_args, store)
         return tuple(out)
 
@@ -1182,38 +1248,40 @@ class GBDT:
                                cegb_coupled, cegb_used)
             return fn
 
-        # data / voting: rows sharded
-        n_pad = (-n) % ns
-
-        def grow(bins, g, h, rw, fmask, key, cc, cu):
+        # data / voting: rows sharded; the bins come padded to a multiple of
+        # the mesh (Dataset.device_data), the gradients are padded here, and
+        # rows of zero weight count in no histogram or sum, so the trees are
+        # the serial learner's.  Under ``data`` each device grows in its own
+        # histogram store, donated and handed back (GBDT._grow).
+        def grow(bins, g, h, rw, fmask, key, cc, cu, store):
             return grow_tree(bins, g, h, rw, fmask, dd.num_bins,
                              dd.default_bins, dd.nan_bins, dd.is_categorical,
                              dd.monotone, key, cfg, interaction_sets=inter,
                              cegb_coupled=cc, cegb_lazy=lazy,
                              cegb_used_data=cu, forced=forced, efb=dd.efb,
-                             feature_contri=contri, with_stats=True)
+                             feature_contri=contri, with_stats=True,
+                             hist_store=store)
 
-        sharded = jax.shard_map(
-            grow, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(), P(),
-                      P(axis)),
-            out_specs=(P(), P(axis), P()), check_vma=False)
-
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=8)
         def fn(bins, g, h, rw, fmask, key, cegb_coupled, cegb_used,
-               hist_store):                # None: each shard's store is its own
-            if n_pad:
-                # pad rows to a mesh multiple; zero weight excludes them from
-                # every histogram/sum, so results match serial exactly
-                bins = jnp.pad(bins, ((0, n_pad), (0, 0)))
-                g = jnp.pad(g, (0, n_pad))
-                h = jnp.pad(h, (0, n_pad))
-                rw = jnp.pad(rw, (0, n_pad))
-                if cegb_used is not None:
-                    cegb_used = jnp.pad(cegb_used, ((0, n_pad), (0, 0)))
-            tree, na, stats = sharded(bins, g, h, rw, fmask, key,
-                                      cegb_coupled, cegb_used)
-            return tree, (na[:n] if n_pad else na), stats
+               hist_store):
+            rows = bins.shape[0] + (-bins.shape[0]) % ns
+            pad = lambda a: jnp.pad(a, [(0, rows - a.shape[0])]  # noqa: E731
+                                    + [(0, 0)] * (a.ndim - 1))
+            bins, g, h, rw = pad(bins), pad(g), pad(h), pad(rw)
+            if cegb_used is not None:
+                cegb_used = pad(cegb_used)
+            sharded = jax.shard_map(
+                grow, mesh=mesh,
+                in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(), P(),
+                          P(axis), P()),
+                out_specs=(P(), P(axis), P())
+                + ((P(),) if hist_store is not None else ()),
+                check_vma=False)
+            tree, na, stats, *store = sharded(bins, g, h, rw, fmask, key,
+                                              cegb_coupled, cegb_used,
+                                              hist_store)
+            return (tree, na[:n], stats, *store)
         return fn
 
     def _cegb_state(self):
@@ -1311,7 +1379,9 @@ class GBDT:
                 jax.block_until_ready(score_dev)
             if not all(on_device):
                 with tracer.span("lgbm/eval/fetch"):
-                    score = np.asarray(score_dev, np.float64)
+                    # without the rows that pad a mesh (_place_valid)
+                    score = np.asarray(score_dev, np.float64)[
+                        :, :metrics[0].num_data]
                 s = score[0] if self.num_tree_per_iteration == 1 else score
             for m, dev in zip(metrics, on_device):
                 with tracer.span("lgbm/eval/metric", metric=type(m).__name__,
@@ -1579,9 +1649,9 @@ class GBDT:
             # under the streaming engine a full device_data() here would
             # materialize (and cache) a valid bin matrix the budget says
             # does not fit
-            self._valid_scores[vi] = warm(vset, vset.device_meta(),
-                                          jnp.zeros_like(self._valid_scores[vi]),
-                                          raw_of(vset))
+            self._valid_scores[vi] = self._place_valid(vset, np.asarray(warm(
+                vset, vset.device_meta(),
+                np.zeros((K, vset.num_data), np.float32), raw_of(vset))))
 
     # ------------------------------------------------------------------
     def refit(self, X: np.ndarray, y: np.ndarray, decay_rate: float = 0.9) -> None:

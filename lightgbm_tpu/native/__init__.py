@@ -162,7 +162,10 @@ def bin_values(data: np.ndarray, mappers, used_features) -> Optional[np.ndarray]
     def_c = np.asarray(default_bins, np.int32)
     cat_c = np.asarray(is_cat, np.uint8)
 
-    sub = np.ascontiguousarray(data[:, list(used_features)], np.float64)
+    # every column used, in order (the usual dense table): no copy of it
+    sub = (data if list(used_features) == list(range(data.shape[1]))
+           and data.dtype == np.float64 and data.flags.c_contiguous
+           else np.ascontiguousarray(data[:, list(used_features)], np.float64))
     out = np.empty((n, cols), np.uint16)
     if lib.BinValues(_ptr(sub, ctypes.c_double), n, cols,
                      _ptr(uppers_c, ctypes.c_double),

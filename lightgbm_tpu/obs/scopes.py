@@ -63,6 +63,10 @@ SCOPES = (
     # the built-in binary metrics over device scores (metric/device.py): the
     # AUC's sort and scans, the log-loss's softplus, their block sums
     "lgbm/valid_metric",
+    # tree_learner=data across devices: the histograms' exchange (both
+    # growers) and the gather of a validation set's scores for its metrics
+    "lgbm/collective/hist",
+    "lgbm/collective/metric",
     # the serial grower (ops/grower.py)
     "lgbm/partition",
     "lgbm/hist",

@@ -72,6 +72,19 @@ def hist_store_shape(cfg, n_cols: int) -> tuple:
     return (L + 2 * k, n_cols, cfg.bundle_bins or cfg.max_bin, 6)
 
 
+def exchange_bytes(cfg, n_cols: int, rounds: int) -> int:
+    """Bytes one device receives in one tree's exchange under
+    ``tree_learner=data``, reckoned from the exchanged shapes as an
+    all-gather moves them, from each of the other shards: the root's
+    histogram and each round's ``k`` smaller children's (``[n_cols, bins,
+    6]`` float32 each, ``histogram.psum_hist``).  Nothing else crosses: every
+    shard searches the same summed histograms with the same program, so
+    every shard picks the same splits."""
+    k = max(1, min(cfg.frontier_k, cfg.num_leaves - 1))
+    hist = n_cols * (cfg.bundle_bins or cfg.max_bin) * 6 * 4
+    return (cfg.num_shards - 1) * hist * (1 + rounds * k)
+
+
 def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
                        num_bins, default_bins, nan_bins, is_categorical,
                        monotone, key, cfg, efb=None, feature_contri=None,
@@ -217,7 +230,10 @@ def grow_tree_frontier(bins, grad, hess, row_weight, feature_mask,
     def reduce_hist(h):
         # data: full-histogram allreduce; feature/voting keep shard-local
         # stores (voting reduces only ELECTED slices inside the search)
-        return psum_hist(h, axis) if mode == "data" else h
+        if mode != "data":
+            return h
+        with jax.named_scope("lgbm/collective/hist"):
+            return psum_hist(h, axis)
 
     @jax.named_scope("lgbm/sum_repair")
     def totals_of(h):

@@ -547,10 +547,11 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         block (dp_scatter) or full allreduce.  No-op outside data mode."""
         if mode != "data":
             return h
-        if dp_scatter:
-            hp = jnp.pad(h, ((0, shard_wp - n_cols), (0, 0), (0, 0)))
-            return psum_scatter_hist(hp, axis, cfg.num_shards)
-        return psum_hist(h, axis)
+        with jax.named_scope("lgbm/collective/hist"):
+            if dp_scatter:
+                hp = jnp.pad(h, ((0, shard_wp - n_cols), (0, 0), (0, 0)))
+                return psum_scatter_hist(hp, axis, cfg.num_shards)
+            return psum_hist(h, axis)
 
     @jax.named_scope("lgbm/sum_repair")
     def totals_of(h):

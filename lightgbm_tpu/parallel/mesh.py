@@ -31,6 +31,40 @@ def default_mesh(num_devices: Optional[int] = None,
     return jax.sharding.Mesh(np.asarray(devices), (axis_name,))
 
 
+def row_shards(config) -> int:
+    """Devices the rows are divided over: the mesh that ``tree_learner=data``
+    or ``voting`` trains on (``GBDT._setup_parallel``), 1 for the other
+    learners and where there is one device."""
+    if config.tree_learner not in ("data", "voting"):
+        return 1
+    n = config.mesh_shape[0] if config.mesh_shape else len(jax.devices())
+    return n if n >= 2 else 1
+
+
+def put_rows(host: np.ndarray, sharding, row_axis: int = 0, fill=0):
+    """``host`` on the devices of ``sharding`` (a ``NamedSharding`` that
+    divides ``row_axis`` over a 1-D mesh), padded with ``fill`` to a multiple
+    of the mesh.  Each device is sent its own rows straight from the host, so
+    the whole array never sits on one device."""
+    shards = sharding.mesh.devices.size
+    n = host.shape[row_axis]
+    n_pad = -(-n // shards) * shards
+    shape = host.shape[:row_axis] + (n_pad,) + host.shape[row_axis + 1:]
+    lead = (slice(None),) * row_axis
+
+    def piece(index):
+        rows = index[row_axis]
+        lo, hi = rows.start or 0, n_pad if rows.stop is None else rows.stop
+        part = host[lead + (slice(lo, min(hi, n)),)]
+        if hi <= n:
+            return part
+        widths = [(0, 0)] * host.ndim
+        widths[row_axis] = (0, hi - max(lo, n))
+        return np.pad(part, widths, constant_values=fill)
+
+    return jax.make_array_from_callback(shape, sharding, piece)
+
+
 def mesh_2d(num_data: int, num_feature: int,
             devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
     """(data, feature) mesh for combined row+feature sharding."""

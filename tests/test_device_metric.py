@@ -78,6 +78,38 @@ def test_device_form_equals_the_host_metric(kind, n):
         assert int(np.asarray(partials).sum(dtype=np.int64)) > 2 ** 31
 
 
+@pytest.mark.parametrize("kind,n", [("ties", 257), ("wide", 100_003), ("all_neg", 257)])
+def test_scores_over_a_mesh_give_the_host_metric(kind, n):
+    """Scores whose rows lie on 4 devices (padded to a multiple of them, as
+    ``GBDT._place_valid`` places a validation set's): the AUC gathered and
+    counted on every device equals the host's on the fetched scores bit for
+    bit, and the log-loss, block sums added on the host, is within the one
+    device's tolerance."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.parallel.mesh import put_rows
+    rng = np.random.default_rng(n + len(kind))
+    s, y = _scores(kind, n, rng)
+    cfg = Config()
+    objective = BinaryLogloss(cfg)
+    meta = _Meta(y)
+    objective.init(meta, n)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    score_dev = put_rows(s[None], NamedSharding(mesh, P(None, "data")), row_axis=1)
+    assert score_dev.shape[1] == -(-n // 4) * 4
+    assert device.rows_mesh(score_dev) == (mesh, "data")
+    fetched = np.asarray(score_dev, np.float64)[0, :n]
+    for m in (AUCMetric(cfg), BinaryLoglossMetric(cfg)):
+        m.init(meta, n)
+        want = m.eval(fetched, objective)[0][1]
+        got = m.device_eval(score_dev, objective)[0][1]
+        if isinstance(m, AUCMetric):
+            assert got == want
+        else:
+            assert abs(got - want) <= 1e-6 * abs(want)
+        assert m._label_dev.sharding.spec == P("data")
+
+
 def test_auc_block_keeps_every_partial_in_int32():
     for n in (1, 1000, 100_000, 3_541_666, 17_708_333, device.MAX_ROWS):
         block = device.auc_block(n)

@@ -244,8 +244,16 @@ def _api_train(tree_learner, X, y, **extra):
     return lgb.train(params, ds, num_boost_round=5)
 
 
-@pytest.mark.parametrize("learner", ["data", "feature", "voting"])
-def test_api_tree_learner_matches_serial(learner):
+@pytest.mark.parametrize("learner,extra", [
+    pytest.param("data", {}, id="data"),
+    pytest.param("feature", {}, id="feature"),
+    pytest.param("voting", {}, id="voting"),
+    # the table in 4 shards on a 4-device mesh, at the library's leaf limits
+    # (at 31 leaves the last splits of these 1,001 rows gain under 1e-5,
+    # float noise whose order any change of summation order flips)
+    pytest.param("data", {"shards": 4, "mesh_shape": [4], "min_data_in_leaf": 20,
+                          "min_sum_hessian_in_leaf": 1e-3}, id="data-4-shards")])
+def test_api_tree_learner_matches_serial(learner, extra):
     """Through the PUBLIC API, every parallel learner on the 8-device mesh
     must produce the identical model to serial training (stronger than the
     reference's quality-only Dask parity, test_dask.py)."""
@@ -256,8 +264,22 @@ def test_api_tree_learner_matches_serial(learner):
     # different float-reduction order can legitimately flip — the exact-
     # structure assertion below is only meaningful on tie-free data.
     X, y = _api_data(n=1001)
-    serial = _api_train("serial", X, y)
-    par = _api_train(learner, X, y)
+    extra = dict(extra)
+    shards = extra.pop("shards", 0)
+    serial = _api_train("serial", X, y, **{k: v for k, v in extra.items()
+                                            if k != "mesh_shape"})
+    par = _api_train(learner, np.array_split(X, shards) if shards else X, y,
+                     **extra)
+    if shards:
+        assert par._gbdt._mesh.devices.size == 4
+        assert par._gbdt._grower_name() == "frontier"
+        # leaf counts exact (below); each leaf value is its own float32 sums
+        # of gradient and hessian over its rows, added across 4 shards in
+        # another order than on one device: equal to a few float32 ulps
+        for ts, tp in zip(serial.dump_model()["tree_info"],
+                          par.dump_model()["tree_info"]):
+            np.testing.assert_allclose(_leaf_values(tp), _leaf_values(ts),
+                                       rtol=1e-5, atol=1e-7)
     assert serial.num_trees() == par.num_trees()
     np.testing.assert_allclose(par.predict(X), serial.predict(X),
                                rtol=0, atol=1e-6)
@@ -270,6 +292,79 @@ def test_api_tree_learner_matches_serial(learner):
     def structure(s):
         return [l for l in s.splitlines() if l.startswith(struct_keys)]
     assert structure(par.model_to_string()) == structure(serial.model_to_string())
+
+
+def _leaf_values(tree_json):
+    out, stack = {}, [tree_json["tree_structure"]]
+    while stack:
+        node = stack.pop()
+        if "leaf_index" in node:
+            out[node["leaf_index"]] = node["leaf_value"]
+        else:
+            stack += [node["left_child"], node["right_child"]]
+    return np.array([out[i] for i in sorted(out)])
+
+
+def test_shards_bin_as_the_whole_table(n=1003):
+    """A table given as a list of shards (rows that do not divide the mesh)
+    gets the bin mappers and bins of the whole matrix, byte for byte, and
+    under ``tree_learner=data`` its bins go to the mesh padded to a multiple
+    of it, each device holding its own rows; a validation set's scores are
+    padded alike and read back without the padding."""
+    import lightgbm_tpu as lgb
+    X, y = _api_data(n=n)
+    Xv, yv = _api_data(n=n // 2, seed=6)
+    params = {"objective": "binary", "max_bin": 63, "verbose": -1,
+              "tree_learner": "data", "mesh_shape": [4], "num_leaves": 7}
+    whole = lgb.Dataset(X, label=y, params=params).construct()._inner
+    sharded = lgb.Dataset(np.array_split(X, 4), label=y, params=params)
+    inner = sharded.construct()._inner
+    assert ([m.to_state() for m in inner.bin_mappers]
+            == [m.to_state() for m in whole.bin_mappers])
+    assert inner.bins.tobytes() == whole.bins.tobytes()
+    bst = lgb.Booster(params, sharded)
+    valid = lgb.Dataset(np.array_split(Xv, 4), label=yv, reference=sharded)
+    bst.add_valid(valid, "v")
+    bins = bst._gbdt._dd.bins
+    assert bins.shape[0] == -(-n // 4) * 4
+    assert {s.data.shape[0] for s in bins.addressable_shards} == {-(-n // 4)}
+    assert len({s.device for s in bins.addressable_shards}) == 4
+    bst.update()
+    vs = bst._gbdt._valid_scores[0]
+    assert vs.shape[1] == -(-(n // 2) // 4) * 4
+    np.testing.assert_allclose(bst._gbdt.valid_score_host(0)[0],
+                               bst.predict(Xv, raw_score=True), rtol=1e-6, atol=1e-6)
+
+
+def test_frontier_shards_grow_one_tree_bit_for_bit():
+    """Under ``tree_learner=data`` nothing but the histograms crosses the
+    mesh: each shard searches the exchanged sums by itself.  Every shard's
+    tree, split records and leaf values alike, is the same bit for bit."""
+    from jax.sharding import PartitionSpec as P
+    from lightgbm_tpu.ops.frontier import grow_tree_frontier
+    n, f, max_bin = 1000, 6, 32
+    bins_np, label_np = _data(n, f, max_bin, seed=2)
+    g, h = _grad_fn(jnp.zeros(n), jnp.asarray(label_np))
+    meta = _meta(f, max_bin)
+    cfg = _cfg(axis_name="data")._replace(parallel_mode="data", num_shards=4,
+                                          frontier_k=4)
+
+    def grow(b, g, h, rw):
+        tree, _ = grow_tree_frontier(
+            b, g, h, rw, jnp.ones(f, bool), meta["num_bins"],
+            meta["default_bins"], meta["nan_bins"], meta["is_categorical"],
+            meta["monotone"], jax.random.key(1), cfg)
+        return jax.tree.map(lambda a: a[None], tree)
+
+    per_shard = jax.jit(jax.shard_map(
+        grow, mesh=default_mesh(4), in_specs=(P("data"),) * 4,
+        out_specs=P("data"), check_vma=False))(
+        jnp.asarray(bins_np), g, h, jnp.ones(n, jnp.float32))
+    assert int(per_shard.num_leaves[0]) == cfg.num_leaves
+    for name, a in zip(per_shard._fields, per_shard):
+        a = np.asarray(a)
+        for s in range(1, 4):
+            assert a[s].tobytes() == a[0].tobytes(), (name, s)
 
 
 def test_api_tree_learner_uses_mesh():

@@ -237,14 +237,15 @@ def _listed(metric):
 
 
 def _train_cells():
-    """The cells that BENCHMARK.json runs the ``train`` job in."""
+    """The cells that BENCHMARK.json runs a training job in: ``train``, and
+    ``train_dp``, the same job with its rows in shards over a mesh."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         cells = json.load(f)["workloads"]
     out = set()
     for w in cells:
         with open(os.path.join(ROOT, "benchmarks", "traffic",
                                w["traffic"] + ".json")) as f:
-            if json.load(f)["job"] == "train":
+            if json.load(f)["job"] in ("train", "train_dp"):
                 out.add(w["name"])
     return out
 
@@ -385,4 +386,62 @@ def test_device_metric_share_reader(fixture, monkeypatch):
         s["args"]["on_device"] = 1
     _program_with(monkeypatch, fixture["spans"])
     assert read(run) == 100.0
+    assert read({"trace": None, "window_s": 1.0}) is None
+
+
+# ---- across chips: read per chip, from the chip that sets the pace
+def _second_chip(fixture, copy7_ns):
+    """The fixture's chip, and a second one that runs the same operations
+    but ``%fusion.51`` (0.6 ms less busy) with ``%copy.7`` lasting
+    ``copy7_ns``."""
+    trace = copy.deepcopy(fixture["trace"])
+    events = trace["device"]["/device:TPU:0"]
+    other = [[n, s, copy7_ns if n.startswith("%copy.7") else d]
+             for n, s, d in events if not n.startswith("%fusion.51")]
+    trace["device"]["/device:TPU:1"] = other
+    return trace
+
+
+def test_collective_share_reader(fixture, monkeypatch):
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.obs import scopes
+    assert set(_listed("collective_share.train")["workloads"]) == _train_cells()
+    read = _reader("collective_share.train")
+    table = dict(fixture["scopes"])
+    monkeypatch.setattr(obs, "device_scopes", lambda: dict(table))
+    run = {"trace": fixture["trace"], "window_s": WINDOW_S}
+    # one chip and no operation under the scope: no time in collectives
+    assert read(run) == 0.0
+    table["copy.7 s32[4096]"] = "lgbm/collective/hist"
+    assert read(run) == pytest.approx(100 * 0.1e-3 / WINDOW_S)
+    # two chips: the first, busier by 0.5 ms, sets the pace and is read;
+    # the second's 0.2 ms is neither added (0.3) nor averaged in (0.15)
+    run["trace"] = _second_chip(fixture, 200_000)
+    assert read(run) == pytest.approx(100 * 0.1e-3 / WINDOW_S)
+    # a program that names no such scope (the parent's), and no device plane
+    monkeypatch.setattr(scopes, "SCOPES", tuple(
+        s for s in scopes.SCOPES if not s.startswith("lgbm/collective/")))
+    assert read(run) is None
+    assert read({"trace": None, "window_s": 1.0}) is None
+
+
+def test_chip_skew_share_reader(fixture, monkeypatch):
+    from lightgbm_tpu import obs
+    from lightgbm_tpu.obs import scopes
+    assert set(_listed("chip_skew_share.train")["workloads"]) == _train_cells()
+    read = _reader("chip_skew_share.train")
+    table = dict(fixture["scopes"], **{"copy.7 s32[4096]": "lgbm/collective/hist"})
+    monkeypatch.setattr(obs, "device_scopes", lambda: dict(table))
+    # one chip: nothing to wait for
+    assert read({"trace": fixture["trace"], "window_s": WINDOW_S}) == 0.0
+    # the second chip is 0.6 ms less busy outside the exchange and spends
+    # as long in it: neither waited for the other, whatever the busy times
+    assert read({"trace": _second_chip(fixture, 100_000), "window_s": WINDOW_S}) == 0.0
+    # its exchange 0.1 ms longer: it came first and waited 0.1 ms inside it
+    run = {"trace": _second_chip(fixture, 200_000), "window_s": WINDOW_S}
+    assert read(run) == pytest.approx(100 * 0.1e-3 / 11.6e-3)
+    # a program that names no such scope (the parent's), and no device plane
+    monkeypatch.setattr(scopes, "SCOPES", tuple(
+        s for s in scopes.SCOPES if not s.startswith("lgbm/collective/")))
+    assert read(run) is None
     assert read({"trace": None, "window_s": 1.0}) is None
